@@ -25,9 +25,13 @@ reference's single _reader_process does.
 
 Start method: ``fork`` by default (dataset/generator need no pickling —
 the reference and torch do the same on Linux).  Workers only run
-numpy, so the usual forked-JAX hazards don't apply to the child's work;
-pass ``mp_start_method="spawn"`` for a picklable dataset if the parent's
-thread state is a concern.
+numpy, so on the CPU backend the forked-JAX hazards don't apply to the
+child's work.  A process that holds the TPU runtime must not fork (a
+chip belongs to one process; the child inherits the runtime's threads
+and device handles), so on a TPU backend ``fork`` is refused — pass
+``mp_start_method="spawn"`` with a picklable dataset, or use the
+threaded loader (``use_multiprocess=False``), which is what the device
+double buffer sits on.
 """
 
 from __future__ import annotations
@@ -119,6 +123,14 @@ class MultiprocessIterator:
                  capacity: int = 8, to_feed=None, mp_start_method="fork"):
         if generator is not None:
             num_workers = 1          # see module docstring
+        if mp_start_method == "fork":
+            import jax
+            if jax.default_backend() == "tpu":
+                raise RuntimeError(
+                    "multiprocess DataLoader: refusing to fork a process "
+                    "that holds the TPU runtime — pass "
+                    "mp_start_method='spawn' (picklable dataset) or use "
+                    "the threaded loader (use_multiprocess=False)")
         ctx = mp.get_context(mp_start_method)
         per_q = max(2, capacity // max(num_workers, 1))
         self._queues = [ctx.Queue(maxsize=per_q) for _ in range(num_workers)]

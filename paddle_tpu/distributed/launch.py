@@ -5,7 +5,10 @@ TPU the launcher's job is per-HOST (one jax process per host, all chips of
 the host attached): set the jax.distributed coordination env and exec the
 training script on every host.  On Cloud TPU pods the platform runner
 already does this; this module covers manual multi-host bring-up and
-single-host multi-process CPU testing."""
+single-host multi-process CPU testing.  A chip belongs to one process, so
+``nproc > 1`` on one host is refused unless the children are pinned to the
+CPU backend (``JAX_PLATFORMS=cpu``): one process drives every local chip
+through the mesh."""
 
 from __future__ import annotations
 
@@ -22,6 +25,12 @@ def launch(script_args=None, nproc: int = 1, coordinator: str = "127.0.0.1:12355
     if not script_args:
         raise SystemExit("usage: python -m paddle_tpu.distributed.launch "
                          "[--nproc N] script.py [args...]")
+    if nproc > 1 and os.environ.get("JAX_PLATFORMS") != "cpu":
+        raise SystemExit(
+            f"launch: --nproc {nproc} on one host would have {nproc} "
+            f"processes claim the same chips; run ONE process per host "
+            f"(it drives every local chip through the mesh), or set "
+            f"JAX_PLATFORMS=cpu for multi-process CPU testing")
     procs = []
     for pid in range(nproc):
         env = dict(os.environ)

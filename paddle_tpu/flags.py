@@ -252,12 +252,15 @@ def flag(name: str):
     return _REGISTRY[name]
 
 
-#: XLA flags that let the compiler's latency-hiding scheduler keep the
+#: libtpu flags that let the compiler's latency-hiding scheduler keep the
 #: ready-ordered grad-sync collectives where the trace put them (async
 #: collectives overlapped with compute instead of re-sunk to the tail).
-#: These are process-start flags — they must be in XLA_FLAGS before the
-#: first backend touch, which is why they are plumbed as data here
-#: instead of set_flags entries.
+#: These are process-start flags — they must be in LIBTPU_INIT_ARGS
+#: before the first backend touch, which is why they are plumbed as data
+#: here instead of set_flags entries.  They are TPU-compiler flags:
+#: XLA_FLAGS does not know them and aborts start-up on any of the four
+#: (jaxlib 0.9.0, on the CPU and on the chip alike); libtpu 0.0.34
+#: accepts all four through LIBTPU_INIT_ARGS (v5e, PR 21).
 OVERLAP_XLA_FLAGS = (
     "--xla_tpu_enable_latency_hiding_scheduler=true",
     "--xla_tpu_enable_async_collective_fusion=true",
@@ -266,19 +269,35 @@ OVERLAP_XLA_FLAGS = (
 )
 
 
-def overlap_xla_flags():
-    """The XLA latency-hiding-scheduler flag strings the overlap
-    scheduler wants active on TPU (see OVERLAP_XLA_FLAGS)."""
-    return list(OVERLAP_XLA_FLAGS)
-
-
 def apply_overlap_xla_flags(environ=None):
-    """Append any missing overlap XLA flags to ``XLA_FLAGS`` in
+    """Append any missing overlap flags to ``LIBTPU_INIT_ARGS`` in
     ``environ`` (default ``os.environ``).  Call BEFORE the first jax
     backend initialisation; returns the flags that were added."""
     env = os.environ if environ is None else environ
-    current = env.get("XLA_FLAGS", "")
+    current = env.get("LIBTPU_INIT_ARGS", "")
     added = [f for f in OVERLAP_XLA_FLAGS if f not in current]
     if added:
-        env["XLA_FLAGS"] = (current + " " + " ".join(added)).strip()
+        env["LIBTPU_INIT_ARGS"] = (current + " " + " ".join(added)).strip()
     return added
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its
+    directory.  Call before the first compile (chip_smoke.py, bench.py).
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX already reads it and
+    no path is set here.  Otherwise the cache lives at the fixed
+    ``<checkout>/.jax_cache`` (git-ignored): the directory is part of
+    the cache key, so a path built from a temp dir, pid or clock would
+    never hit.  The thresholds drop to zero so the many small decode
+    executables are cached too."""
+    import jax
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    return path

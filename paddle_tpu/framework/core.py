@@ -620,16 +620,41 @@ CUDAPlace = TPUPlace
 
 
 def _jax_device_for(place: Place):
+    """``TPUPlace(i)`` is device ``i`` of JAX's DEFAULT backend — the
+    chip on a TPU host, a host device under ``JAX_PLATFORMS=cpu`` (the
+    whole CPU test suite builds ``TPUPlace(0)``).  The place does not
+    check the platform; the chip entry points (chip_smoke.py, bench.py)
+    do, and fail without a TPU.  An index past the last device is an
+    error, never an alias of another chip."""
     import jax
     if isinstance(place, CPUPlace):
-        for d in jax.devices("cpu"):
-            return d
-        return jax.devices()[0]
+        return jax.devices("cpu")[0]
     devs = jax.devices()
     idx = getattr(place, "device_id", 0)
-    return devs[idx % len(devs)]
+    if not 0 <= idx < len(devs):
+        raise ValueError(
+            f"{place!r}: the {devs[0].platform} backend has "
+            f"{len(devs)} device(s)")
+    return devs[idx]
 
 
 def is_compiled_with_tpu() -> bool:
     import jax
-    return any(d.platform != "cpu" for d in jax.devices())
+    return any(d.platform == "tpu" for d in jax.devices())
+
+
+def require_tpu() -> dict:
+    """The device check of the chip entry points (chip_smoke.py,
+    bench.py): returns ``{"platform", "kind", "count"}`` as JAX reports
+    the default backend, and exits non-zero before any model is built
+    when that backend is not the TPU — a device number is never taken
+    from a CPU."""
+    import jax
+    devs = jax.devices()
+    if devs[0].platform != "tpu":
+        raise SystemExit(
+            f"no TPU: JAX's default backend is {devs[0].platform!r} "
+            f"({len(devs)} device(s)) — this entry point runs on the "
+            f"chip only")
+    return {"platform": devs[0].platform, "kind": devs[0].device_kind,
+            "count": len(devs)}

@@ -569,8 +569,7 @@ def _lower_pipelined_schedule(ops, env, ctx, bw_idx, fetch_names,
     feed_names = [n for n in attrs.get("pipe_feed_names", ()) if n in env]
     tail_ops = ops[bw_idx + 1:]
 
-    from .jax_compat import axis_size
-    n_pp = axis_size(axis)
+    n_pp = jax.lax.axis_size(axis)
     if n_pp != S:
         raise ValueError(
             f"pipelined program has {S} ranks ({V} virtual stages x "
@@ -2579,7 +2578,12 @@ class Executor:
         except (KeyboardInterrupt, SystemExit):
             raise
         except BaseException:
-            return None, True       # lazy jit path will surface the error
+            # counted, not silent: the lazy jit path recompiles and
+            # surfaces a real error; restart legs assert the counter
+            # stayed 0
+            from ..monitor import stat
+            stat("aot_cache_error").add()
+            return None, True
         aot_cache.store(cache_dir, key, compiled,
                         meta={"fetches": list(fetch_names),
                               "feed_sig": [list(map(str, i))
@@ -2632,10 +2636,9 @@ class Executor:
                         {k: state_in_specs[k] for k in state_vals}, P())
             # fetches are merged to replicated inside the step; state keeps
             # its (possibly tp-sharded) layout
-            from .jax_compat import shard_map
-            fn = shard_map(step, mesh=mesh, in_specs=in_specs,
-                           out_specs=(P(), state_out_specs, P()),
-                           check_vma=False)
+            fn = jax.shard_map(step, mesh=mesh, in_specs=in_specs,
+                               out_specs=(P(), state_out_specs, P()),
+                               check_vma=False)
             return fn(feed_vals, state_vals, rng_key)
 
         # explicit GSPMD shardings on the jit boundary: without them XLA

@@ -57,7 +57,10 @@ def device_peak_flops(device=None) -> float:
         for sub, peak in DEVICE_PEAK_FLOPS:
             if sub in kind:
                 return peak
-        return DEVICE_PEAK_FLOPS[-1][1]    # unknown TPU: price as oldest
+        raise ValueError(
+            f"no peak FLOP/s on file for TPU device_kind "
+            f"{getattr(device, 'device_kind', None)!r} — add it to "
+            f"DEVICE_PEAK_FLOPS with its source")
     return CPU_FALLBACK_FLOPS
 
 

@@ -24,8 +24,6 @@ from .registry import register, x
 from .quantize_wire import (CompressionSpec, dequantize_blockwise,
                             pad_to_blocks, quantize_blockwise)
 
-from ..framework.jax_compat import axis_size
-
 
 def _ring_axis(ctx, attrs):
     """ring_id → mesh axis name(s); None when not running under shard_map.
@@ -183,7 +181,7 @@ def _quant_allreduce_axis(flat, ax, spec, ctx, use_kernel=False):
     (the registry's dequant_accumulate pallas route) the receive stage
     runs as one fused VMEM pass — and for round-to-nearest int8 the
     requantization fuses too, so the local f32 sum never touches HBM."""
-    n = axis_size(ax)
+    n = lax.axis_size(ax)
     numel = flat.shape[0]
     bs = spec.block_size
     flat = pad_to_blocks(flat, n * bs)
@@ -229,7 +227,7 @@ def _quant_route(op_type, ins, attrs, axis):
     """Op-level pallas_route for a quantized collective's receive stage
     (counts the hit/fallback in observability.metrics)."""
     from .registry import pallas_route
-    axis_sizes = {ax: axis_size(ax) for ax in _axes_tuple(axis)}
+    axis_sizes = {ax: lax.axis_size(ax) for ax in _axes_tuple(axis)}
     route, _ = pallas_route(op_type, ins, attrs, axis_sizes=axis_sizes)
     return route is not None
 
@@ -318,7 +316,7 @@ def _quant_reduce_scatter(ctx, ins, attrs):
         return {"Out": g.reshape(-1)}
     axes = _axes_tuple(axis)
     scatter_ax, rest = axes[0], axes[1:]
-    n = axis_size(scatter_ax)
+    n = lax.axis_size(scatter_ax)
     orig = g.dtype
     flat = _flat_pad(g.astype(jnp.float32), n, align=spec.block_size)
     if rest:
@@ -360,7 +358,7 @@ def _zero_reduce_scatter(ctx, ins, attrs):
         return {"Out": g.reshape(-1)}
     axes = _axes_tuple(axis)
     scatter_ax, rest = axes[0], axes[1:]
-    n = axis_size(scatter_ax)
+    n = lax.axis_size(scatter_ax)
     # ``align`` mirrors zero_shard_slice: the sharded optimizer pads
     # flat shards to the fused-Adam kernel's 128-lane layout, so grad
     # and param shards must cover identical element ranges
@@ -384,7 +382,7 @@ def _zero_shard_slice(ctx, ins, attrs):
     if axis is None:
         return {"Out": a.reshape(-1)}
     ax = _axes_tuple(axis)[0]
-    n = axis_size(ax)
+    n = lax.axis_size(ax)
     # ``align`` matches the flat pad of a quantized grad scatter so the
     # param shard covers the same element range as the grad shard
     flat = _flat_pad(a, n, align=attrs.get("align", 1))
@@ -477,7 +475,7 @@ def _c_split(ctx, ins, attrs):
     axis = _ring_axis(ctx, attrs)
     if axis is None:
         return {"Out": a}
-    n = axis_size(axis)
+    n = lax.axis_size(axis)
     idx = lax.axis_index(axis)
     piece = a.shape[0] // n
     return {"Out": lax.dynamic_slice_in_dim(a, idx * piece, piece, axis=0)}
@@ -489,7 +487,7 @@ def _alltoall(ctx, ins, attrs):
     axis = _ring_axis(ctx, attrs)
     if axis is None:
         return {"Out": a}
-    n = axis_size(axis)
+    n = lax.axis_size(axis)
     parts = a.reshape((n, a.shape[0] // n) + a.shape[1:])
     return {"Out": lax.all_to_all(parts, axis, split_axis=0, concat_axis=0)
             .reshape(a.shape)}
@@ -556,7 +554,7 @@ def _collective_permute(ctx, ins, attrs):
     axis = _ring_axis(ctx, attrs)
     if axis is None:
         return {"Out": a}
-    n = axis_size(axis)
+    n = lax.axis_size(axis)
     shift = attrs.get("shift", 1)
     perm = [(i, (i + shift) % n) for i in range(n)]
     return {"Out": lax.ppermute(a, axis, perm)}

@@ -1235,11 +1235,12 @@ def _pl_adam_supported(ins, attrs, axis_sizes=None):
         return False, "shape-unknown"
     if not shapes[0] == shapes[1] == shapes[2]:
         return False, "param-grad-moment-shapes"
+    from .pallas.fused_ops import ADAM_MIN_NUMEL
     n = _numel(shapes[0])
     if n % 128:
         return False, f"numel:{n}%128"
-    if n < 1024:
-        return False, f"numel:{n}<1024"
+    if n < ADAM_MIN_NUMEL:
+        return False, f"numel:{n}<{ADAM_MIN_NUMEL}"
     return True, ""
 
 
@@ -1258,8 +1259,9 @@ def _pl_ln_supported(ins, attrs, axis_sizes=None):
     rd = _rows_last_dim(_sig(ins, "X"), attrs.get("begin_norm_axis", 1))
     if rd is None:
         return False, "shape-unknown"
+    from .pallas.fused_ops import LN_MAX_D
     _, d = rd
-    if d % 128 or d > 8192:
+    if d % 128 or d > LN_MAX_D:
         return False, f"norm-dim:{d}"
     return True, ""
 
@@ -1287,7 +1289,8 @@ def _pl_bias_gelu_supported(ins, attrs, axis_sizes=None):
     d = xs[-1]
     if d < 0:
         return False, "shape-unknown"
-    if d % 128 or d > 16384:
+    from .pallas.fused_ops import BG_MAX_D
+    if d % 128 or d > BG_MAX_D:
         return False, f"dim:{d}"
     return True, ""
 

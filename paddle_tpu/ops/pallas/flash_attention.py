@@ -408,10 +408,6 @@ def _reference(q, k, v, bias, causal=False):
                       preferred_element_type=jnp.float32).astype(v.dtype)
 
 
-# backends whose canonical lowering is the TPU Mosaic pipeline
-from . import TPU_BACKENDS as _TPU_BACKENDS
-
-
 def supported(shape_bhsd, k_seq=None, backend=None):
     """Static gate: can the kernel tile this (B, H, Sq, D) problem (with
     key/value sequence length ``k_seq``, defaulting to Sq)?  Mirrors
@@ -424,10 +420,8 @@ def supported(shape_bhsd, k_seq=None, backend=None):
     if d % 128 and d != 64:
         # lane dim must tile; 64 still packs efficiently as (8, 128)
         return False
-    if backend is None:
-        from . import effective_backend
-        backend = effective_backend()
-    return backend in _TPU_BACKENDS
+    from . import is_tpu_backend
+    return is_tpu_backend(backend)
 
 
 def flash_attention_bshd(q, k, v, bias=None, dropout_rate=0.0, seed=None,

@@ -18,9 +18,11 @@ cases where owning the schedule still pays on TPU:
 - ``adam_update``: m/v/param updated in ONE read/write pass per tensor
   with input/output aliasing (three separate HBM round-trips otherwise).
 
-All kernels carry a ``supported()`` predicate; callers fall back to the
-jnp composition off-TPU or at unsupported shapes.  Row counts need not
-tile: partial edge blocks mask their reduction contributions explicitly.
+The shape gates live in the registry's Pallas channel
+(ops/op_specs.py), which reads the bounds below; callers fall back to
+the jnp composition off-TPU or at unsupported shapes.  Row counts need
+not tile: partial edge blocks mask their reduction contributions
+explicitly.
 """
 
 from __future__ import annotations
@@ -32,12 +34,25 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
-BLOCK_R = 128          # row-block for [R, D] layouts
+BLOCK_R = 128          # row-block for [R, D] layouts at model widths
+
+#: widest last dim each [R, D] kernel family is admitted at — what the
+#: v5e was seen to compile (chip_smoke.py leg K compiles every family at
+#: its bound)
+LN_MAX_D = 8192
+BG_MAX_D = 16384
+#: fused Adam: below this a pallas_call costs more than it saves
+ADAM_MIN_NUMEL = 1024
 
 
-def _on_tpu() -> bool:
-    from . import is_tpu_backend
-    return is_tpu_backend()
+def _block_rows(d: int) -> int:
+    """Rows per (rows, D) block: BLOCK_R at model widths, fewer as D grows
+    so that one f32 block stays within 512 KiB.  The backward kernels hold
+    three such blocks double-buffered and about as much again in f32
+    temporaries, against the 16 MiB default scoped-VMEM limit: at
+    (128, 3072) the bias+GELU backward asked the v5e for 17.95 MiB and
+    was refused.  A multiple of 16, the bf16 sublane tile."""
+    return max(16, min(BLOCK_R, (1 << 17) // d // 16 * 16))
 
 
 def _row_mask(i, r_total, block_rows):
@@ -49,10 +64,6 @@ def _row_mask(i, r_total, block_rows):
 # ---------------------------------------------------------------------------
 # layer_norm
 # ---------------------------------------------------------------------------
-
-
-def ln_supported(r: int, d: int) -> bool:
-    return _on_tpu() and d % 128 == 0 and d <= 8192
 
 
 def _ln_fwd_kernel(x_ref, s_ref, b_ref, y_ref, *, eps):
@@ -102,14 +113,15 @@ def layer_norm(x2, scale, bias, eps=1e-5, interpret=False):
 
 def _ln_fwd(x2, scale, bias, eps, interpret):
     r, d = x2.shape
-    grid = (pl.cdiv(r, BLOCK_R),)
+    br = _block_rows(d)
+    grid = (pl.cdiv(r, br),)
     y = pl.pallas_call(
         functools.partial(_ln_fwd_kernel, eps=eps),
         grid=grid,
-        in_specs=[pl.BlockSpec((BLOCK_R, d), lambda i: (i, 0)),
+        in_specs=[pl.BlockSpec((br, d), lambda i: (i, 0)),
                   pl.BlockSpec((1, d), lambda i: (0, 0)),
                   pl.BlockSpec((1, d), lambda i: (0, 0))],
-        out_specs=pl.BlockSpec((BLOCK_R, d), lambda i: (i, 0)),
+        out_specs=pl.BlockSpec((br, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((r, d), x2.dtype),
         interpret=interpret,
     )(x2, scale.reshape(1, d), bias.reshape(1, d))
@@ -119,14 +131,15 @@ def _ln_fwd(x2, scale, bias, eps, interpret):
 def _ln_bwd(eps, interpret, res, dy):
     x2, scale = res
     r, d = x2.shape
-    grid = (pl.cdiv(r, BLOCK_R),)
+    br = _block_rows(d)
+    grid = (pl.cdiv(r, br),)
     dx, ds, db = pl.pallas_call(
         functools.partial(_ln_bwd_kernel, eps=eps, r_total=r),
         grid=grid,
-        in_specs=[pl.BlockSpec((BLOCK_R, d), lambda i: (i, 0)),
+        in_specs=[pl.BlockSpec((br, d), lambda i: (i, 0)),
                   pl.BlockSpec((1, d), lambda i: (0, 0)),
-                  pl.BlockSpec((BLOCK_R, d), lambda i: (i, 0))],
-        out_specs=[pl.BlockSpec((BLOCK_R, d), lambda i: (i, 0)),
+                  pl.BlockSpec((br, d), lambda i: (i, 0))],
+        out_specs=[pl.BlockSpec((br, d), lambda i: (i, 0)),
                    pl.BlockSpec((1, d), lambda i: (0, 0)),
                    pl.BlockSpec((1, d), lambda i: (0, 0))],
         out_shape=[jax.ShapeDtypeStruct((r, d), x2.dtype),
@@ -197,14 +210,15 @@ def add_layer_norm(a2, b2, scale, bias, eps=1e-5, interpret=False):
 
 def _aln_fwd(a2, b2, scale, bias, eps, interpret):
     r, d = a2.shape
+    br = _block_rows(d)
     y = pl.pallas_call(
         functools.partial(_aln_fwd_kernel, eps=eps),
-        grid=(pl.cdiv(r, BLOCK_R),),
-        in_specs=[pl.BlockSpec((BLOCK_R, d), lambda i: (i, 0)),
-                  pl.BlockSpec((BLOCK_R, d), lambda i: (i, 0)),
+        grid=(pl.cdiv(r, br),),
+        in_specs=[pl.BlockSpec((br, d), lambda i: (i, 0)),
+                  pl.BlockSpec((br, d), lambda i: (i, 0)),
                   pl.BlockSpec((1, d), lambda i: (0, 0)),
                   pl.BlockSpec((1, d), lambda i: (0, 0))],
-        out_specs=pl.BlockSpec((BLOCK_R, d), lambda i: (i, 0)),
+        out_specs=pl.BlockSpec((br, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((r, d), a2.dtype),
         interpret=interpret,
     )(a2, b2, scale.reshape(1, d), bias.reshape(1, d))
@@ -214,14 +228,15 @@ def _aln_fwd(a2, b2, scale, bias, eps, interpret):
 def _aln_bwd(eps, interpret, res, dy):
     a2, b2, scale = res
     r, d = a2.shape
+    br = _block_rows(d)
     dx, ds, db = pl.pallas_call(
         functools.partial(_aln_bwd_kernel, eps=eps, r_total=r),
-        grid=(pl.cdiv(r, BLOCK_R),),
-        in_specs=[pl.BlockSpec((BLOCK_R, d), lambda i: (i, 0)),
-                  pl.BlockSpec((BLOCK_R, d), lambda i: (i, 0)),
+        grid=(pl.cdiv(r, br),),
+        in_specs=[pl.BlockSpec((br, d), lambda i: (i, 0)),
+                  pl.BlockSpec((br, d), lambda i: (i, 0)),
                   pl.BlockSpec((1, d), lambda i: (0, 0)),
-                  pl.BlockSpec((BLOCK_R, d), lambda i: (i, 0))],
-        out_specs=[pl.BlockSpec((BLOCK_R, d), lambda i: (i, 0)),
+                  pl.BlockSpec((br, d), lambda i: (i, 0))],
+        out_specs=[pl.BlockSpec((br, d), lambda i: (i, 0)),
                    pl.BlockSpec((1, d), lambda i: (0, 0)),
                    pl.BlockSpec((1, d), lambda i: (0, 0))],
         out_shape=[jax.ShapeDtypeStruct((r, d), a2.dtype),
@@ -243,15 +258,27 @@ add_layer_norm.defvjp(
 # ---------------------------------------------------------------------------
 
 
+def _erf_f32(x):
+    # Mosaic (jax 0.9.0) has no lowering for lax.erf: Abramowitz & Stegun
+    # 7.1.26, |error| <= 1.5e-7 — f32 rounding level, so the fused path
+    # still matches the stock erf GELU
+    ax = jnp.abs(x)
+    t = 1.0 / (1.0 + 0.3275911 * ax)
+    poly = t * (0.254829592 + t * (-0.284496736 + t * (
+        1.421413741 + t * (-1.453152027 + t * 1.061405429))))
+    y = 1.0 - poly * jnp.exp(-ax * ax)
+    return jnp.where(x < 0, -y, y)
+
+
 def _gelu_f32(u):
     # EXACT erf GELU — must match the stock gelu op (math_ops.py uses
     # jax.nn.gelu(approximate=False)); a tanh approximation here would
     # silently change numerics between fused/unfused paths
-    return 0.5 * u * (1.0 + lax.erf(u * 0.7071067811865476))
+    return 0.5 * u * (1.0 + _erf_f32(u * 0.7071067811865476))
 
 
 def _dgelu_f32(u):
-    cdf = 0.5 * (1.0 + lax.erf(u * 0.7071067811865476))
+    cdf = 0.5 * (1.0 + _erf_f32(u * 0.7071067811865476))
     pdf = 0.3989422804014327 * jnp.exp(-0.5 * u * u)   # 1/sqrt(2π)
     return cdf + u * pdf
 
@@ -277,10 +304,6 @@ def _bg_bwd_kernel(x_ref, b_ref, dy_ref, dx_ref, db_ref, *, r_total):
     db_ref[...] += jnp.sum(dx, axis=0, keepdims=True).astype(db_ref.dtype)
 
 
-def bg_supported(r: int, d: int) -> bool:
-    return _on_tpu() and d % 128 == 0 and d <= 16384
-
-
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
 def bias_gelu(x2, bias, interpret=False):
     """gelu(x2 + bias) fused, x2 [R, D], bias [D]."""
@@ -290,12 +313,13 @@ def bias_gelu(x2, bias, interpret=False):
 
 def _bg_fwd(x2, bias, interpret):
     r, d = x2.shape
+    br = _block_rows(d)
     y = pl.pallas_call(
         _bg_fwd_kernel,
-        grid=(pl.cdiv(r, BLOCK_R),),
-        in_specs=[pl.BlockSpec((BLOCK_R, d), lambda i: (i, 0)),
+        grid=(pl.cdiv(r, br),),
+        in_specs=[pl.BlockSpec((br, d), lambda i: (i, 0)),
                   pl.BlockSpec((1, d), lambda i: (0, 0))],
-        out_specs=pl.BlockSpec((BLOCK_R, d), lambda i: (i, 0)),
+        out_specs=pl.BlockSpec((br, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((r, d), x2.dtype),
         interpret=interpret,
     )(x2, bias.reshape(1, d))
@@ -305,13 +329,14 @@ def _bg_fwd(x2, bias, interpret):
 def _bg_bwd(interpret, res, dy):
     x2, bias = res
     r, d = x2.shape
+    br = _block_rows(d)
     dx, db = pl.pallas_call(
         functools.partial(_bg_bwd_kernel, r_total=r),
-        grid=(pl.cdiv(r, BLOCK_R),),
-        in_specs=[pl.BlockSpec((BLOCK_R, d), lambda i: (i, 0)),
+        grid=(pl.cdiv(r, br),),
+        in_specs=[pl.BlockSpec((br, d), lambda i: (i, 0)),
                   pl.BlockSpec((1, d), lambda i: (0, 0)),
-                  pl.BlockSpec((BLOCK_R, d), lambda i: (i, 0))],
-        out_specs=[pl.BlockSpec((BLOCK_R, d), lambda i: (i, 0)),
+                  pl.BlockSpec((br, d), lambda i: (i, 0))],
+        out_specs=[pl.BlockSpec((br, d), lambda i: (i, 0)),
                    pl.BlockSpec((1, d), lambda i: (0, 0))],
         out_shape=[jax.ShapeDtypeStruct((r, d), x2.dtype),
                    jax.ShapeDtypeStruct((1, d), jnp.float32)],
@@ -338,10 +363,6 @@ def _adam_kernel(lr_ref, p_ref, g_ref, m_ref, v_ref, po_ref, mo_ref,
     po_ref[...] = p.astype(po_ref.dtype)
     mo_ref[...] = m.astype(mo_ref.dtype)
     vo_ref[...] = v.astype(vo_ref.dtype)
-
-
-def adam_supported(size: int) -> bool:
-    return _on_tpu() and size % 128 == 0 and size >= 1024
 
 
 def adam_update(p, g, m, v, lr_t, *, beta1, beta2, eps, interpret=False):

@@ -20,10 +20,11 @@ elements (int8) or ``block_size/2`` byte-packed pairs (int4); scales
 arrive as (n·blocks, 1) f32 columns (row stats live as (rows, 1), the
 same TPU-tiling idiom as the flash kernel's lse).
 
-int4 nibbles are sign-extended in-kernel via arithmetic shifts
-(``(q << 4) >> 4`` / ``q >> 4``) but NOT re-interleaved: the kernel
-emits separate even/odd-element sums (lo = elements 0::2 of each block,
-hi = 1::2) and the host-side wrapper interleaves the small f32 result —
+int4 nibbles are sign-extended in-kernel via arithmetic shifts on the
+int32-widened byte (``(q << 28) >> 28`` / ``q >> 4``) but NOT
+re-interleaved: the kernel emits separate even/odd-element sums (lo =
+elements 0::2 of each block, hi = 1::2) and the host-side wrapper
+interleaves the small f32 result —
 one cheap stack/reshape on shard-sized data instead of a lane shuffle
 inside the kernel.
 
@@ -58,7 +59,7 @@ def supported(n_peers: int, num_blocks: int, spec, backend=None):
     contributions of ``num_blocks`` quantization blocks under
     ``spec``?  Returns (ok, reason) — mirrors exactly what the kernels
     reject, so routing dispatches without try/except."""
-    from . import TPU_BACKENDS, effective_backend
+    from . import effective_backend, is_tpu_backend
     if spec.dtype not in ("int8", "int4"):
         return False, f"wire-dtype:{spec.dtype}"
     cols = _payload_cols(spec)
@@ -71,7 +72,7 @@ def supported(n_peers: int, num_blocks: int, spec, backend=None):
     if BLOCK_ROWS * cols * 5 > _TILE_BYTES_MAX:
         return False, f"tile-bytes:{BLOCK_ROWS * cols}"
     backend = backend or effective_backend()
-    if backend not in TPU_BACKENDS:
+    if not is_tpu_backend(backend):
         return False, f"backend:{backend}"
     return True, ""
 
@@ -82,7 +83,10 @@ def _dq_tile(q_ref, s_ref, *, int4):
     q = q_ref[0]                                   # (BR, C) int8
     s = s_ref[0]                                   # (BR, 1) f32
     if int4:
-        lo = ((q << 4) >> 4).astype(jnp.float32) * s
+        # shift in int32: Mosaic (jax 0.9.0) does not legalize shifts on
+        # int8 vectors ('arith.shli' on vector<..xi8>, v5e)
+        q = q.astype(jnp.int32)
+        lo = ((q << 28) >> 28).astype(jnp.float32) * s
         hi = (q >> 4).astype(jnp.float32) * s
         return lo, hi
     return q.astype(jnp.float32) * s, None

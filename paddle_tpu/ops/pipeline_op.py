@@ -23,8 +23,6 @@ from jax import lax
 
 from .registry import register, LoweringContext
 
-from ..framework.jax_compat import axis_size
-
 
 def _run_segment(seg_ops, env, ctx):
     from ..framework.executor import run_ops
@@ -68,7 +66,7 @@ def _pipeline_op(ctx, ins, attrs):
         return {"Loss": jnp.mean(losses)}
 
     idx = lax.axis_index(axis)
-    n_pp = axis_size(axis)
+    n_pp = lax.axis_size(axis)
     if n_pp != S:
         raise ValueError(f"pipeline has {S} stages but pp axis size {n_pp}")
     perm = [(i, i + 1) for i in range(S - 1)]     # no wrap: stage0 gets zeros

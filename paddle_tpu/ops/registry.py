@@ -188,7 +188,7 @@ def pallas_route(op_type: str, ins, attrs, axis_sizes=None, backend=None,
         if not enabled:
             reasons.append(f"flag:{route.flag}=off")
             continue
-        if backend not in _pallas.TPU_BACKENDS:
+        if not _pallas.is_tpu_backend(backend):
             reasons.append(f"backend:{backend}")
             continue
         ok, why = (True, "") if route.supported is None else \

@@ -85,7 +85,7 @@ def xxh64_int64_rows(vals, seed):
     device feed path stores int64 ids as int32, so ids >= 2^31 reach this
     function already truncated and bucket differently from the reference
     (MIGRATION.md "Known gaps" scopes the compat claim accordingly)."""
-    from ..framework.jax_compat import enable_x64
+    from jax import enable_x64
 
     with enable_x64(True):
         u64 = jnp.uint64
@@ -137,7 +137,7 @@ def xxh64_mod(vals, seed, mod_by):
     """``XXH64(row bytes, seed) % mod_by`` as an int32 bucket index —
     the remainder is taken in true 64-bit inside the x64 scope, then the
     (< mod_by) result is safe to carry back to 32-bit mode."""
-    from ..framework.jax_compat import enable_x64
+    from jax import enable_x64
 
     hi, lo = xxh64_int64_rows(vals, seed)
     with enable_x64(True):

@@ -24,8 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..framework.jax_compat import axis_size
-
 from ..framework.core import (default_main_program, Variable)
 from ..framework import core as _core
 from ..optimizer import Optimizer
@@ -48,7 +46,7 @@ def gpipe_spmd(stage_fn: Callable, stage_params, microbatches,
       microbatches: [M, mb, ...] — full input stream (only stage 0 reads it).
     Returns [M, mb, ...] outputs, replicated over the pp axis.
     """
-    S = axis_size(axis_name)
+    S = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     M = microbatches.shape[0]
     T = M + S - 1

@@ -36,7 +36,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..framework.jax_compat import axis_size
 
 _NEG = -1e30
 
@@ -82,7 +81,7 @@ def ring_attention(q, k, v, axis_name: str,
 
     Returns [B, H, S_local, D].
     """
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     my_idx = lax.axis_index(axis_name)
     b, h, s_loc, d = q.shape
     scale = 1.0 / jnp.sqrt(jnp.asarray(d, jnp.float32))
@@ -93,13 +92,7 @@ def ring_attention(q, k, v, axis_name: str,
     def _vary(t):
         # mark freshly-created accumulators as varying over the sp axis so
         # the scan carry types match (shard_map VMA tracking)
-        try:
-            return lax.pcast(t, (axis_name,), to="varying")
-        except (AttributeError, TypeError):   # older jax: no VMA tracking
-            try:
-                return lax.pvary(t, (axis_name,))
-            except AttributeError:
-                return t
+        return lax.pcast(t, (axis_name,), to="varying")
 
     m0 = _vary(jnp.full((b, h, s_loc), -jnp.inf, jnp.float32))
     l0 = _vary(jnp.zeros((b, h, s_loc), jnp.float32))

@@ -456,14 +456,16 @@ class DecodeEngine:
                     cache_vars=self._programs.cache_vars
                 ).raise_on_error()
 
-        # isolated weight snapshot for the reference loop — host copies,
-        # taken BEFORE the donated fast path can consume scope buffers
+        # isolated weight snapshot for the reference loop — fresh device
+        # buffers (a host copy would re-cross the host link on every
+        # scored token), taken BEFORE the donated fast path can consume
+        # the scope's own buffers
         self._ref_scope = Scope()
         for name in self._scope.var_names():
             if name in self._programs.cache_vars:
                 continue
             self._ref_scope.set_var(
-                name, np.asarray(self._scope.find_var(name)))
+                name, jnp.array(self._scope.find_var(name), copy=True))
 
         fetches = list(self._programs.fetch_names)
         self._prefill = self._exe.prepare(
@@ -1295,6 +1297,32 @@ class DecodeEngine:
             raise InvalidArgumentError(
                 f"prompt ({prompt.size}) + max_new_tokens ({max_new}) "
                 f"exceeds max_seq_len={cfg.max_seq_len}")
+        seq = list(int(t) for t in prompt)
+        out_tokens: List[int] = []
+        reason = "length"
+        for _ in range(max_new):
+            tok = int(self._score_prefix(seq)[1].numpy()[0])
+            out_tokens.append(tok)
+            seq.append(tok)
+            if eos is not None and tok == eos:
+                reason = "eos"
+                break
+        return GenerationResult(out_tokens, int(prompt.size), reason,
+                                len(out_tokens))
+
+    def _score_prefix(self, seq):
+        """One cache-free scoring pass over the token prefix ``seq``
+        (padded to the score-bucket ladder) on the reference weight
+        snapshot: the ``[next_logits, next_tokens]`` fetch handles."""
+        cur = len(seq)
+        sb = next(b for b in self._score_buckets() if b >= cur)
+        src = np.zeros((1, sb), np.int64)
+        src[0, :cur] = seq
+        pos = np.zeros((1, sb), np.int64)
+        pos[0, :cur] = np.arange(cur)
+        mask = np.zeros((1, sb, 1), np.float32)
+        mask[0, :cur, 0] = 1.0
+        last = np.full((1, 1), cur - 1, np.int64)
         with self._ref_lock:
             if self._score is None:
                 self._score = self._exe.prepare(
@@ -1302,31 +1330,21 @@ class DecodeEngine:
                     feed_names=self._programs.score_feeds,
                     fetch_list=list(self._programs.fetch_names),
                     scope=self._ref_scope, donate_state=False)
-            seq = list(int(t) for t in prompt)
-            out_tokens: List[int] = []
-            reason = "length"
-            buckets = self._score_buckets()
-            for _ in range(max_new):
-                cur = len(seq)
-                sb = next(b for b in buckets if b >= cur)
-                src = np.zeros((1, sb), np.int64)
-                src[0, :cur] = seq
-                pos = np.zeros((1, sb), np.int64)
-                pos[0, :cur] = np.arange(cur)
-                mask = np.zeros((1, sb, 1), np.float32)
-                mask[0, :cur, 0] = 1.0
-                last = np.full((1, 1), cur - 1, np.int64)
-                handles = self._score.run({
-                    "src_ids": src, "pos_ids": pos, "input_mask": mask,
-                    "last_pos": last})
-                tok = int(handles[1].numpy()[0])
-                out_tokens.append(tok)
-                seq.append(tok)
-                if eos is not None and tok == eos:
-                    reason = "eos"
-                    break
-        return GenerationResult(out_tokens, int(prompt.size), reason,
-                                len(out_tokens))
+            return self._score.run({
+                "src_ids": src, "pos_ids": pos, "input_mask": mask,
+                "last_pos": last})
+
+    def reference_logits(self, tokens) -> np.ndarray:
+        """Next-token logits ``[vocab]`` the parity oracle assigns after
+        the prefix ``tokens`` — what a caller needs to judge a token
+        mismatch against the reference's own top-2 margin on a backend
+        whose matmuls round (the chip's bf16-input f32 matmuls)."""
+        seq = [int(t) for t in self._normalize_prompt(tokens)]
+        if len(seq) > self.config.max_seq_len:
+            raise InvalidArgumentError(
+                f"prefix ({len(seq)} tokens) exceeds "
+                f"max_seq_len={self.config.max_seq_len}")
+        return self._score_prefix(seq)[0].numpy()[0]
 
     # -- observability ----------------------------------------------------
     @property

@@ -118,14 +118,18 @@ class OpTest:
             exe.run(startup)
             analytic = exe.run(main, feed=feed, fetch_list=grad_names)
 
-        # numeric: central differences on a scalar function of each input
+        # numeric: central differences on a scalar function of each
+        # input — ONE forward program and executor for every evaluation
+        # (a fresh build per evaluation recompiled 2x per element)
+        exe2 = fluid.Executor(fluid.CPUPlace())
+        s2 = fluid.Scope()
+        main2, startup2, _, out_vars2 = self._build_program(
+            inputs, attrs, {output_slot: out_index + 1})
+        with fluid.scope_guard(s2):
+            exe2.run(startup2)
+
         def run_sum(feed_over):
-            exe2 = fluid.Executor(fluid.CPUPlace())
-            s2 = fluid.Scope()
-            main2, startup2, _, out_vars2 = self._build_program(
-                inputs, attrs, {output_slot: out_index + 1})
             with fluid.scope_guard(s2):
-                exe2.run(startup2)
                 r = exe2.run(main2, feed=feed_over,
                              fetch_list=[out_vars2[output_slot][out_index]])
             return float(np.sum(r[0]))

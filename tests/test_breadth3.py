@@ -8,7 +8,7 @@ import jax
 import jax.numpy as jnp
 
 from paddle_tpu.ops.registry import OPS, LoweringContext
-from paddle_tpu.framework.jax_compat import shard_map
+from jax import shard_map
 
 
 def _ctx(**kw):

@@ -8,7 +8,7 @@ import numpy as np
 import paddle_tpu.fluid as fluid
 from paddle_tpu.framework.core import Program, program_guard
 from paddle_tpu.framework.compiler import make_mesh
-from paddle_tpu.framework.jax_compat import shard_map
+from jax import shard_map
 
 
 def _build(seed=0):

@@ -297,6 +297,13 @@ def test_device_peak_flops_table_and_flag():
         device_kind = "TPU v5 lite"
 
     assert flops.device_peak_flops(_Dev()) == 197e12
+
+    class _Unknown:
+        platform = "tpu"
+        device_kind = "TPU v99"
+
+    with pytest.raises(ValueError, match="TPU v99"):
+        flops.device_peak_flops(_Unknown())
     old = get_flags(["device_peak_flops"])
     set_flags({"device_peak_flops": 123.0})
     try:

@@ -1,4 +1,4 @@
-"""Fused Pallas kernel numerics (interpret mode on CPU; tools/tpu_smoke.py
+"""Fused Pallas kernel numerics (interpret mode on CPU; chip_smoke.py leg K
 re-validates on hardware).  Reference: the jnp compositions these kernels
 replace (ref CUDA analogs: operators/fused/ fused_elemwise kernels,
 optimizers/adam_op.cu)."""

@@ -215,7 +215,7 @@ def _sp_mesh(n):
 
 def test_ring_attention_flash_matches_einsum_composition():
     from jax.sharding import PartitionSpec as P
-    from paddle_tpu.framework.jax_compat import shard_map
+    from jax import shard_map
     from paddle_tpu.parallel.ring_attention import ring_attention
 
     mesh = _sp_mesh(4)
@@ -245,7 +245,7 @@ def test_ring_attention_flash_matches_einsum_composition():
 
 def test_ring_attention_flash_grads_match():
     from jax.sharding import PartitionSpec as P
-    from paddle_tpu.framework.jax_compat import shard_map
+    from jax import shard_map
     from paddle_tpu.parallel.ring_attention import ring_attention
 
     mesh = _sp_mesh(4)

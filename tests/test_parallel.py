@@ -10,7 +10,7 @@ import jax
 import jax.numpy as jnp
 
 import paddle_tpu.fluid as fluid
-from paddle_tpu.framework.jax_compat import shard_map
+from jax import shard_map
 from paddle_tpu.framework.core import Program, program_guard
 from paddle_tpu import parallel
 from paddle_tpu.parallel import build_mesh

@@ -1,6 +1,10 @@
 #!/usr/bin/env python
-"""Decode-engine bench — the ISSUE 16 acceptance artifact (decode fast
-path v2).
+"""Decode-engine contract gate — the ISSUE 16 acceptance artifact (decode
+fast path v2).  CPU-ONLY: it pins ``JAX_PLATFORMS=cpu`` (its restart leg
+starts child processes after the parent has touched JAX, and a chip
+belongs to one process), so its tokens/s ratios are CPU stopwatch
+readings next to parity and count contracts, never device numbers.  The
+engine's run on the chip is chip_smoke.py leg B.
 
 Six legs on the CPU BERT-tiny-decoder (the "before" shape is the
 reference's serving story: a per-request greedy loop that re-scores the
@@ -72,7 +76,7 @@ import numpy as np
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+os.environ["JAX_PLATFORMS"] = "cpu"       # see module docstring
 
 SCHEMA = "paddle_tpu.decode_bench/2"
 ARTIFACT = "DECODE_BENCH_r20.json"

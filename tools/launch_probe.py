@@ -224,7 +224,9 @@ print(f"rank {{rank}} agreed", flush=True)
 
 def _rendezvous_drill(timeout=120):
     """Two real processes; rank 1 arms the seam; both must abort with
-    exit 43 naming the op, within the timeout (no hang)."""
+    exit 43 naming the op, within the timeout (no hang).  Both are CPU
+    processes — the drill needs no chip, and two processes could not
+    share one."""
     d = tempfile.mkdtemp(prefix="launch_drill_")
     ep = os.path.join(d, "endpoint")
     script = _CHILD.format(repo=REPO, ep=ep)

@@ -96,10 +96,12 @@ python tools/verify_multichip_lowering.py --fsdp
 echo "== preflight: dryrun_multichip(8) =="
 python -c "import __graft_entry__ as g; g.dryrun_multichip(8)"
 
+echo "== preflight: chip_smoke dry run (every leg of the chip script at"
+echo "   tiny width on the CPU; the real run needs the chip) =="
+python chip_smoke.py --cpu-dry-run
+
 echo "== preflight: entry() compile-check =="
-python - <<'EOF'
-import os
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+JAX_PLATFORMS=cpu python - <<'EOF'
 import jax
 import __graft_entry__ as g
 fn, args = g.entry()

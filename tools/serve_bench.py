@@ -1,5 +1,9 @@
 #!/usr/bin/env python
-"""Serving bench — the ISSUE 7 "Serving v2" acceptance artifact.
+"""Serving contract gate — the ISSUE 7 "Serving v2" acceptance artifact.
+CPU-ONLY: it pins ``JAX_PLATFORMS=cpu`` (its AOT-cache leg starts child
+processes after the parent has touched JAX, and a chip belongs to one
+process), so its throughput ratios are CPU stopwatch readings next to
+parity and count contracts, never device numbers.
 
 Three legs on the CPU BERT-tiny encoder (before-numbers: the PR 4
 artifact ``SERVE_BENCH_r08.json`` — 44.7 % padding waste, steady-state
@@ -45,7 +49,7 @@ import numpy as np
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+os.environ["JAX_PLATFORMS"] = "cpu"       # see module docstring
 
 SEQ_FEEDS = ("src_ids", "pos_ids", "sent_ids", "input_mask")
 
