@@ -179,15 +179,21 @@ def run_ops(ops, env, ctx):
                 # wrapped below into the same EnforceNotMet a real
                 # lowering failure produces
                 _faultline.crossing("collective_impl", op=op.type)
-            if traced:
-                # trace-time collective spans (once per compile, zero
-                # steady-state cost): kind/axis/wire bytes land on the
-                # timeline correlated to the compiling step's id
-                from ..ops.collective_ops import maybe_trace_collective
-                with maybe_trace_collective(op, ins, ctx):
+            # the op's type on the name stack (the analog of the
+            # reference's RecordEvent(Type()) in OperatorBase::Run):
+            # every HLO instruction's op_name then says which Fluid op
+            # it came from.  Trace time only.
+            with jax.named_scope(op.type):
+                if traced:
+                    # trace-time collective spans (once per compile,
+                    # zero steady-state cost): kind/axis/wire bytes land
+                    # on the timeline correlated to the compiling step's
+                    # id
+                    from ..ops.collective_ops import maybe_trace_collective
+                    with maybe_trace_collective(op, ins, ctx):
+                        outs = impl(ctx, ins, op.attrs)
+                else:
                     outs = impl(ctx, ins, op.attrs)
-            else:
-                outs = impl(ctx, ins, op.attrs)
         except EnforceNotMet:
             raise
         except (KeyboardInterrupt, SystemExit):

@@ -243,16 +243,28 @@ def prometheus_text(prefix: str = "paddle_tpu") -> str:
         else:
             head(pname, m.kind)
             lines.append(f"{pname}{lbl} {_prom_num(m.snapshot()['value'])}")
-    # serving tier: live engine stats as gauges labeled by engine index
+    # serving tier: live engine stats as gauges labeled by engine index;
+    # a dict of numbers (a histogram, the decode worker's phase_ns) is
+    # one series per key
     from ..profiler import serving_stats
+
+    def numeric(v):
+        return isinstance(v, (int, float)) and not isinstance(v, bool)
+
     for i, stats in enumerate(serving_stats()):
         for k, v in stats.items():
-            if isinstance(v, bool) or not isinstance(v, (int, float)):
-                continue
             pname = f"{prefix}_serving_{_prom_name(k)}"
-            head(pname, "gauge")
-            lines.append(f"{pname}{_prom_labels({'engine': i})} "
-                         f"{_prom_num(v)}")
+            if numeric(v):
+                series = [({"engine": i}, v)]
+            elif isinstance(v, dict):
+                series = [({"engine": i, "key": kk}, vv)
+                          for kk, vv in v.items() if numeric(vv)]
+            else:
+                continue
+            for labels, value in series:
+                head(pname, "gauge")
+                lines.append(f"{pname}{_prom_labels(labels)} "
+                             f"{_prom_num(value)}")
     return "\n".join(lines) + "\n"
 
 

@@ -28,11 +28,20 @@ on:
   crash flight recorder (``observability/flight.py``) snapshots into
   its diagnostic bundle.
 
+* **the profiler's clock** — whenever a ``jax.profiler`` session is
+  running (``profiler.start_profiler(trace_dir=...)`` or a bare
+  ``jax.profiler.start_trace``), every span also opens a
+  ``jax.profiler.TraceAnnotation`` under its own name, so it lands in
+  the xplane's host plane on the device trace's timeline.  This does
+  NOT depend on the enable flag below: a device trace taken by someone
+  else still sees the program's phases.
+
 Disabled-path cost is the contract the prepared hot loop depends on
 (≤5 % of the 10 μs/step PR-2 baseline, asserted by
 tests/test_observability.py): ``Span.__enter__``/``__exit__`` reduce to
-one module-global bool test, and ``next_step_id`` to one list-slot
-increment.
+one module-global bool test plus one ``TraceMe.is_enabled()`` call
+(~75 ns; constructing an inactive ``TraceAnnotation`` would cost ~400),
+and ``next_step_id`` to one list-slot increment.
 """
 
 from __future__ import annotations
@@ -42,6 +51,11 @@ import contextlib
 import threading
 import time
 from typing import Any, Dict, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation as _TraceAnnotation
+
+#: True while any jax.profiler session is collecting host events
+_session_active = _TraceAnnotation.is_enabled
 
 # (name, start_ns, end_ns, tid, attrs-or-None) — the profiler's event
 # buffer lives HERE now; profiler.py re-exports its legacy API over it
@@ -111,9 +125,10 @@ def step_scope(step_id: int):
 class Span:
     """RAII span.  ``attrs`` (or keyword attributes) land in the trace's
     ``args``; ``step_id`` is attached automatically at close.  Cheap
-    no-op while tracing is disabled — one bool test per enter/exit."""
+    no-op while tracing is disabled and no profiler session runs — one
+    bool test and one ``is_enabled()`` per enter, two tests per exit."""
 
-    __slots__ = ("name", "attrs", "_start")
+    __slots__ = ("name", "attrs", "_start", "_ann")
 
     def __init__(self, name: str, attrs: Optional[Dict[str, Any]] = None,
                  **kw):
@@ -123,20 +138,36 @@ class Span:
             attrs.update(kw)
         self.attrs = attrs
         self._start = None
+        self._ann = None
+
+    @property
+    def recording(self) -> bool:
+        """Whether anything keeps this (entered) span — lets a caller
+        skip building an expensive attribute nobody will read."""
+        return self._start is not None or self._ann is not None
 
     def set(self, **kw):
         """Attach attributes discovered mid-span (e.g. cache hit/miss)."""
         if self.attrs is None:
             self.attrs = {}
         self.attrs.update(kw)
+        if self._ann is not None:
+            self._ann.set_metadata(**kw)
         return self
 
     def __enter__(self):
         if _enabled:
             self._start = time.perf_counter_ns()
+        if _session_active():
+            self._ann = _TraceAnnotation(
+                self.name, step_id=current_step_id(), **(self.attrs or {}))
+            self._ann.__enter__()
         return self
 
     def __exit__(self, *exc):
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+            self._ann = None
         if self._start is not None:
             end = time.perf_counter_ns()
             tid = threading.get_ident()

@@ -1378,7 +1378,7 @@ def _pl_cached_supported(ins, attrs, axis_sizes=None):
     return _flash_tiles(q[1], t, hd // n_head)
 
 
-_FLASH_KERNELS = ("_fwd_kernel", "_bwd_dq_kernel", "_bwd_dkv_kernel")
+_FLASH_KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
 
 #: the Pallas tier, one route table entry per op (kernel names are the
 #: census contract: each must appear as a tpu_custom_call kernel_name in
@@ -1407,31 +1407,31 @@ _PL_CACHED = PallasLowering(
 _PL_ADAM = PallasLowering(
     "fused_adam", flag="use_pallas_fused",
     supported=_pl_adam_supported,
-    kernels=("_adam_kernel",))
+    kernels=("fused_adam",))
 _PL_LN = PallasLowering(
     "fused_layer_norm", flag="use_pallas_fused",
     supported=_pl_ln_supported,
-    kernels=("_ln_fwd_kernel", "_ln_bwd_kernel"))
+    kernels=("fused_layer_norm_fwd", "fused_layer_norm_bwd"))
 _PL_ADD_LN = PallasLowering(
     "fused_add_layer_norm", flag="use_pallas_fused",
     supported=_pl_add_ln_supported,
-    kernels=("_aln_fwd_kernel", "_aln_bwd_kernel"))
+    kernels=("fused_add_layer_norm_fwd", "fused_add_layer_norm_bwd"))
 _PL_BIAS_GELU = PallasLowering(
     "fused_bias_gelu", flag="use_pallas_fused",
     supported=_pl_bias_gelu_supported,
-    kernels=("_bg_fwd_kernel", "_bg_bwd_kernel"))
+    kernels=("fused_bias_gelu_fwd", "fused_bias_gelu_bwd"))
 _PL_MHM = PallasLowering(
     "flash_attention", flag="use_flash_attention",
     supported=_pl_mhm_supported,
-    kernels=("_fwd_kernel",))
+    kernels=("flash_fwd",))
 _PL_DEQUANT_ACC = PallasLowering(
     "dequant_accumulate", flag="use_pallas_fused",
     supported=_pl_dequant_acc_supported,
-    kernels=("_dq_acc_kernel",))
+    kernels=("dequant_accumulate",))
 _PL_DEQUANT_ACC_AR = PallasLowering(
     "dequant_accumulate", flag="use_pallas_fused",
     supported=_pl_dequant_acc_supported,
-    kernels=("_dq_acc_kernel", "_dq_acc_requant_kernel"))
+    kernels=("dequant_accumulate", "dequant_accumulate_requant"))
 
 
 def register_default_specs():

@@ -282,6 +282,7 @@ def _flash_fwd(q, k, v, bias, seed, rate, causal, interpret):
             flops=flops, bytes_accessed=q.size * 4 * 3,
             transcendentals=bh * sq * sk),
         interpret=interpret,
+        name="flash_fwd",
     )(seed, q, k, v, barg)
 
 
@@ -325,6 +326,7 @@ def _flash_bwd(q, k, v, bias, seed, o, lse, g, rate, causal, interpret,
             flops=2 * flops, bytes_accessed=q.size * 4 * 4,
             transcendentals=bh * sq * sk),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(seed, q, k, v, barg, g, lse, delta)
 
     # dkv grid: (b, kv block, q block) — q axis innermost for accumulation
@@ -349,6 +351,7 @@ def _flash_bwd(q, k, v, bias, seed, o, lse, g, rate, causal, interpret,
             flops=2 * flops, bytes_accessed=q.size * 4 * 4,
             transcendentals=bh * sq * sk),
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(seed, q, k, v, barg_t, g, lse, delta)
     return dq, dk, dv
 

@@ -124,6 +124,7 @@ def _ln_fwd(x2, scale, bias, eps, interpret):
         out_specs=pl.BlockSpec((br, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((r, d), x2.dtype),
         interpret=interpret,
+        name="fused_layer_norm_fwd",
     )(x2, scale.reshape(1, d), bias.reshape(1, d))
     return y, (x2, scale)
 
@@ -146,6 +147,7 @@ def _ln_bwd(eps, interpret, res, dy):
                    jax.ShapeDtypeStruct((1, d), jnp.float32),
                    jax.ShapeDtypeStruct((1, d), jnp.float32)],
         interpret=interpret,
+        name="fused_layer_norm_bwd",
     )(x2, scale.reshape(1, d), dy)
     return dx, ds.reshape(d).astype(scale.dtype), \
         db.reshape(d).astype(scale.dtype)
@@ -221,6 +223,7 @@ def _aln_fwd(a2, b2, scale, bias, eps, interpret):
         out_specs=pl.BlockSpec((br, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((r, d), a2.dtype),
         interpret=interpret,
+        name="fused_add_layer_norm_fwd",
     )(a2, b2, scale.reshape(1, d), bias.reshape(1, d))
     return y, (a2, b2, scale)
 
@@ -243,6 +246,7 @@ def _aln_bwd(eps, interpret, res, dy):
                    jax.ShapeDtypeStruct((1, d), jnp.float32),
                    jax.ShapeDtypeStruct((1, d), jnp.float32)],
         interpret=interpret,
+        name="fused_add_layer_norm_bwd",
     )(a2, b2, scale.reshape(1, d), dy)
     return dx, dx, ds.reshape(d).astype(scale.dtype), \
         db.reshape(d).astype(scale.dtype)
@@ -322,6 +326,7 @@ def _bg_fwd(x2, bias, interpret):
         out_specs=pl.BlockSpec((br, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((r, d), x2.dtype),
         interpret=interpret,
+        name="fused_bias_gelu_fwd",
     )(x2, bias.reshape(1, d))
     return y, (x2, bias)
 
@@ -341,6 +346,7 @@ def _bg_bwd(interpret, res, dy):
         out_shape=[jax.ShapeDtypeStruct((r, d), x2.dtype),
                    jax.ShapeDtypeStruct((1, d), jnp.float32)],
         interpret=interpret,
+        name="fused_bias_gelu_bwd",
     )(x2, bias.reshape(1, d), dy)
     return dx, db.reshape(d).astype(bias.dtype)
 
@@ -392,5 +398,6 @@ def adam_update(p, g, m, v, lr_t, *, beta1, beta2, eps, interpret=False):
                    jax.ShapeDtypeStruct((r, d), v.dtype)],
         input_output_aliases={1: 0, 3: 1, 4: 2},
         interpret=interpret,
+        name="fused_adam",
     )(lr2, p2, g2, m2, v2)
     return po.reshape(shape), mo.reshape(shape), vo.reshape(shape)

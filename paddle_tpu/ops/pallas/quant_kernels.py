@@ -168,6 +168,7 @@ def dequant_accumulate(payload, scales, spec, n_peers, interpret=False):
         out_shape=jax.ShapeDtypeStruct((sb, out_cols), jnp.float32),
         scratch_shapes=[pltpu.VMEM((br, out_cols), jnp.float32)],
         interpret=interpret,
+        name="dequant_accumulate",
     )(q3, s3)
     if int4:
         # kernel emits [lo | hi] halves per block row; interleave the
@@ -200,5 +201,6 @@ def dequant_accumulate_requant(payload, scales, spec, n_peers,
                    jax.ShapeDtypeStruct((1, sb, 1), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((br, cols), jnp.float32)],
         interpret=interpret,
+        name="dequant_accumulate_requant",
     )(q3, s3)
     return q2.reshape(sb, cols), s2.reshape(sb)

@@ -250,8 +250,10 @@ _serving_engines: List = []   # weakrefs to live ServingEngines
 
 
 def register_serving_engine(engine):
-    """Expose a ServingEngine's stats through :func:`serving_stats` —
-    called by the engine constructor."""
+    """Expose a serving engine's ``stats()`` (``ServingEngine``,
+    ``DecodeEngine``) through :func:`serving_stats`, which ``/metrics``
+    and ``metrics_snapshot()`` pull at scrape time — called by the
+    engine constructor."""
     _serving_engines.append(_weakref.ref(engine))
 
 

@@ -70,6 +70,22 @@ far:
   interleaves with live decode chains instead of head-of-line blocking
   them.  Only the final chunk syncs to the host.
 
+**The worker's timeline** is legible from inside the program.  Every
+instant of the worker thread falls in exactly one of :data:`PHASES`:
+``idle`` (the condition wait with nothing to do), ``admit``, ``feed``
+(host arrays for the next launch), ``dispatch`` (owner handoff +
+``prepared.run`` until it returns), ``sync`` (the blocking
+``.numpy()``), ``emit`` (token loops, ``on_token`` callbacks, prefix
+promotion) and ``retire`` (``_retire()`` and whatever bookkeeping is
+left between the others).  Each boundary is one clock read added into
+``stats()["phase_ns"]`` (always on), and a ``decode::<phase>`` span
+(on whenever the profiler or any ``jax.profiler`` session is) whose
+parent is the launch's ``decode::prefill|chunk|chain`` span.
+``stats()["launch_ns"]``/``["launches"]`` split dispatch + sync time by
+executable kind, and every request carries ``rid`` and its
+submit/admit/first-token/done stamps (``GenerationResult.timing``,
+``stats()["queue_wait_ns"]``/``["first_token_ns"]``).
+
 Static safety: ``analysis.verify_decode`` checks every program at
 engine start — no collectives, no persistable writes outside the
 declared cache pool, and the ``decode_chain`` marker (when present)
@@ -90,13 +106,22 @@ import numpy as np
 
 from ..framework.errors import InvalidArgumentError, UnavailableError
 from ..observability import flight as _flight
-from ..observability import metrics as _metrics
 from ..observability import watchdog as _watchdog
 from ..observability.tracing import next_step_id, step_scope
-from ..profiler import RecordEvent
+from ..profiler import RecordEvent, register_serving_engine
 from ..testing import faultline as _faultline
 from ..testing.faultline import _ARMED as _FL_ARMED
 from .engine import _plan_bins
+
+
+#: the worker's phases: every instant of its wall time is in exactly one
+PHASES = ("idle", "admit", "feed", "dispatch", "sync", "emit", "retire")
+#: the executables a launch (dispatch + sync) can be of
+LAUNCH_KINDS = ("prefill", "chunk", "chain")
+
+#: the engine's one clock: request stamps, phase boundaries and the
+#: load generator's own ``time.monotonic()`` stamps are comparable
+_now_ns = time.monotonic_ns
 
 
 def blocks_needed(prompt_len: int, max_new_tokens: int,
@@ -214,13 +239,20 @@ class DecodeConfig:
 class GenerationResult:
     """What a generation future resolves to."""
 
-    __slots__ = ("tokens", "prompt_len", "finish_reason", "steps")
+    __slots__ = ("tokens", "prompt_len", "finish_reason", "steps",
+                 "timing")
 
-    def __init__(self, tokens, prompt_len, finish_reason, steps):
+    def __init__(self, tokens, prompt_len, finish_reason, steps,
+                 timing=None):
         self.tokens = np.asarray(tokens, dtype=np.int64)
         self.prompt_len = int(prompt_len)
         self.finish_reason = finish_reason      # "length" | "eos"
         self.steps = int(steps)                 # decode steps it rode
+        #: {"rid", "submit", "admit", "first_token", "done"}: the
+        #: request's id and its stamps in ``time.monotonic_ns()`` —
+        #: TTFT = (admit - submit) queue wait + (first_token - admit)
+        #: prefill.  None from the reference loop.
+        self.timing = timing
 
     def __repr__(self):
         return (f"GenerationResult(tokens={self.tokens.tolist()}, "
@@ -233,7 +265,8 @@ class _Seq:
                  "block_ids", "pos", "out_tokens", "done", "reason",
                  "t_submit", "steps", "_gather_idx", "waited_rounds",
                  "temperature", "top_k", "top_p", "seed", "hit_blocks",
-                 "_chunk_off")
+                 "_chunk_off", "rid", "t_submit_ns", "t_admit_ns",
+                 "t_first_token_ns")
 
     def __init__(self, prompt, max_new, eos, on_token,
                  temperature=0.0, top_k=0, top_p=0.0, seed=0):
@@ -247,7 +280,11 @@ class _Seq:
         self.out_tokens: List[int] = []
         self.done = False
         self.reason = "length"
-        self.t_submit = time.monotonic()
+        self.rid = -1                  # set under the queue lock
+        self.t_submit_ns = _now_ns()
+        self.t_submit = self.t_submit_ns * 1e-9
+        self.t_admit_ns = None
+        self.t_first_token_ns = None
         self.steps = 0
         self._gather_idx = 0
         self.waited_rounds = 0
@@ -357,6 +394,28 @@ class _PrefixIndex:
 
     def __len__(self):
         return len(self._entries)
+
+
+class _PhaseSpan:
+    """One worker phase as a context manager: the phase clock switches
+    to ``name`` on entry and back to ``retire`` on exit, and a
+    ``decode::<name>`` span brackets the same interval."""
+
+    __slots__ = ("_engine", "_name", "_span")
+
+    def __init__(self, engine: "DecodeEngine", name: str):
+        self._engine = engine
+        self._name = name
+        self._span = RecordEvent("decode::" + name)
+
+    def __enter__(self):
+        self._engine._switch(self._name)
+        return self._span.__enter__()
+
+    def __exit__(self, *exc):
+        self._span.__exit__(*exc)
+        self._engine._switch("retire")
+        return False
 
 
 class DecodeEngine:
@@ -538,7 +597,20 @@ class DecodeEngine:
         self._prefill_tokens = 0        # prompt tokens actually computed
         self._t_first = None
         self._t_last = None
+        self._next_rid = 0              # under _cond
+        # the worker's phase clock (all under _stats_lock): closed time
+        # per phase, and the phase open now with its start (None while
+        # no worker runs), so stats() is exact at any instant
+        self._phase_ns = dict.fromkeys(PHASES, 0)
+        self._phase_open: Optional[Tuple[str, int]] = None
+        self._launch_ns = dict.fromkeys(LAUNCH_KINDS, 0)
+        self._launches = dict.fromkeys(LAUNCH_KINDS, 0)
+        self._admitted = 0
+        self._queue_wait_ns = 0         # sum of admit - submit
+        self._first_token_ns = 0        # sum of first token - admit
+        self._first_tokens = 0
         _watchdog.ensure_started()
+        register_serving_engine(self)   # /metrics pulls stats() at scrape
         if auto_start:
             self.start()
 
@@ -706,6 +778,8 @@ class DecodeEngine:
                     f"{self._unhealthy!r}; restart the engine")
             if not self._accepting:
                 raise UnavailableError("decode engine is shut down")
+            seq.rid = self._next_rid
+            self._next_rid += 1
             self._pending.append(seq)
             self._cond.notify_all()
         with self._stats_lock:
@@ -716,17 +790,37 @@ class DecodeEngine:
 
     # -- worker -----------------------------------------------------------
     def _worker_loop(self):
+        self._switch("retire")          # the phase clock starts here
         try:
             self._loop_inner()
         except BaseException as e:    # noqa: BLE001 — worker last line
             self._worker_fatal(e)
+        finally:
+            self._switch(None)
+
+    def _switch(self, phase: Optional[str]) -> None:
+        """A phase boundary: close the open phase into ``phase_ns`` and
+        open ``phase`` at the same clock read, so the phases tile the
+        worker's wall time with nothing between them."""
+        now = _now_ns()
+        with self._stats_lock:
+            if self._phase_open is not None:
+                name, t0 = self._phase_open
+                self._phase_ns[name] += now - t0
+            self._phase_open = None if phase is None else (phase, now)
+
+    def _phase(self, name: str) -> "_PhaseSpan":
+        """``with self._phase("feed"):`` — the worker is in ``name``
+        until the block ends, then back in ``retire`` (bookkeeping)."""
+        return _PhaseSpan(self, name)
 
     def _loop_inner(self):
         while True:
             with self._cond:
                 while not self._stop and not self._pending \
                         and not self._active and not self._chunking:
-                    self._cond.wait()
+                    with self._phase("idle"):
+                        self._cond.wait()
                 if self._stop and not self._pending \
                         and not self._active and not self._chunking:
                     return
@@ -735,7 +829,12 @@ class DecodeEngine:
                 # outside any per-step recovery
                 _faultline.crossing("serving_decode")
             with self._run_lock:
-                admitted = self._admit()
+                n_chunking = len(self._chunking)
+                with self._phase("admit") as span:
+                    admitted = self._admit()
+                    if span.recording:
+                        taken = admitted + self._chunking[n_chunking:]
+                        span.set(rids=",".join(str(s.rid) for s in taken))
                 if admitted:
                     self._run_prefill(admitted)
                     self._retire()
@@ -748,7 +847,6 @@ class DecodeEngine:
                 if self._active:
                     self._chain_step()
                     self._retire()
-            self._update_gauges()
 
     def _worker_fatal(self, exc: BaseException):
         """Terminal worker failure: every generation future fails, every
@@ -778,7 +876,6 @@ class DecodeEngine:
             self._cond.notify_all()
         with self._stats_lock:
             self._failed += failed
-        self._update_gauges()
 
     # -- scheduling -------------------------------------------------------
     def _availability(self) -> int:
@@ -838,6 +935,7 @@ class DecodeEngine:
         row_lens: List[int] = []
         bucket_s = None
         taken = 0
+        now = _now_ns()
         with self._cond:
             slots_left = (cfg.max_batch_size - len(self._active)
                           - len(self._chunking))
@@ -886,8 +984,11 @@ class DecodeEngine:
                     idx.hits += len(hits)
                     idx.misses += probed - len(hits)
                     idx.bytes_saved += len(hits) * idx.block_bytes
+                seq.t_admit_ns = now
                 with self._stats_lock:
                     self._prefill_tokens += plen - seq._chunk_off
+                    self._admitted += 1
+                    self._queue_wait_ns += now - seq.t_submit_ns
                 if chunked:
                     self._chunking.append(seq)
                 else:
@@ -942,31 +1043,64 @@ class DecodeEngine:
             self._owner.sync_scope()
         self._owner = prepared
 
-    def _run_prefill(self, admitted: List[_Seq]):
-        feed, bucket = self._prefill_feed(admitted)
-        sid = next_step_id()
-        _flight.note_step(sid, "decode_prefill", bucket)
+    def _launch(self, prepared, feed, fetch: Optional[int]):
+        """One executable's ``dispatch`` and, when ``fetch`` names the
+        handle the host needs, its ``sync``, under the watchdog.
+        Returns (the fetched host array or None, the two phases' ns —
+        the worker is synchronous, so that is the executable's device
+        time plus launch latency)."""
+        # the worker is the phase clock's one writer, so its reads
+        # outside the lock see its own last write
+        ph = self._phase_ns
+        launch0 = ph["dispatch"] + ph["sync"]
+        out = None
         _watchdog.begin("decode")
         try:
-            with step_scope(sid), \
-                    RecordEvent("decode::prefill", requests=len(admitted),
-                                bucket=f"{bucket[0]}x{bucket[1]}"):
-                self._acquire(self._prefill)
-                handles = self._prefill.run(feed)
-                tokens = handles[1].numpy()
+            with self._phase("dispatch"):
+                self._acquire(prepared)
+                handles = prepared.run(feed)
+            if fetch is not None:
+                with self._phase("sync"):
+                    out = handles[fetch].numpy()
         finally:
             _watchdog.end("decode")
-        now = time.monotonic()
-        for seq in admitted:
-            tok = int(tokens[seq._gather_idx])
-            seq.pos = int(seq.prompt.size)
-            self._emit(seq, tok)
-            self._promote(seq)
+        return out, ph["dispatch"] + ph["sync"] - launch0
+
+    def _run_prefill(self, admitted: List[_Seq]):
+        sid = next_step_id()
+        with step_scope(sid), \
+                RecordEvent("decode::prefill",
+                            requests=len(admitted)) as parent:
+            with self._phase("feed"):
+                feed, bucket = self._prefill_feed(admitted)
+                parent.set(bucket=f"{bucket[0]}x{bucket[1]}")
+                _flight.note_step(sid, "decode_prefill", bucket)
+            tokens, launch_ns = self._launch(self._prefill, feed, 1)
+            now = time.monotonic()
+            with self._phase("emit"):
+                self._first_tokens_out(
+                    admitted, [int(tokens[seq._gather_idx])
+                               for seq in admitted])
         self._active.extend(admitted)
         with self._stats_lock:
             self._prefill_batches += 1
             self._host_syncs += 1
+            self._launches["prefill"] += 1
+            self._launch_ns["prefill"] += launch_ns
             self._t_last = now
+
+    def _first_tokens_out(self, seqs: List[_Seq], toks: List[int]):
+        """The prompt is in the cache and each sequence's first token is
+        on the host: stamp it, stream it, index the prompt's blocks."""
+        now = _now_ns()
+        for seq, tok in zip(seqs, toks):
+            seq.pos = int(seq.prompt.size)
+            seq.t_first_token_ns = now
+            self._emit(seq, tok)
+            self._promote(seq)
+        with self._stats_lock:
+            self._first_tokens += len(seqs)
+            self._first_token_ns += sum(now - s.t_admit_ns for s in seqs)
 
     def _promote(self, seq: _Seq):
         """Index every freshly-written FULL prompt block for
@@ -989,14 +1123,9 @@ class DecodeEngine:
         for seq in list(self._chunking):
             self._chunk_step(seq)
 
-    def _chunk_step(self, seq: _Seq):
-        cfg = self.config
-        width = cfg.chunk_width
-        plen = int(seq.prompt.size)
-        start = seq._chunk_off
-        end = min(plen, start + width)
+    def _chunk_feed(self, seq: _Seq, start: int, end: int, final: bool):
+        width = self.config.chunk_width
         n = end - start
-        final = end >= plen
         src = np.zeros((1, width), np.int64)
         src[0, :n] = seq.prompt[start:end]
         pos = np.zeros((1, width), np.int64)
@@ -1007,32 +1136,39 @@ class DecodeEngine:
         table[0, :len(seq.block_ids)] = seq.block_ids
         ctx = np.array([end], np.int32)
         last = np.full((1, 1), n - 1 if final else 0, np.int64)
-        feed = {"src_ids": src, "pos_ids": pos, "slot_ids": slots,
+        return {"src_ids": src, "pos_ids": pos, "slot_ids": slots,
                 "block_table": table, "ctx_len": ctx, "last_pos": last}
+
+    def _chunk_step(self, seq: _Seq):
+        plen = int(seq.prompt.size)
+        start = seq._chunk_off
+        end = min(plen, start + self.config.chunk_width)
+        final = end >= plen
         sid = next_step_id()
         _flight.note_step(sid, "decode_chunk", (start, end))
-        _watchdog.begin("decode")
-        try:
-            with step_scope(sid), \
-                    RecordEvent("decode::chunk", tokens=n,
-                                final=final):
-                self._acquire(self._chunk)
-                handles = self._chunk.run(feed)
-                # only the FINAL chunk's first generated token crosses
-                # to the host — intermediate chunks stay async
-                tok = int(handles[1].numpy()[0]) if final else None
-        finally:
-            _watchdog.end("decode")
-        seq._chunk_off = end
+        with step_scope(sid), \
+                RecordEvent("decode::chunk", tokens=end - start,
+                            final=final):
+            with self._phase("feed"):
+                feed = self._chunk_feed(seq, start, end, final)
+            # only the FINAL chunk's first generated token crosses to
+            # the host — intermediate chunks stay async (their device
+            # time lands in a later launch's sync)
+            toks, launch_ns = self._launch(self._chunk, feed,
+                                           1 if final else None)
+            now = time.monotonic()
+            seq._chunk_off = end
+            if final:
+                with self._phase("emit"):
+                    self._first_tokens_out([seq], [int(toks[0])])
         with self._stats_lock:
             self._chunk_steps += 1
             if final:
                 self._host_syncs += 1
-            self._t_last = time.monotonic()
+            self._launches["chunk"] += 1
+            self._launch_ns["chunk"] += launch_ns
+            self._t_last = now
         if final:
-            seq.pos = plen
-            self._emit(seq, tok)
-            self._promote(seq)
             self._chunking.remove(seq)
             self._active.append(seq)
 
@@ -1125,38 +1261,37 @@ class DecodeEngine:
         mark rows that finished mid-chain (the device froze them)."""
         cfg = self.config
         live = self._active
-        length = self._pick_chain()
-        bucket_b = next(b for b in cfg.batch_buckets if b >= len(live))
-        feed = self._chain_feed_arrays(bucket_b, live)
         sid = next_step_id()
-        _flight.note_step(sid, "decode_chain",
-                          (length, bucket_b, len(live)))
-        _watchdog.begin("decode")
-        try:
-            with step_scope(sid), \
-                    RecordEvent("decode::chain", live=len(live),
-                                bucket=bucket_b, chain=length):
-                prepared = self._chains[length]
-                self._acquire(prepared)
-                handles = prepared.run(feed)
-                tokens = handles[0].numpy()     # [length, bucket_b]
-        finally:
-            _watchdog.end("decode")
-        now = time.monotonic()
-        emitted = 0
-        for s in range(length):
-            for i, seq in enumerate(live):
-                tok = int(tokens[s, i])
-                if tok < 0:
-                    continue
-                seq.pos += 1
-                seq.steps += 1
-                self._emit(seq, tok)
-                emitted += 1
+        with step_scope(sid), \
+                RecordEvent("decode::chain", live=len(live)) as parent:
+            with self._phase("feed"):
+                length = self._pick_chain()
+                bucket_b = next(b for b in cfg.batch_buckets
+                                if b >= len(live))
+                parent.set(bucket=bucket_b, chain=length)
+                feed = self._chain_feed_arrays(bucket_b, live)
+                _flight.note_step(sid, "decode_chain",
+                                  (length, bucket_b, len(live)))
+            # tokens: [length, bucket_b]
+            tokens, launch_ns = self._launch(self._chains[length], feed, 0)
+            now = time.monotonic()
+            emitted = 0
+            with self._phase("emit"):
+                for s in range(length):
+                    for i, seq in enumerate(live):
+                        tok = int(tokens[s, i])
+                        if tok < 0:
+                            continue
+                        seq.pos += 1
+                        seq.steps += 1
+                        self._emit(seq, tok)
+                        emitted += 1
         with self._stats_lock:
             self._decode_steps += length
             self._chains_run += 1
             self._host_syncs += 1
+            self._launches["chain"] += 1
+            self._launch_ns["chain"] += launch_ns
             self._chain_tokens += emitted
             self._chain_hist[length] = \
                 self._chain_hist.get(length, 0) + 1
@@ -1180,6 +1315,10 @@ class DecodeEngine:
             seq.done = True
 
     def _retire(self):
+        with RecordEvent("decode::retire"):     # the phase it is in already
+            self._retire_finished()
+
+    def _retire_finished(self):
         with self._stats_lock:
             in_use = sum(len(s.block_ids)
                          for s in self._active + self._chunking)
@@ -1193,10 +1332,15 @@ class DecodeEngine:
                 self._retired_blocks.update(seq.block_ids)
                 self._release_blocks(seq)
             self._cond.notify_all()
+        now = _now_ns()
         for seq in finished:
             seq.future.set_result(GenerationResult(
                 seq.out_tokens, int(seq.prompt.size), seq.reason,
-                seq.steps))
+                seq.steps,
+                timing={"rid": seq.rid, "submit": seq.t_submit_ns,
+                        "admit": seq.t_admit_ns,
+                        "first_token": seq.t_first_token_ns,
+                        "done": now}))
         with self._stats_lock:
             self._completed += len(finished)
 
@@ -1207,22 +1351,6 @@ class DecodeEngine:
         evictable = self._prefix_index.evictable() \
             if self._prefix_index is not None else 0
         return self.pool_blocks - len(self._free) - evictable
-
-    def _update_gauges(self):
-        try:
-            _metrics.gauge("decode::cache_blocks_used").set(
-                self._blocks_in_use())
-            _metrics.gauge("decode::active_seqs").set(
-                len(self._active) + len(self._chunking))
-            idx = self._prefix_index
-            if idx is not None:
-                _metrics.gauge("decode::prefix_cache_hits").set(idx.hits)
-                _metrics.gauge("decode::prefix_cache_misses").set(
-                    idx.misses)
-                _metrics.gauge("decode::prefix_cache_bytes_saved").set(
-                    idx.bytes_saved)
-        except Exception:          # noqa: BLE001 — metrics best-effort
-            pass
 
     # -- warmup -----------------------------------------------------------
     def warmup(self) -> int:
@@ -1387,7 +1515,18 @@ class DecodeEngine:
                 "chunk_steps": self._chunk_steps,
                 "interleaved_rounds": self._interleaved_rounds,
                 "prefill_tokens": self._prefill_tokens,
+                "launches": dict(self._launches),
+                "launch_ns": dict(self._launch_ns),
+                "admitted": self._admitted,
+                "queue_wait_ns": self._queue_wait_ns,
+                "first_tokens": self._first_tokens,
+                "first_token_ns": self._first_token_ns,
             }
+            phase_ns = dict(self._phase_ns)
+            if self._phase_open is not None:
+                name, t0 = self._phase_open
+                phase_ns[name] += _now_ns() - t0
+            out["phase_ns"] = phase_ns
         out["cache_blocks_used"] = self._blocks_in_use()
         out["compile_count"] = self.compiled_executables
         idx = self._prefix_index

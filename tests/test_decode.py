@@ -484,18 +484,148 @@ def test_cached_flash_route_cross_lowers_as_tpu_custom_call():
 
 
 def test_decode_metrics_and_spans(engine):
+    """The decode engine's numbers are PULLED from ``stats()`` at scrape
+    time (the worker pushes no gauge): block, sequence and prefix-cache
+    series, and the phase clock one series per phase."""
     from paddle_tpu.observability import metrics
     (p,) = _prompts([5], seed=55)
     engine.generate({"src_ids": p}, max_new_tokens=4).result(timeout=300)
     engine.drain()
-    snap = metrics.metrics_snapshot(include_serving=False)
-    names = {m["name"] for m in snap["metrics"]}
-    assert "decode::cache_blocks_used" in names
-    assert "decode::active_seqs" in names
+    snap = metrics.metrics_snapshot()
+    mine = [st for st in snap["serving"] if "cache_blocks_used" in st
+            and st["pool_blocks"] == engine.pool_blocks]
+    assert mine and mine[0]["active"] == 0
+    assert not {m["name"] for m in snap["metrics"]
+                if m["name"].startswith("decode::")}
+    text = metrics.prometheus_text()
+    for series in ("cache_blocks_used", "active", "prefix_hits",
+                   "prefix_misses", "prefix_bytes_saved", "queue_wait_ns"):
+        assert f"paddle_tpu_serving_{series}{{engine=" in text, series
+    assert 'paddle_tpu_serving_phase_ns{engine="' in text
+    assert 'key="sync"} ' in text
     stats = engine.stats()
     assert stats["tokens_per_s"] > 0
     assert 0 < stats["peak_occupancy"] <= 1
     assert stats["compile_count"] >= 2
+
+
+# ---------------------------------------------------------------------------
+# the worker's timeline: phase clock, launch counters, request stamps
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def timeline_run():
+    """One engine through packed prefill, chunked prefill and chains,
+    with a pool small enough that later requests queue: its results, its
+    ``stats()`` taken while idle, and the test's own clock around the
+    worker's whole life so far."""
+    from paddle_tpu.serving.decode import PHASES
+    eng = DecodeEngine(_model(), _config(chunk_tokens=4, pool_blocks=14),
+                       auto_start=False)
+    prompts = _prompts([5, 20, 7, 18, 3, 9], seed=77)
+    try:
+        t0 = time.monotonic_ns()
+        eng.start()
+        futs = [eng.generate({"src_ids": p}, max_new_tokens=6)
+                for p in prompts]
+        results = [f.result(timeout=300) for f in futs]
+        assert eng.drain(timeout=60)
+        time.sleep(0.05)                     # some idle time on the clock
+        stats = eng.stats()
+        t1 = time.monotonic_ns()
+        assert eng.shutdown(timeout=60)
+        after = [eng.stats()["phase_ns"], None]
+        time.sleep(0.02)
+        after[1] = eng.stats()["phase_ns"]
+    finally:
+        eng.shutdown()
+    return {"results": results, "stats": stats, "wall_ns": t1 - t0,
+            "after_shutdown": after, "phases": PHASES}
+
+
+def test_phase_ns_tiles_the_workers_wall_time(timeline_run):
+    """Every instant of the worker is in exactly one phase: the phases
+    add up to the wall time since ``start()`` within 1 %, each of them
+    was visited, and the clock stops with the worker."""
+    ph = timeline_run["stats"]["phase_ns"]
+    assert tuple(ph) == timeline_run["phases"]
+    wall = timeline_run["wall_ns"]
+    assert abs(sum(ph.values()) - wall) <= 0.01 * wall, (ph, wall)
+    assert all(v > 0 for v in ph.values()), ph
+    first, second = timeline_run["after_shutdown"]
+    assert first == second
+    assert sum(first.values()) >= sum(ph.values())
+
+
+def test_launch_counters_agree_with_the_schedulers(timeline_run):
+    st = timeline_run["stats"]
+    assert st["launches"] == {"prefill": st["prefill_batches"],
+                              "chunk": st["chunk_steps"],
+                              "chain": st["chains_run"]}
+    assert all(n > 0 for n in st["launches"].values()), st["launches"]
+    assert all(st["launch_ns"][k] > 0 for k in st["launches"])
+    # every dispatch and every sync belongs to exactly one launch
+    assert sum(st["launch_ns"].values()) == \
+        st["phase_ns"]["dispatch"] + st["phase_ns"]["sync"]
+
+
+def test_request_timing_is_ordered_and_sums_to_the_counters(timeline_run):
+    results, st = timeline_run["results"], timeline_run["stats"]
+    timings = [r.timing for r in results]
+    assert sorted(t["rid"] for t in timings) == list(range(len(results)))
+    for t in timings:
+        assert t["submit"] <= t["admit"] <= t["first_token"] <= t["done"]
+    assert st["admitted"] == st["first_tokens"] == len(results)
+    assert st["queue_wait_ns"] == sum(t["admit"] - t["submit"]
+                                      for t in timings)
+    assert st["first_token_ns"] == sum(t["first_token"] - t["admit"]
+                                       for t in timings)
+    # the pool held back the later arrivals: their wait is on the record
+    assert st["admission_waits"] > 0
+    assert max(t["admit"] - t["submit"] for t in timings) > \
+        min(t["first_token"] - t["admit"] for t in timings)
+
+
+def test_decode_spans_reach_the_xplane_under_a_bare_jax_trace(
+        engine, tmp_path):
+    """With ``jax.profiler.start_trace`` alone — no ``start_profiler``,
+    the program's own tracing off — the worker's phases land in the
+    xplane's host plane under their own names, children inside the
+    launch's ``decode::chain`` span."""
+    import glob
+
+    import jax
+    from paddle_tpu.observability import tracing
+    assert not tracing.is_enabled()
+    (p,) = _prompts([6], seed=91)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        engine.generate({"src_ids": p}, max_new_tokens=5).result(
+            timeout=300)
+        engine.drain()
+    finally:
+        jax.profiler.stop_trace()
+    assert not tracing.get_events()
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                            / "*.xplane.pb"))
+    events = [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns)
+              for plane in jax.profiler.ProfileData.from_file(path).planes
+              if plane.name.startswith("/host:")
+              for line in plane.lines for ev in line.events
+              if ev.name.startswith("decode::")]
+    names = {e[0] for e in events}
+    assert {"decode::admit", "decode::feed", "decode::dispatch",
+            "decode::sync", "decode::emit", "decode::retire",
+            "decode::prefill", "decode::chain"} <= names, names
+    chains = [e for e in events if e[0] == "decode::chain"]
+    for name, a, b in events:
+        if name in ("decode::sync", "decode::emit"):
+            assert any(pa <= a and b <= pb for n, pa, pb in events
+                       if n in ("decode::chain", "decode::prefill")), name
+    assert chains
 
 
 def test_decode_bench_artifact_contract():

@@ -9,6 +9,7 @@ contract."""
 import gzip
 import json
 import os
+import re
 import sys
 import threading
 import time
@@ -559,6 +560,10 @@ def test_prometheus_guardrail_series():
             prepared.run(feed)
             info = prepared.guard_info(sync=True)   # decodes both gauges
             assert info["loss_scale"] is not None
+            # the first run above compiles, which can itself outlast the
+            # deadline: count the trips the ARMED stall adds
+            trips = metrics.counter("watchdog::trip", beacon="prepared")
+            before = trips.get()
             faultline.arm("step_stall", action="stall",
                           seconds=3 * deadline, times=1)
             prepared.run(feed)                      # watchdog trips
@@ -571,7 +576,9 @@ def test_prometheus_guardrail_series():
         assert "# TYPE paddle_tpu_guardrail::loss_scale gauge" in text
         assert "paddle_tpu_guardrail::loss_scale " in text
         assert "# TYPE paddle_tpu_watchdog::trip counter" in text
-        assert 'paddle_tpu_watchdog::trip{beacon="prepared"} 1' in text
+        (count,) = re.findall(
+            r'paddle_tpu_watchdog::trip\{beacon="prepared"\} (\d+)\n', text)
+        assert int(count) >= before + 1
         with metrics.serve_metrics(port=0) as srv:
             scraped = urllib.request.urlopen(srv.url).read().decode()
         assert "paddle_tpu_guardrail::skipped_total" in scraped
@@ -769,3 +776,44 @@ def test_obs_bench_artifact_contract():
     audit = json.load(open(os.path.join(REPO, "FLOPS_AUDIT_r05.json")))
     assert audit["metric"] == "bert_step_flops_xla_vs_analytic"
     assert 0.9 <= audit["value"] <= 1.1
+
+
+def test_span_lands_in_a_bare_jax_profiler_session(tmp_path):
+    """A ``Span`` opens a ``jax.profiler.TraceAnnotation`` whenever ANY
+    profiler session runs — the program's own tracing stays off and
+    records nothing — so the device trace's host plane carries the span
+    under its own name with its attributes and ``step_id``; with no
+    session it opens none."""
+    import glob
+
+    import jax
+    assert not tracing.is_enabled()
+    idle = tracing.Span("unit::no_session")
+    with idle:
+        assert idle._ann is None
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        with tracing.step_scope(4217), \
+                tracing.Span("unit::outer", program="p7") as sp:
+            sp.set(cache_hit=True)
+            with tracing.Span("unit::inner"):
+                pass
+    finally:
+        jax.profiler.stop_trace()
+    assert not [e for e in tracing.get_events()
+                if e[0].startswith("unit::")]
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                            / "*.xplane.pb"))
+    found = {ev.name: (ev.start_ns, ev.start_ns + ev.duration_ns,
+                       {k: str(v) for k, v in ev.stats})
+             for plane in jax.profiler.ProfileData.from_file(path).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for ev in line.events
+             if ev.name.startswith("unit::")}
+    assert set(found) == {"unit::outer", "unit::inner"}
+    a, b, stats = found["unit::outer"]
+    assert stats["program"] == "p7" and stats["step_id"] == "4217"
+    assert stats["cache_hit"] in ("True", "1")
+    assert a <= found["unit::inner"][0] <= found["unit::inner"][1] <= b

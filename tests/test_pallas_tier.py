@@ -435,14 +435,14 @@ def test_kernel_census_artifact_contract():
     secs = art["sections"]
     # every grafted kernel is present as a custom call in the TPU-
     # cross-lowered module of its hot path
-    assert "_fwd_kernel" in secs["single_device_bert_tiny_seq128"]["kernels"]
-    assert "_adam_kernel" in secs["single_device_bert_tiny_seq128"]["kernels"]
-    assert "_fwd_kernel" in secs["ring_attention_sp4"]["kernels"]
-    for k in ("_bwd_dq_kernel", "_bwd_dkv_kernel"):
+    assert "flash_fwd" in secs["single_device_bert_tiny_seq128"]["kernels"]
+    assert "fused_adam" in secs["single_device_bert_tiny_seq128"]["kernels"]
+    assert "flash_fwd" in secs["ring_attention_sp4"]["kernels"]
+    for k in ("flash_bwd_dq", "flash_bwd_dkv"):
         assert k in secs["ring_attention_sp4_grad"]["kernels"]
-    assert "_adam_kernel" in secs["zero1_dp8_flat_shard_adam"]["kernels"]
-    assert "_dq_acc_requant_kernel" in secs["quant_int8_dp8"]["kernels"]
-    assert "_dq_acc_kernel" in secs["quant_int4_dp8"]["kernels"]
+    assert "fused_adam" in secs["zero1_dp8_flat_shard_adam"]["kernels"]
+    assert "dequant_accumulate_requant" in secs["quant_int8_dp8"]["kernels"]
+    assert "dequant_accumulate" in secs["quant_int4_dp8"]["kernels"]
     for s in secs.values():
         assert s["complete"], s["leg"]
         assert s["tpu_custom_call_sites"] > 0

@@ -176,10 +176,10 @@ def selftest():
     fused = set(ladder["+fused_ln_adam"])
     both = set(ladder["both (bench default)"])
     ok = ok and not base                     # flags off → NO pallas calls
-    ok = ok and {"_fwd_kernel", "_bwd_dq_kernel",
-                 "_bwd_dkv_kernel"} <= flash
-    ok = ok and {"_ln_fwd_kernel", "_ln_bwd_kernel",
-                 "_adam_kernel"} <= fused
+    ok = ok and {"flash_fwd", "flash_bwd_dq",
+                 "flash_bwd_dkv"} <= flash
+    ok = ok and {"fused_layer_norm_fwd", "fused_layer_norm_bwd",
+                 "fused_adam"} <= fused
     ok = ok and (flash | fused) <= both
     art["cross_lowered_kernels"] = ladder
     with open(ARTIFACT, "w") as f:

@@ -137,8 +137,8 @@ def main():
                  f"{donated}")
     lines.append(f"GEMM operand dtypes: {gemm_pairs} "
                  f"({'PURE bf16' if set(gemm_pairs) <= {'bf16xbf16'} else 'MIXED — check mxu_matmul routing'})")
-    want = {"_fwd_kernel", "_bwd_dq_kernel", "_bwd_dkv_kernel",
-            "_ln_fwd_kernel", "_ln_bwd_kernel", "_adam_kernel"}
+    want = {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
+            "fused_layer_norm_fwd", "fused_layer_norm_bwd", "fused_adam"}
     missing = want - set(kernels)
     lines.append(f"required kernel set: "
                  f"{'COMPLETE' if not missing else f'MISSING {missing}'}")
@@ -193,8 +193,8 @@ def census_single_device():
                                             scope=scope)
     txt = exported.mlir_module()
     sec = _section("single_device_bert_tiny_seq128", txt,
-                   ("_fwd_kernel", "_bwd_dq_kernel", "_bwd_dkv_kernel",
-                    "_ln_fwd_kernel", "_ln_bwd_kernel", "_adam_kernel"))
+                   ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
+                    "fused_layer_norm_fwd", "fused_layer_norm_bwd", "fused_adam"))
     # the static report must agree with what the module proves
     from paddle_tpu.framework.analysis import kernel_routing_report
     sec["routing_report"] = kernel_routing_report(
@@ -253,9 +253,9 @@ def census_ring_sp4():
         grad_txt = jexp.export(grad_of(make(True, False)),
                                platforms=("tpu",))(
             q, k, v, mask).mlir_module()
-    sec = _section("ring_attention_sp4", fwd_txt, ("_fwd_kernel",))
+    sec = _section("ring_attention_sp4", fwd_txt, ("flash_fwd",))
     gsec = _section("ring_attention_sp4_grad", grad_txt,
-                    ("_fwd_kernel", "_bwd_dq_kernel", "_bwd_dkv_kernel"))
+                    ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"))
 
     # interpret-mode parity vs the einsum inner step (CPU, no TPU)
     import jax.numpy as jnp
@@ -330,7 +330,7 @@ def census_zero1_dp8():
     """dp8 ZeRO-1 sharded update: the fused Adam kernel engages on the
     flat 128-aligned 1/n state shards inside shard_map."""
     txt = _dp8_step_module(sharded_update=True)
-    return _section("zero1_dp8_flat_shard_adam", txt, ("_adam_kernel",))
+    return _section("zero1_dp8_flat_shard_adam", txt, ("fused_adam",))
 
 
 def census_quant_dp8(mode):
@@ -338,8 +338,8 @@ def census_quant_dp8(mode):
     the fused dequant-accumulate kernel (int8 round-to-nearest also
     fuses the requantization)."""
     txt = _dp8_step_module(quant_mode=mode)
-    required = ("_dq_acc_requant_kernel",) if mode == "int8" \
-        else ("_dq_acc_kernel",)
+    required = ("dequant_accumulate_requant",) if mode == "int8" \
+        else ("dequant_accumulate",)
     sec = _section(f"quant_{mode}_dp8", txt, required)
     sec["wire_tier_parity_bound"] = WIRE_TIER_BOUNDS[mode]
     return sec
