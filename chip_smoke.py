@@ -6,7 +6,8 @@ One process drives the main path once, through the entry points a user
 calls, at the full width of BERT-base (random weights from a seed), and
 checks what comes out by the repo's own means:
 
-* **leg K** — every Pallas kernel family (flash attention incl. the
+* **leg K** — every Pallas kernel family (flash attention and the
+  one-tile attention the trainer runs at sequence 128, each incl. the
   hardware-PRNG dropout path, fused LayerNorm / add+LN, bias+GELU, fused
   Adam, the int8/int4 dequant-accumulate pair) compiled by Mosaic at the
   shapes the models use and at the bound each routing gate admits, each
@@ -70,12 +71,16 @@ class Sizes:
             # gate-bound check (the interpreter has no VMEM limit to
             # find, so the dry run only walks the code)
             self.flash = (1, 1, 128, 64)
+            self.tile = (2, 2)
             self.rows = (130,)
             self.bound = (8, 256, 256)
         else:
             self.cfg = BertConfig.base()
             self.batch, self.seq, self.masks = 96, 128, 20
             self.flash = (2, 4, 256, 64)
+            # one-tile attention: (batch rows, heads) at D = 64 — the
+            # trainer's 12 heads, two grid steps of four rows
+            self.tile = (8, 12)
             # batch * seq plus a ragged edge block; a decode step's rows
             self.rows = (96 * 128 + 8, 8)
             self.bound = (4 * 128, LN_MAX_D, BG_MAX_D)
@@ -132,6 +137,9 @@ def leg_kernels(S: Sizes):
     import numpy as np
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
+    from paddle_tpu.ops.attention_ops import (_merge_heads, _split_heads,
+                                              reference_attention)
+    from paddle_tpu.ops.pallas import attention_tile as at
     from paddle_tpu.ops.pallas import flash_attention as fa
     from paddle_tpu.ops.pallas import fused_ops as F
     from paddle_tpu.ops.pallas import quant_kernels as qk
@@ -215,6 +223,73 @@ def leg_kernels(S: Sizes):
             return jnp.einsum("bst,btd->bsd", pd, flat(v))
         _check_kernel("flash_attention dropout", drop, masked_ref,
                       (q, k, v), 2e-2, 2e-2)
+
+    # -- one-tile attention (attn_tile_fwd / attn_tile_bwd): what
+    # fused_attention lowers to at Sq == Sk == 128, non-causal, in the
+    # op's own (B, S, H*D) layout with the trainer's [B, 1, S, S] bias ---
+    Bt, Ht = S.tile
+    St, Dt = at.TILE, 64
+    qt, kt, vt = (randn(Bt, St, Ht * Dt) for _ in range(3))
+    mt = (rng.rand(Bt, 1, St, St) > 0.2).astype(np.float32)
+    mt[0, 0, 3, :] = 0.0          # a fully masked row: uniform, no NaN
+    bias_t = jnp.asarray((mt - 1.0) * 1e4)
+
+    def tile_ref(q, k, v):
+        return reference_attention(
+            *(t.astype(jnp.float32) for t in (q, k, v)), bias_t, Ht, 0.0,
+            None, True)
+
+    for dtype, tol in ((jnp.float32, 2e-2), (jnp.bfloat16, 4e-2)):
+        _check_kernel(
+            "attention_tile",
+            lambda q, k, v: at.attention_tile_bsd(
+                q, k, v, bias_t, n_head=Ht, interpret=interp),
+            tile_ref, tuple(t.astype(dtype) for t in (qt, kt, vt)),
+            tol, tol)
+
+    if not interp:
+        rate = 0.1
+        seed = jnp.asarray([42], jnp.int32)
+
+        def tile_drop(q, k, v, seed=seed):
+            return at.attention_tile_bsd(q, k, v, bias_t, n_head=Ht,
+                                         dropout_rate=rate, seed=seed)
+        o1 = tile_drop(qt, kt, vt)
+        assert float(jnp.max(jnp.abs(o1 - tile_drop(qt, kt, vt)))) == 0.0, \
+            "one-tile dropout is not deterministic in its seed"
+        assert float(jnp.max(jnp.abs(
+            o1 - tile_drop(qt, kt, vt, jnp.asarray([7], jnp.int32))))) > 0, \
+            "the one-tile dropout seed has no effect"
+        # the kernels seed per (batch row, 128-lane group) and draw the
+        # group's heads stacked along the rows: regenerate exactly that
+        groups, per = Ht * Dt // at.LANES, at.LANES // Dt
+
+        def tile_mask_kernel(seed_ref, m_ref):
+            b, g = pl.program_id(0), pl.program_id(1)
+            keep = fa._dropout_mask(seed_ref, b * groups + g,
+                                    (per * St, St), rate)
+            m_ref[0, 0] = keep.astype(jnp.float32)
+
+        keep_t = pl.pallas_call(
+            tile_mask_kernel, grid=(Bt, groups),
+            in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)],
+            out_specs=pl.BlockSpec((1, 1, per * St, St),
+                                   lambda b, g: (b, g, 0, 0),
+                                   memory_space=pltpu.VMEM),
+            out_shape=jax.ShapeDtypeStruct((Bt, groups, per * St, St),
+                                           jnp.float32),
+        )(seed).reshape(Bt, Ht, St, St)
+        kr = float(jnp.mean(keep_t))
+        _say(f"  one-tile hardware keep rate {kr:.4f} (want {1 - rate})")
+        assert abs(kr - (1 - rate)) < 0.01, kr
+
+        def tile_masked_ref(q, k, v):
+            qh, kh, vh = (_split_heads(t, Ht) for t in (q, k, v))
+            s = jnp.einsum("bhsd,bhtd->bhst", qh, kh) / np.sqrt(Dt)
+            pd = keep_t * jax.nn.softmax(s + bias_t, -1) / (1.0 - rate)
+            return _merge_heads(jnp.einsum("bhst,bhtd->bhsd", pd, vh))
+        _check_kernel("attention_tile dropout", tile_drop,
+                      tile_masked_ref, (qt, kt, vt), 2e-2, 2e-2)
 
     # -- [R, D] kernels at the model's shapes, then each family at the
     # widest D its routing gate admits: a gate must not admit what the
@@ -443,7 +518,7 @@ def leg_trainer(S: Sizes, platform: str):
 
     _check_routes(
         _routes_since(routes0), S,
-        want_hits=("flash_attention", "fused_layer_norm", "fused_adam"),
+        want_hits=("attention_tile", "fused_layer_norm", "fused_adam"),
         allowed_fallbacks=ADAM_SHAPE_FALLBACKS)
 
     # -- A/B: dropout off, Pallas flags on vs off, same weights (same
@@ -701,7 +776,7 @@ def leg_four_chips(S: Sizes, platform: str):
              f"the HLO; smoke timing first step {t_first:.1f} s")
     _check_routes(
         _routes_since(routes0), S,
-        want_hits=("flash_attention", "fused_layer_norm", "fused_adam"),
+        want_hits=("attention_tile", "fused_layer_norm", "fused_adam"),
         allowed_fallbacks=ADAM_SHAPE_FALLBACKS)
 
     # -- C2: parity — dp4 vs one chip, one global batch, dropout off ----

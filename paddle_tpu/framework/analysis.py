@@ -1575,7 +1575,9 @@ def kernel_routing_report(program: Program, feed_shapes=None,
         else:
             matching = [r for r in spec.pallas
                         if r.match is None or r.match(op.attrs, mesh_axes)]
-            label = (matching or spec.pallas)[0].kernel
+            # same label pallas_route's fallback counter takes: the
+            # last (most general) route in play
+            label = (matching or spec.pallas[:1])[-1].kernel
             row = {"op": op.type, "index": idx, "route": "fallback",
                    "kernel": label, "reason": reason,
                    "kernels": []}

@@ -5,12 +5,18 @@ math/bert_encoder_functor.cu), TPU-native.
 One op takes projected Q/K/V in (B, S, H*D) layout plus an additive
 attention bias and produces the context in (B, S, H*D).  Keeping the whole
 attention in a single op gives a clean seam to swap the implementation for
-the Pallas flash-attention kernel (ops/pallas/flash_attention.py) on TPU
-while the jnp composition remains the CPU/interpret fallback.
+a Pallas kernel on TPU while the jnp composition remains the
+CPU/interpret fallback: the one-tile kernels
+(ops/pallas/attention_tile.py) when the whole problem is one tile —
+non-causal, Sq == Sk == 128, which they take in this (B, S, H*D) layout —
+and the blockwise flash kernel (ops/pallas/flash_attention.py, on
+head-split operands) for causal, cached, ring and longer sequences.
 
 Routing goes through the registry's Pallas channel
 (``pallas_route("fused_attention", ...)`` — ops/op_specs.py registers the
-``flash_attention`` and ``ring_flash_attention`` routes), so the gate is
+``attention_tile``, ``flash_attention``, ``cached_flash_attention`` and
+``ring_flash_attention`` routes; which one is read from shapes and attrs,
+never from a flag or a model name), so the gate is
 statically enumerable, every hit/fallback lands in
 ``observability.metrics`` counters labeled by op + reason, and fallback
 warnings name the EFFECTIVE lowering backend (ops.pallas), not
@@ -86,6 +92,19 @@ def reference_attention(q, k, v, bias, n_head, dropout_rate, ctx,
     return _merge_heads(ctxv)
 
 
+def _dropout_seed(ctx, attrs):
+    """(rate, seed) of a training-mode attention: a per-step int32 seed
+    from the program RNG, so the in-kernel PRNG mask changes every step
+    but forward and backward agree."""
+    is_test = attrs.get("is_test", False) or ctx.is_test
+    rate = 0.0 if is_test else float(attrs.get("dropout_rate", 0.0))
+    if not rate:
+        return 0.0, None
+    return rate, jax.random.randint(ctx.next_key(), (1,), 0,
+                                    jnp.iinfo(jnp.int32).max,
+                                    dtype=jnp.int32)
+
+
 def lower_flash_attention(ctx, ins, attrs):
     """The ``flash_attention`` Pallas route: blockwise online-softmax
     kernel on head-split operands (pallas_route guarantees the shape
@@ -93,20 +112,26 @@ def lower_flash_attention(ctx, ins, attrs):
     from .pallas.flash_attention import flash_attention_bshd
     q, k, v = x(ins, "Q"), x(ins, "K"), x(ins, "V")
     n_head = _resolve_heads(q, attrs)
-    is_test = attrs.get("is_test", False) or ctx.is_test
-    rate = 0.0 if is_test else float(attrs.get("dropout_rate", 0.0))
-    seed = None
-    if rate:
-        # derive a per-step int32 seed from the program RNG so the
-        # in-kernel PRNG mask changes every step but fwd/bwd agree
-        seed = jax.random.randint(ctx.next_key(), (1,), 0,
-                                  jnp.iinfo(jnp.int32).max,
-                                  dtype=jnp.int32)
+    rate, seed = _dropout_seed(ctx, attrs)
     out = flash_attention_bshd(
         _split_heads(q, n_head), _split_heads(k, n_head),
         _split_heads(v, n_head), _attn_bias(ins), dropout_rate=rate,
         seed=seed, causal=bool(attrs.get("causal", False)))
     return {"Out": _merge_heads(out)}
+
+
+def lower_attention_tile(ctx, ins, attrs):
+    """The ``attention_tile`` Pallas route: the whole problem is one
+    (S, S) tile per head, so the kernels take Q/K/V and write the context
+    in the (B, S, H*D) layout the op has them in — no head split or
+    merge (pallas_route guarantees the shape rule before this is
+    called)."""
+    from .pallas.attention_tile import attention_tile_bsd
+    q, k, v = x(ins, "Q"), x(ins, "K"), x(ins, "V")
+    rate, seed = _dropout_seed(ctx, attrs)
+    return {"Out": attention_tile_bsd(
+        q, k, v, _attn_bias(ins), n_head=_resolve_heads(q, attrs),
+        dropout_rate=rate, seed=seed)}
 
 
 def lower_cached_attention(ctx, ins, attrs, use_flash=False):
@@ -195,8 +220,10 @@ def _fused_attention(ctx, ins, attrs):
         if route is not None:
             return route.lower(ctx, ins, attrs)
         return lower_ring_attention(ctx, ins, attrs, use_flash=False)
+    # one tile (Sq == Sk == 128, non-causal) has its own kernels; every
+    # other shape the blockwise kernel tiles is the flash route's
     route, _ = pallas_route("fused_attention", ins, attrs,
-                            kernel="flash_attention")
+                            kernel=("attention_tile", "flash_attention"))
     if route is not None:
         return route.lower(ctx, ins, attrs)
     return {"Out": reference_attention(q, k, v, _attn_bias(ins), n_head,

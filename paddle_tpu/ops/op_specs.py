@@ -1201,6 +1201,23 @@ def _pl_flash_supported(ins, attrs, axis_sizes=None):
     return _flash_tiles(s, sk, d, causal=bool(attrs.get("causal")))
 
 
+def _pl_tile_supported(ins, attrs, axis_sizes=None):
+    """One-tile route gate (ops/pallas/attention_tile.py): the whole
+    attention is one (128, 128) tile per head — non-causal, Sq == Sk ==
+    128, a head-shared additive bias (or none).  Everything else is the
+    blockwise kernel's."""
+    from .pallas.attention_tile import tiles
+    dims = _attn_bhsd(ins, attrs)
+    if dims is None:
+        return False, "shape-unknown"
+    b, h, s, sk, d = dims
+    bias = _sig(ins, "AttnBias")
+    # an unknown bias shape is () here: not 4-D, so the rule rejects it
+    bias_shape = None if bias is None else _shape_of(bias) or ()
+    return tiles(s, sk, h, d, causal=bool(attrs.get("causal")),
+                 bias_shape=bias_shape)
+
+
 def _pl_ring_supported(ins, attrs, axis_sizes=None):
     if _sig(ins, "AttnBias") is not None:
         return False, "ring-explicit-bias"
@@ -1344,6 +1361,11 @@ def _lower_flash_attention(ctx, ins, attrs):
     return lower_flash_attention(ctx, ins, attrs)
 
 
+def _lower_attention_tile(ctx, ins, attrs):
+    from .attention_ops import lower_attention_tile
+    return lower_attention_tile(ctx, ins, attrs)
+
+
 def _lower_ring_flash_attention(ctx, ins, attrs):
     from .attention_ops import lower_ring_attention
     return lower_ring_attention(ctx, ins, attrs, use_flash=True)
@@ -1389,6 +1411,14 @@ _PL_FLASH = PallasLowering(
     and not attrs.get("_cached"),
     supported=_pl_flash_supported, lower=_lower_flash_attention,
     kernels=_FLASH_KERNELS)
+# the plain (no ring, no KV pool) fused_attention tries this route first:
+# at one tile the blockwise kernel's machinery is pure cost (PERF.md,
+# PR 28); any other shape falls through to _PL_FLASH
+_PL_TILE = PallasLowering(
+    "attention_tile", flag="use_flash_attention", attr="use_flash",
+    match=_PL_FLASH.match,
+    supported=_pl_tile_supported, lower=_lower_attention_tile,
+    kernels=("attn_tile_fwd", "attn_tile_bwd"))
 _PL_RING = PallasLowering(
     "ring_flash_attention", flag="use_flash_attention", attr="use_flash",
     match=_ring_stamped,
@@ -1506,7 +1536,7 @@ def register_default_specs():
     op_spec("fused_attention", infer=_infer_fused_attention,
             mem_backward_extra=_attention_probs_bytes,
             flops=_flops_fused_attention,
-            pallas=(_PL_RING, _PL_CACHED, _PL_FLASH))
+            pallas=(_PL_RING, _PL_CACHED, _PL_TILE, _PL_FLASH))
     op_spec("cache_write", infer=_infer_cache_write)
     op_spec("decode_chain", infer=_infer_decode_chain)
 

@@ -2,12 +2,20 @@
 
 The reference's fastest attention is a monolithic fused CUDA kernel
 (ref: operators/fused/multihead_matmul_op.cu) that still materialises the
-full (S, S) score matrix.  This kernel is strictly stronger: O(S) memory via
+full (S, S) score matrix.  This kernel does more: O(S) memory via
 online softmax, MXU-shaped (128x128) blocks, f32 accumulation, in-kernel
 PRNG dropout (the reference's fused path has no dropout at all — its
 dropout runs as a separate elementwise kernel over the (S, S) probs,
 ref: operators/dropout_op.cu), and causal masking with true block
 skipping (blocks above the diagonal never execute).
+
+It is the lowering for what has something to block over or to skip:
+causal attention, the cached (chunked-prefill) read, the ring step, any
+sequence past one tile.  At Sq == Sk == 128, non-causal, the machinery
+is pure cost — a grid step per (batch, head), one key tile's worth of
+online softmax, two backward kernels each recomputing the scores, a
+bias tile per head — and measured 3 % of the MXU's peak for a fifth of
+a BERT-base step (PERF.md, PR 28); that shape is attention_tile.py's.
 
 Layout: every kernel runs a 3-D grid with the KV (or Q, for dk/dv) axis
 innermost and carries the online-softmax state in VMEM scratch.  K/V

@@ -162,8 +162,10 @@ def pallas_route(op_type: str, ins, attrs, axis_sizes=None, backend=None,
     ``observability.metrics`` labeled by op + kernel + reason, so tests
     and the census observe EVERY routing decision, not just the first;
     static callers (analysis.kernel_routing_report) pass ``count=False``.
-    ``kernel`` filters to one named route (op impls that already know
-    which path they are on — e.g. fused_attention's ring branch)."""
+    ``kernel`` filters to one named route, or a tuple of them tried in
+    table order (op impls that already know which path they are on —
+    e.g. fused_attention's ring branch, or its plain branch: the
+    one-tile route, else the blockwise one)."""
     spec = OP_SPECS.get(op_type)
     routes = getattr(spec, "pallas", None) if spec is not None else None
     if not routes:
@@ -171,10 +173,11 @@ def pallas_route(op_type: str, ins, attrs, axis_sizes=None, backend=None,
     from . import pallas as _pallas
     if backend is None:
         backend = _pallas.effective_backend()
+    names = (kernel,) if isinstance(kernel, str) else kernel
     reasons = []
     matched = []
     for route in routes:
-        if kernel is not None and route.kernel != kernel:
+        if names is not None and route.kernel not in names:
             continue
         if route.match is not None and not route.match(attrs, axis_sizes):
             continue
@@ -198,9 +201,15 @@ def pallas_route(op_type: str, ins, attrs, axis_sizes=None, backend=None,
                 _pallas_count(op_type, route.kernel, "hit", "supported")
             return route, "supported"
         reasons.append(why)
-    reason = "; ".join(reasons) if reasons else "no-matching-route"
+    # routes sharing a gate (flag, backend) give its reason once
+    reason = "; ".join(dict.fromkeys(reasons)) if reasons \
+        else "no-matching-route"
     if count and routes:
-        kname = kernel or (matched[0] if matched else routes[0].kernel)
+        # filed under the LAST route in play — the general one (the
+        # blockwise kernel behind the one-tile route), where the
+        # fallbacks of an op were counted before it gained a special case
+        kname = matched[-1] if matched else \
+            (names[-1] if names else routes[0].kernel)
         _pallas_count(op_type, kname, "fallback", reason)
         _pallas_warn(op_type, kname, reason, backend)
     return None, reason

@@ -216,3 +216,102 @@ def test_causal_with_padding_bias():
                         causal=True)
     np.testing.assert_allclose(np.asarray(out.reshape(B * H, S, D)),
                                np.asarray(ref), atol=2e-4)
+
+
+# ---------------------------------------------------------------------------
+# the one-tile lowering (ops/pallas/attention_tile.py): Sq == Sk == 128,
+# non-causal, operands in the op's own (B, S, H*D) layout
+# ---------------------------------------------------------------------------
+
+
+def _tile_case(dtype, bias_kind, n_head=4, d=64, batch=3):
+    from paddle_tpu.ops.pallas import attention_tile as at
+    rng = np.random.RandomState(7)
+    S = at.TILE
+    q, k, v, g = (jnp.asarray(rng.randn(batch, S, n_head * d)
+                              .astype(np.float32) * 0.5, dtype)
+                  for _ in range(4))
+    bias = None
+    if bias_kind == "full":         # bert.py's [B, 1, S, S] mask bias
+        m = (rng.rand(batch, 1, S, S) > 0.2).astype(np.float32)
+        m[0, 0, 3, :] = 0.0         # a fully masked row
+        bias = jnp.asarray((m - 1.0) * 1e4)
+    elif bias_kind == "keys":       # the KVMask-derived [B, 1, 1, S]
+        m = (rng.rand(batch, 1, 1, S) > 0.2).astype(np.float32)
+        bias = jnp.asarray((m - 1.0) * 1e9)
+    return at, (q, k, v), g, bias
+
+
+def _rel_l2(a, b):
+    a = np.asarray(a, np.float64)
+    b = np.asarray(b, np.float64)
+    return np.linalg.norm(a - b) / max(np.linalg.norm(b), 1e-30)
+
+
+def _tile_matches_reference(dtype, bias_kind, tol, n_head=4, d=64, batch=3):
+    """Forward and dq/dk/dv of the one-tile kernels against
+    reference_attention in (B, S, H*D) layout."""
+    from paddle_tpu.ops.attention_ops import reference_attention
+    at, qkv, g, bias = _tile_case(dtype, bias_kind, n_head, d, batch)
+    out, vjp = jax.vjp(lambda *a: at.attention_tile_bsd(
+        *a, bias, n_head=n_head, interpret=True), *qkv)
+    ref, ref_vjp = jax.vjp(lambda *a: reference_attention(
+        *a, bias, n_head, 0.0, None, True), *qkv)
+    assert out.shape == ref.shape and out.dtype == dtype
+    assert np.isfinite(np.asarray(out, np.float32)).all()
+    assert _rel_l2(out, ref) <= tol
+    for got, want in zip(vjp(g), ref_vjp(g)):
+        assert got.dtype == dtype
+        assert _rel_l2(got, want) <= tol
+
+
+@pytest.mark.parametrize("bias_kind", [None, "full", "keys"])
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-6),
+                                       (jnp.bfloat16, 8e-3)])
+def test_tile_fwd_and_grads_match_reference(dtype, tol, bias_kind):
+    """With and without a bias, f32 and bf16; the full bias carries a
+    fully masked row (uniform attention, no NaN)."""
+    _tile_matches_reference(dtype, bias_kind, tol)
+
+
+@pytest.mark.parametrize("n_head,d,batch", [(12, 64, 8), (2, 128, 1),
+                                            (6, 64, 5)])
+def test_tile_head_layouts_and_row_counts(n_head, d, batch):
+    """D == 64 (two heads to a 128-lane group), D == 128 (one), and
+    batches that give 4, 1 and 1 rows to a grid step."""
+    _tile_matches_reference(jnp.float32, "full", 2e-6, n_head, d, batch)
+
+
+def test_tile_shape_rule():
+    from paddle_tpu.ops.pallas import attention_tile as at
+    assert at.tiles(128, 128, 12, 64) == (True, "")
+    assert at.tiles(128, 128, 12, 64, bias_shape=(96, 1, 128, 128))[0]
+    assert at.tiles(128, 128, 12, 64, bias_shape=(96, 1, 1, 128))[0]
+    for args, kw, why in (
+            ((256, 256, 12, 64), {}, "one-tile:256x256"),
+            ((128, 512, 12, 64), {}, "one-tile:128x512"),
+            ((128, 128, 12, 64), {"causal": True}, "one-tile:causal"),
+            ((128, 128, 3, 64), {}, "one-tile:head-dim:64x3"),
+            ((128, 128, 4, 32), {}, "one-tile:head-dim:32x4"),
+            ((128, 128, 32, 64), {}, "one-tile:width:2048"),
+            ((128, 128, 12, 64), {"bias_shape": (96, 12, 128, 128)},
+             "one-tile:bias-shape")):
+        assert at.tiles(*args, **kw) == (False, why)
+    # the rule and the wrapper agree
+    q = jnp.zeros((1, 256, 128), jnp.float32)
+    with pytest.raises(ValueError, match="one-tile:256x256"):
+        at.attention_tile_bsd(q, q, q, n_head=2, interpret=True)
+    q = jnp.zeros((1, 128, 128), jnp.float32)
+    with pytest.raises(ValueError, match="requires a seed"):
+        at.attention_tile_bsd(q, q, q, n_head=2, dropout_rate=0.1)
+    # rows per grid step: the largest divisor that fits VMEM
+    assert at._rows_per_step(96, 128 * 768 * 2) == 4
+    assert at._rows_per_step(96, 128 * 768 * 4) == 2
+    assert at._rows_per_step(7, 128 * 768 * 2) == 1
+
+
+def test_tile_bias_grad_is_zero_by_contract():
+    at, qkv, g, bias = _tile_case(jnp.float32, "full")
+    db = jax.grad(lambda b: jnp.sum(at.attention_tile_bsd(
+        *qkv, b, n_head=4, interpret=True)))(bias)
+    assert float(jnp.max(jnp.abs(db))) == 0.0

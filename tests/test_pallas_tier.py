@@ -44,8 +44,10 @@ def test_routing_report_flash_hit_at_128_fallback_at_64():
     cfg, main_p, _, total = _bert_tiny_train()
     rep = kernel_routing_report(main_p, feed_shapes=_feed_arrays(cfg, 128),
                                 backend="tpu")
-    assert rep["summary"]["flash_attention"]["pallas"] == 2
-    assert rep["summary"]["flash_attention"]["fallback"] == 0
+    # seq 128 is one tile: both layers take the one-tile route
+    assert rep["summary"]["attention_tile"]["pallas"] == 2
+    assert rep["summary"]["attention_tile"]["fallback"] == 0
+    assert "flash_attention" not in rep["summary"]
     assert rep["summary"]["fused_layer_norm"]["pallas"] > 0
     # BERT-tiny's 128-wide square params tile the fused-Adam layout;
     # the small bias/scale leaves fall back with the size floor named
@@ -142,15 +144,112 @@ def test_pallas_route_counters_every_fallback_counted():
             assert route is None and "seq" in reason
         route, reason = pallas_route("fused_attention", _attn_sigs(128),
                                      attrs)
+        assert route is not None and route.kernel == "attention_tile"
+        route, reason = pallas_route("fused_attention", _attn_sigs(256),
+                                     attrs)
         assert route is not None and route.kernel == "flash_attention"
+    # a fallback is filed under the last (general) route in play and
+    # carries every route's reason
     c_fb = metrics.counter("pallas_routes", op="fused_attention",
                            kernel="flash_attention", outcome="fallback",
-                           reason="seq:100x100%128")
+                           reason="one-tile:100x100; seq:100x100%128")
     assert c_fb.get() == 3            # EVERY fallback counted, not one
-    c_hit = metrics.counter("pallas_routes", op="fused_attention",
-                            kernel="flash_attention", outcome="hit",
-                            reason="supported")
-    assert c_hit.get() == 1
+    for kernel in ("attention_tile", "flash_attention"):
+        c_hit = metrics.counter("pallas_routes", op="fused_attention",
+                                kernel=kernel, outcome="hit",
+                                reason="supported")
+        assert c_hit.get() == 1
+
+
+def _tile_route_cases():
+    from paddle_tpu.ops.registry import VarSig
+    plain = _attn_sigs(128)
+    bias = lambda *shape: [VarSig(shape, "float32")]
+    pool = [VarSig((16, 128, 128), "float32")]
+    cached = {"Q": plain["Q"], "KPool": pool, "VPool": pool,
+              "BlockTable": [VarSig((2, 1), "int32")],
+              "CtxLen": [VarSig((2,), "int32")]}
+    return [
+        # (id, ins, attrs, axis_sizes, kernel the table picks)
+        ("one-tile", plain, {}, None, "attention_tile"),
+        ("one-tile-mask-bias", dict(plain, AttnBias=bias(2, 1, 128, 128)),
+         {}, None, "attention_tile"),
+        ("one-tile-key-bias", dict(plain, AttnBias=bias(2, 1, 1, 128)),
+         {}, None, "attention_tile"),
+        ("per-head-bias", dict(plain, AttnBias=bias(2, 2, 128, 128)),
+         {}, None, "flash_attention"),
+        ("causal", plain, {"causal": True}, None, "flash_attention"),
+        ("seq256", _attn_sigs(256), {}, None, "flash_attention"),
+        ("cached", cached, {"_cached": True}, None,
+         "cached_flash_attention"),
+        ("ring-stamped", _attn_sigs(512), {"_seq_axis": "sp"}, {"sp": 4},
+         "ring_flash_attention"),
+    ]
+
+
+@pytest.mark.parametrize(
+    "ins,attrs,axis_sizes,want",
+    [pytest.param(*c[1:], id=c[0]) for c in _tile_route_cases()])
+def test_route_table_picks_tile_only_for_one_tile(ins, attrs, axis_sizes,
+                                                  want):
+    """The one-tile route takes (Sq == Sk == 128, non-causal, no pool,
+    no ring, head-shared bias) and nothing else; causal, cached,
+    ring-stamped and S = 256 keep the routes they had."""
+    from paddle_tpu.ops.pallas import lowering_target
+    from paddle_tpu.ops.registry import pallas_route
+    attrs = dict(attrs, n_head=2)
+    with lowering_target("tpu"):
+        route, reason = pallas_route("fused_attention", ins, attrs,
+                                     axis_sizes=axis_sizes, count=False)
+    assert route is not None, reason
+    assert route.kernel == want
+    if want == "attention_tile":
+        assert route.kernels == ("attn_tile_fwd", "attn_tile_bwd")
+    else:
+        assert route.kernels == ("flash_fwd", "flash_bwd_dq",
+                                 "flash_bwd_dkv")
+
+
+def test_fused_attention_op_counts_tile_hits_and_lowers_its_kernels():
+    """Trace-time census: the op impl's plain branch resolves the
+    one-tile route (hit counter by op, kernel, reason) and the traced
+    program holds attn_tile_fwd / attn_tile_bwd in the op's (B, S, H*D)
+    layout — no transpose around them; a causal op of the same shape
+    still counts and lowers flash_*."""
+    from paddle_tpu.observability import metrics
+    from paddle_tpu.ops.pallas import lowering_target
+    from paddle_tpu.ops.registry import LoweringContext, get_op
+
+    impl = get_op("fused_attention")
+    q = jnp.zeros((4, 128, 128), jnp.bfloat16)
+    bias = jnp.zeros((4, 1, 128, 128), jnp.float32)
+
+    def loss(causal):
+        def f(q, k, v):
+            ctx = LoweringContext(jax.random.PRNGKey(0), is_test=False)
+            out = impl(ctx, {"Q": [q], "K": [k], "V": [v],
+                             "AttnBias": [bias]},
+                       {"n_head": 2, "dropout_rate": 0.1,
+                        "causal": causal})["Out"]
+            return jnp.sum(out.astype(jnp.float32))
+        return f
+
+    def hits(kernel):
+        return metrics.counter("pallas_routes", op="fused_attention",
+                               kernel=kernel, outcome="hit",
+                               reason="supported").get()
+
+    metrics.reset_metrics()
+    with lowering_target("tpu"):
+        tile = str(jax.make_jaxpr(jax.grad(loss(False), (0, 1, 2)))(q, q, q))
+        assert (hits("attention_tile"), hits("flash_attention")) == (1, 0)
+        flash = str(jax.make_jaxpr(jax.grad(loss(True), (0, 1, 2)))(q, q, q))
+        assert (hits("attention_tile"), hits("flash_attention")) == (1, 1)
+    assert "attn_tile_fwd" in tile and "attn_tile_bwd" in tile
+    assert "flash_" not in tile and "transpose[" not in tile
+    for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert name in flash
+    assert "attn_tile" not in flash
 
 
 def test_pallas_route_flag_and_backend_reasons():
@@ -199,8 +298,8 @@ def test_pallas_table_enumerates_the_tier():
                "c_fused_quant_allreduce_sum", "quant_reduce_scatter"):
         assert op in table, op
     kernels = {r.kernel for routes in table.values() for r in routes}
-    assert {"flash_attention", "ring_flash_attention", "fused_adam",
-            "dequant_accumulate"} <= kernels
+    assert {"attention_tile", "flash_attention", "ring_flash_attention",
+            "fused_adam", "dequant_accumulate"} <= kernels
 
 
 # ---------------------------------------------------------------------------
@@ -435,7 +534,9 @@ def test_kernel_census_artifact_contract():
     secs = art["sections"]
     # every grafted kernel is present as a custom call in the TPU-
     # cross-lowered module of its hot path
-    assert "flash_fwd" in secs["single_device_bert_tiny_seq128"]["kernels"]
+    for k in ("attn_tile_fwd", "attn_tile_bwd"):
+        assert k in secs["single_device_bert_tiny_seq128"]["kernels"]
+    assert "flash_fwd" not in secs["single_device_bert_tiny_seq128"]["kernels"]
     assert "fused_adam" in secs["single_device_bert_tiny_seq128"]["kernels"]
     assert "flash_fwd" in secs["ring_attention_sp4"]["kernels"]
     for k in ("flash_bwd_dq", "flash_bwd_dkv"):
@@ -457,7 +558,7 @@ def test_kernel_census_artifact_contract():
     assert secs["quant_int4_dp8"]["wire_tier_parity_bound"] == 2.5e-1
     # the embedded static routing report agrees with the module census
     rep = secs["single_device_bert_tiny_seq128"]["routing_report"]
-    assert rep["summary"]["flash_attention"]["pallas"] > 0
+    assert rep["summary"]["attention_tile"]["pallas"] > 0
     assert rep["summary"]["fused_adam"]["pallas"] > 0
 
 

@@ -1,0 +1,80 @@
+"""The main path's Pallas kernels compiled by the TPU's own compiler at
+real widths, for a v5e that is described and not attached
+(``jax.experimental.topologies``): what Mosaic refuses — a slice off the
+tiling, a scoped-VMEM overrun — fails here at no chip time.  Interpret
+mode cannot show either.  A compile that passes says nothing about
+results or speed (chip_smoke.py leg K and the benchmark do).
+
+Everything that touches the topology lives in fixtures of THIS file: one
+process at a time may load the TPU's library, and under pytest-xdist only
+the worker that is given this file may try.
+"""
+
+import os
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:      # no libtpu here, or it is held elsewhere
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def no_compile_cache():
+    """A compile for a described device is written to the persistent
+    cache but cannot be read back without a chip."""
+    from jax.experimental.compilation_cache import compilation_cache
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize("dtype,batch,n_head,d,rate,bias_shape", [
+    # the s128 cell's attention: pure-bf16 AMP, batch 96, bert.py's mask
+    pytest.param(jnp.bfloat16, 96, 12, 64, 0.1, (96, 1, 128, 128),
+                 id="bert-base-bf16-b96-dropout"),
+    # the benchmark's dropout-off reference build, and an f32 caller
+    pytest.param(jnp.bfloat16, 8, 12, 64, 0.0, (8, 1, 128, 128),
+                 id="bert-base-bf16-b8"),
+    pytest.param(jnp.float32, 8, 12, 64, 0.0, (8, 1, 1, 128),
+                 id="bert-base-f32-key-mask"),
+    # the widest f32 row the shape rule admits, one head to a lane group
+    pytest.param(jnp.float32, 2, 13, 128, 0.1, None,
+                 id="f32-at-the-width-bound"),
+])
+def test_attention_tile_kernels_compile_for_v5e(one_chip, no_compile_cache,
+                                                dtype, batch, n_head, d,
+                                                rate, bias_shape):
+    from paddle_tpu.ops.pallas import attention_tile as at
+    assert at.tiles(at.TILE, at.TILE, n_head, d, bias_shape=bias_shape)[0]
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    x = sds((batch, at.TILE, n_head * d), dtype)
+    bias = None if bias_shape is None else sds(bias_shape, jnp.float32)
+
+    def step(q, k, v, g, bias, seed):
+        out, vjp = jax.vjp(lambda *a: at.attention_tile_bsd(
+            *a, bias, n_head=n_head, dropout_rate=rate, seed=seed), q, k, v)
+        return (out,) + vjp(g)
+
+    compiled = jax.jit(step).lower(
+        x, x, x, x, bias, sds((1,), jnp.int32)).compile()
+    txt = compiled.as_text()
+    assert "attn_tile_fwd" in txt and "attn_tile_bwd" in txt

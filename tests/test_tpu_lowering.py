@@ -66,10 +66,11 @@ def test_pallas_kernels_present(lowered_bench_step):
     txt = lowered_bench_step.mlir_module()
     names = set(re.findall(r'kernel_name = "(\w+)"', txt))
     assert txt.count("tpu_custom_call") > 0, "no Mosaic custom calls at all"
-    # flash attention: forward + both backward kernels
-    assert "flash_fwd" in names, f"flash fwd missing; found {names}"
-    assert "flash_bwd_dq" in names, f"flash bwd dq missing; found {names}"
-    assert "flash_bwd_dkv" in names, f"flash bwd dkv missing; found {names}"
+    # attention at seq 128 is one tile: the one-tile forward and the ONE
+    # fused backward kernel — and none of the blockwise flash kernels
+    assert "attn_tile_fwd" in names, f"attn tile fwd missing; found {names}"
+    assert "attn_tile_bwd" in names, f"attn tile bwd missing; found {names}"
+    assert not {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"} & names, names
     # fused LayerNorm fwd+bwd
     assert "fused_layer_norm_fwd" in names, f"fused LN fwd missing; found {names}"
     assert "fused_layer_norm_bwd" in names, f"fused LN bwd missing; found {names}"
@@ -82,16 +83,26 @@ def test_fluid_op_scopes_and_kernel_names_in_op_metadata(lowered_bench_step):
     and every ``pl.pallas_call`` passes ``name=``: the lowered step's
     location metadata (the HLO ``op_name``) says which Fluid op an
     instruction came from, through forward, ``jvp`` and ``transpose``
-    wrapping, and the flash backward's two kernels are told apart."""
+    wrapping, and attention's forward and backward kernels are told apart."""
     txt = lowered_bench_step.mlir_module()
     op_names = set(re.findall(r'loc\("(jit\(step\)/[^"]*)"', txt))
     assert any("layer_norm" in n for n in op_names)
     assert any(re.search(r"transpose\(jvp\(layer_norm\)\)", n)
                for n in op_names), "backward of layer_norm not attributed"
-    assert any("fused_attention" in n and n.endswith("flash_fwd/pallas_call")
-               for n in op_names)
-    assert any(n.endswith("flash_bwd_dkv/pallas_call") for n in op_names)
-    assert any(n.endswith("flash_bwd_dq/pallas_call") for n in op_names)
+    # the one-tile kernels are jitted (a model's layers share one traced
+    # and lowered body): the call sites carry the Fluid op's scope, forward
+    # and backward apart, the shared bodies the kernels' own names
+    assert "jit(step)/jvp(fused_attention)/jit(tile_fwd)" in op_names
+    assert "jit(step)/transpose(jvp(fused_attention))/jit(tile_bwd)" \
+        in op_names
+    locs = set(re.findall(r'loc\("([^"]*)"', txt))
+    assert {"attn_tile_fwd/pallas_call", "attn_tile_bwd/pallas_call"} <= locs
+    # two layers, ONE body each
+    assert len(re.findall(r"func\.func private @tile_fwd", txt)) == 1
+    assert len(re.findall(r"func\.func private @tile_bwd", txt)) == 1
+    # no head split / merge around the kernels any more
+    assert not any("fused_attention" in n and n.endswith("/transpose")
+                   for n in op_names)
     # matmuls land under the op that asked for them
     assert "jit(step)/jvp(mul)/dot_general" in op_names
     assert "jit(step)/transpose(jvp(matmul))/dot_general" in op_names

@@ -137,7 +137,8 @@ def main():
                  f"{donated}")
     lines.append(f"GEMM operand dtypes: {gemm_pairs} "
                  f"({'PURE bf16' if set(gemm_pairs) <= {'bf16xbf16'} else 'MIXED — check mxu_matmul routing'})")
-    want = {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
+    # seq 128 is one tile: the one-tile attention pair, not flash_*
+    want = {"attn_tile_fwd", "attn_tile_bwd",
             "fused_layer_norm_fwd", "fused_layer_norm_bwd", "fused_adam"}
     missing = want - set(kernels)
     lines.append(f"required kernel set: "
@@ -168,8 +169,9 @@ def _section(name, txt, required):
 
 
 def census_single_device():
-    """BERT-tiny seq-128 train step, single device: the flash attention
-    fwd+bwd, fused LN fwd+bwd and fused Adam kernels all engage."""
+    """BERT-tiny seq-128 train step, single device: the one-tile
+    attention fwd+bwd (seq 128 is one tile; the blockwise flash kernels
+    are the ring legs'), fused LN fwd+bwd and fused Adam all engage."""
     import paddle_tpu.fluid as fluid
     from paddle_tpu.framework.core import reset_default_programs
     from paddle_tpu.framework.executor import global_scope
@@ -193,7 +195,7 @@ def census_single_device():
                                             scope=scope)
     txt = exported.mlir_module()
     sec = _section("single_device_bert_tiny_seq128", txt,
-                   ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
+                   ("attn_tile_fwd", "attn_tile_bwd",
                     "fused_layer_norm_fwd", "fused_layer_norm_bwd", "fused_adam"))
     # the static report must agree with what the module proves
     from paddle_tpu.framework.analysis import kernel_routing_report
