@@ -21,6 +21,14 @@ checks what comes out by the repo's own means:
 * **leg B** — the server: ``DecodeEngine(BertDecoder(BertConfig.base()))``
   answering 8 mixed-length requests submitted together, checked against
   ``engine.greedy_reference``;
+* **leg M** — the pre-norm RoPE/GQA decoder with dropless experts
+  (models/decoder_lm.py) at Mellum2-12B-A2.5B's widths: the grouped-head
+  windowed flash kernels against the materialised-scores reference at the
+  window's edge (1 023 / 1 024 / 1 025) and 32 query heads on 4, the
+  grouped matmul kernels against ``lax.ragged_dot`` over ragged groups
+  with an empty expert, then one period of the model (3 sliding layers +
+  1 full, experts 0-15 of 64, a 24 576-row vocabulary slice, 8 192
+  tokens) through ``exe.prepare(...).run`` under pure-bf16 Adam;
 * **leg C** — four chips (run when >= 4 devices are visible): Fleet dp4
   with the bucketed grad all-reduce at per-chip batch 96, dp4-vs-one-chip
   loss parity, one dp2 x tp2 step, one ZeRO-1 flat-shard-Adam step.
@@ -46,7 +54,7 @@ import re
 import sys
 import time
 
-LEGS = ("K", "A", "B", "C")
+LEGS = ("K", "A", "B", "M", "C")
 
 
 def _say(msg):
@@ -546,6 +554,156 @@ def leg_trainer(S: Sizes, platform: str):
 
 
 # ---------------------------------------------------------------------------
+# leg M — the RoPE/GQA decoder with dropless experts
+# ---------------------------------------------------------------------------
+
+MELLUM_ROPE = {
+    "full_attention": {
+        "rope_type": "yarn", "rope_theta": 500000, "factor": 16,
+        "original_max_position_embeddings": 8192, "beta_fast": 32,
+        "beta_slow": 1, "attention_factor": 1.2772588722239782},
+    "sliding_attention": {"rope_type": "default", "rope_theta": 500000}}
+
+
+def leg_decoder_lm(S: Sizes, platform: str):
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu.contrib.mixed_precision import decorate
+    from paddle_tpu.models import decoder_lm as dl
+    from paddle_tpu.ops.decoder_lm_ops import grouped_ffn
+    from paddle_tpu.ops.pallas import flash_gqa as fg
+
+    interp = S.dry
+    rng = np.random.RandomState(0)
+
+    def randn(*shape):
+        return jnp.asarray(rng.randn(*shape).astype(np.float32))
+
+    # -- attention: the window's edge, then the model's head grouping ----
+    if S.dry:
+        seq, d, blk, cases = 32, 16, 8, ((7, 2, 1), (8, 2, 1), (9, 4, 2),
+                                         (None, 4, 2))
+    else:
+        seq, d, blk, cases = 2048, 128, None, (
+            (1023, 8, 1), (1024, 8, 1), (1025, 8, 1), (1024, 32, 4),
+            (None, 32, 4))
+    for window, h, hkv in cases:
+        args = (randn(1, seq, h * d), randn(1, seq, hkv * d),
+                randn(1, seq, hkv * d))
+        for dtype, tol in ((jnp.float32, 2e-2), (jnp.bfloat16, 4e-2)):
+            kw = dict(n_head=h, n_kv_head=hkv, window=window)
+            _check_kernel(
+                f"flash_gqa window={window} heads={h}/{hkv}",
+                lambda q, k, v: fg.flash_gqa_bsd(q, k, v, block=blk,
+                                                 interpret=interp, **kw),
+                lambda q, k, v: fg.reference(
+                    *(t.astype(jnp.float32) for t in (q, k, v)), **kw),
+                tuple(t.astype(dtype) for t in args), tol, tol)
+
+    # -- grouped products: ragged groups, one expert empty ---------------
+    if S.dry:
+        n, dm, f, e, el, k, tiles = 64, 16, 24, 8, 3, 2, (8,)
+    else:
+        n, dm, f, e, el, k, tiles = 2048, 2304, 896, 64, 16, 8, \
+            (128, 256, 512)
+    xs = randn(n, dm)
+    wg, wu = randn(el, dm, f) * 0.02, randn(el, dm, f) * 0.02
+    wd = randn(el, f, dm) * 0.02
+    # a skewed router: expert 0 takes every token, expert 1 none
+    logits = np.array(randn(n, e))
+    logits[:, 0] = 30.0
+    logits[:, 1] = -1e9
+    w, idx = jax.lax.top_k(jax.nn.softmax(jnp.asarray(logits)), k)
+    idx = idx.astype(jnp.int32)
+    for tile_m in tiles:
+        def run(backend, dtype):
+            def loss(x_, w_, wg_, wu_, wd_):
+                out, counts = grouped_ffn(
+                    x_.astype(dtype), w_, idx, wg_.astype(dtype),
+                    wu_.astype(dtype), wd_.astype(dtype), backend=backend,
+                    tile_m=tile_m)
+                return jnp.sum(jnp.sin(out.astype(jnp.float32))), counts
+            return jax.jit(jax.value_and_grad(
+                loss, argnums=(0, 1, 2, 3, 4), has_aux=True))
+        ker = run("pallas_interpret" if interp else "pallas", jnp.bfloat16
+                  if not interp else jnp.float32)
+        (lk, ck), gk = ker(xs, w, wg, wu, wd)
+        with jax.default_matmul_precision("highest"):
+            (lr, cr), gr = run("xla", jnp.float32)(xs, w, wg, wu, wd)
+        assert np.array_equal(np.asarray(ck), np.asarray(cr)), (ck, cr)
+        assert int(ck[1]) == 0 and int(ck[0]) == n, ck
+        eg = max(_rel(a, b) for a, b in zip(gk, gr))
+        _say(f"  moe_gmm tile {tile_m}: counts {np.asarray(ck).tolist()}, "
+             f"loss {float(lk):.4f} vs {float(lr):.4f}, grad rel err "
+             f"{eg:.2e}")
+        assert abs(float(lk) - float(lr)) < 2e-2 * max(abs(float(lr)), 1)
+        assert eg < 6e-2, eg
+        if not interp:
+            jax.block_until_ready(ker(xs, w, wg, wu, wd))
+            t0 = time.perf_counter()
+            for _ in range(5):
+                out = ker(xs, w, wg, wu, wd)
+            jax.block_until_ready(out)
+            _say(f"  moe_gmm tile {tile_m}: smoke timing "
+                 f"{1e3 * (time.perf_counter() - t0) / 5:.2f} ms a "
+                 f"forward + backward at {n} tokens")
+
+    # -- the model: one period through the prepared path -----------------
+    if S.dry:
+        cfg = dataclasses.replace(dl.DecoderLMConfig.tiny(),
+                                  held_experts=(0, 2))
+        seq, steps = 32, 3
+    else:
+        cfg = dl.DecoderLMConfig(
+            vocab_size=24576, num_hidden_layers=4, held_experts=(0, 16),
+            rope_parameters=MELLUM_ROPE)
+        seq, steps = 8192, 4
+    main, startup = fluid.Program(), fluid.Program()
+    main.random_seed = startup.random_seed = 1
+    with fluid.program_guard(main, startup), fluid.unique_name.guard():
+        _, loss, _ = dl.build_lm_network(cfg)
+        decorate(fluid.optimizer.Adam(1e-4),
+                 use_pure_bf16=True).minimize(loss)
+    batch = dl.make_fake_batch(rng, cfg, batch_size=1, seq_len=seq)
+    routes0 = _route_counters()
+    exe = fluid.Executor(fluid.TPUPlace(0))
+    with fluid.scope_guard(fluid.Scope()):
+        exe.run(startup)
+        prepared = exe.prepare(main, fetch_list=[loss])
+        losses, times = [], []
+        for _ in range(steps):
+            t0 = time.perf_counter()
+            losses.append(float(prepared.run(batch)[0]))
+            times.append(time.perf_counter() - t0)
+        prepared.wait()
+        stats = dict(prepared.stats)
+        mem = jax.devices()[0].memory_stats() or {}
+        prepared.sync_scope()
+        _assert_on_device(fluid.global_scope(), exe._device)
+    assert all(np.isfinite(losses)) and losses[-1] < losses[0], losses
+    expect = steps * cfg.num_hidden_layers * seq \
+        * cfg.num_experts_per_tok * (cfg.held_experts[1]
+                                     - cfg.held_experts[0]) \
+        / cfg.num_experts
+    _say(f"  decoder LM: loss {losses[0]:.4f} -> {losses[-1]:.4f} over "
+         f"{steps} steps of {seq} tokens; smoke timings first step "
+         f"{times[0]:.1f} s (compile), later "
+         f"{1e3 * float(np.median(times[1:])):.0f} ms/step; "
+         f"moe_assignments_local {stats['moe_assignments_local']} "
+         f"(uniform routing would give {expect:.0f}), load max/mean "
+         f"{stats['moe_expert_load_max'] / stats['moe_expert_load_mean']:.3f}"
+         f"; peak_bytes_in_use {mem.get('peak_bytes_in_use')}")
+    assert 0.5 * expect < stats["moe_assignments_local"] < 2 * expect
+    _check_routes(
+        _routes_since(routes0), S,
+        want_hits=("flash_gqa_attention", "moe_grouped_matmul",
+                   "fused_adam"),
+        allowed_fallbacks=ADAM_SHAPE_FALLBACKS)
+
+
+# ---------------------------------------------------------------------------
 # leg B — the server
 # ---------------------------------------------------------------------------
 
@@ -853,7 +1011,7 @@ def main(argv=None):
                     help="tiny width on the CPU backend, to debug this "
                          "script; proves nothing about the chip")
     ap.add_argument("--legs", default=",".join(LEGS),
-                    help="comma-separated subset of K,A,B,C")
+                    help="comma-separated subset of K,A,B,M,C")
     args = ap.parse_args(argv)
     legs = [x.strip().upper() for x in args.legs.split(",") if x.strip()]
     if not legs or set(legs) - set(LEGS):
@@ -901,6 +1059,7 @@ def main(argv=None):
     run = {"K": lambda: leg_kernels(S),
            "A": lambda: leg_trainer(S, device["platform"]),
            "B": lambda: leg_server(S, device["platform"]),
+           "M": lambda: leg_decoder_lm(S, device["platform"]),
            "C": lambda: leg_four_chips(S, device["platform"])}
     summary = []
     t_all = time.perf_counter()

@@ -8,7 +8,8 @@ from __future__ import annotations
 
 WHITE_LIST = {
     "mul", "matmul", "matmul_v2", "conv2d", "depthwise_conv2d",
-    "conv2d_transpose", "fused_attention",
+    "conv2d_transpose", "fused_attention", "moe_grouped_ffn",
+    "lm_head_loss",
 }
 
 BLACK_LIST = {
@@ -20,6 +21,7 @@ BLACK_LIST = {
     "kldiv_loss", "huber_loss", "smooth_l1_loss",
     "squared_l2_norm", "p_norm", "clip_by_norm",
     "lr_schedule", "accuracy", "top_k", "arg_max",
+    "rms_norm", "moe_topk_router",
 }
 
 GRAY_LIST = {
@@ -30,7 +32,16 @@ GRAY_LIST = {
     "split", "stack", "slice", "squeeze2", "unsqueeze2", "scale", "pool2d",
     "gather", "gather_tokens", "pad", "expand", "expand_v2", "tile",
     "flatten2", "flatten_contiguous_range", "clip", "label_smooth",
+    "rotary_embedding",
 }
+
+#: input slots a white-list op keeps in fp32 whatever the compute dtype:
+#: the router's combine weights multiply the experts' outputs once and
+#: carry the router's gradient
+KEEP_FP32_SLOTS = {"moe_grouped_ffn": {"TopkWeight"}}
+#: output slots a white-list op writes in fp32 whatever it computes in:
+#: a per-token loss is summed over thousands of positions
+FP32_OUTPUT_SLOTS = {"lm_head_loss": {"Loss"}}
 
 
 class AutoMixedPrecisionLists:

@@ -8,7 +8,8 @@ from __future__ import annotations
 
 from ...framework import unique_name
 from ...framework.core import Program
-from .fp16_lists import AutoMixedPrecisionLists
+from .fp16_lists import (FP32_OUTPUT_SLOTS, KEEP_FP32_SLOTS,
+                         AutoMixedPrecisionLists)
 
 _FLOAT = {"float32", "float64"}
 
@@ -69,11 +70,14 @@ def rewrite_program(program: Program, amp_lists: AutoMixedPrecisionLists,
             # unknown op: play safe, fp32
             target = "float32"
 
+        pinned = KEEP_FP32_SLOTS.get(t, ())
         for slot, names in list(op.inputs.items()):
             new_names = []
             for n in names:
                 c = cur(n)
-                if c in _FLOAT and target == dest_dtype:
+                if slot in pinned:
+                    pass
+                elif c in _FLOAT and target == dest_dtype:
                     n, i = _insert_cast(block, i, n, c, dest_dtype,
                                         cast_cache)
                 elif c == dest_dtype and target == "float32":
@@ -83,7 +87,10 @@ def rewrite_program(program: Program, amp_lists: AutoMixedPrecisionLists,
             op.inputs[slot] = new_names
 
         out_dtype = dest_dtype if target == dest_dtype else "float32"
-        for ns in op.outputs.values():
+        fp32_out = FP32_OUTPUT_SLOTS.get(t, ())
+        for slot, ns in op.outputs.items():
+            if slot in fp32_out:
+                continue
             for n in ns:
                 v = block._find_var_recursive(n)
                 if v is not None and v.dtype in _FLOAT | {dest_dtype}:

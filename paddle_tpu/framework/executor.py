@@ -1471,6 +1471,13 @@ class PreparedStep:
         self.stats = {"steps": 0, "blocking_syncs": 0, "max_inflight": 0,
                       "dispatch_ns": 0, "feed_wait_ns": 0,
                       "fetch_wait_ns": 0}
+        # device counters the program declares (``program._device_counters``:
+        # persistable name -> ((stat key, reduce), ...)), folded at wait()
+        self._device_counters = dict(
+            getattr(program, "_device_counters", {}))
+        self._counters_seen: Dict[str, Any] = {}
+        for pairs in self._device_counters.values():
+            self.stats.update({key: 0 for key, _ in pairs})
         # guardrail bookkeeping (framework/guardrails.py): per-dispatch
         # guard fetch handles pending a non-blocking host poll, and the
         # latest resolved skip/scale facts for telemetry
@@ -1832,7 +1839,26 @@ class PreparedStep:
             jax.block_until_ready(self._key)
         self._inflight.clear()
         self._guard_poll(block=True)
+        if self._device_counters:
+            self._fold_device_counters()
         return self
+
+    def _fold_device_counters(self):
+        """Add to ``stats`` what the program's device counters (int32
+        persistables the step carries and adds to on the device, no fetch)
+        gained since the last blocking point: ``stats[key] +=
+        reduce(gain)`` for every declared pair.  The counters may wrap
+        between two blocking points, their gains may not (2**31)."""
+        state = self._state or {}
+        for name, pairs in self._device_counters.items():
+            if name not in state:
+                continue
+            now = np.asarray(state[name]).astype(np.uint32)
+            gain = (now - self._counters_seen.get(
+                name, np.zeros_like(now))).astype(np.int64)
+            self._counters_seen[name] = now
+            for key, reduce in pairs:
+                self.stats[key] += reduce(gain)
 
     def close(self):
         self.sync_scope()

@@ -38,6 +38,23 @@ def softmax_with_cross_entropy(logits, label, soft_label=False,
     return loss
 
 
+def lm_head_loss(input, label, vocab_size, param_attr=None, name=None):
+    """Untied language-model head and per-token cross-entropy in one op
+    (ops/decoder_lm_ops.py): ``input`` [B, S, d] times a ``[d,
+    vocab_size]`` matrix, ``label`` [B, S]; returns the float32 loss of
+    every position [B, S].  The logits are formed a block of rows at a
+    time and never all at once in float32."""
+    helper = LayerHelper("lm_head_loss", name=name)
+    w = helper.create_parameter(param_attr,
+                                [int(input.shape[-1]), vocab_size],
+                                input.dtype)
+    loss = helper.create_variable_for_type_inference("float32", label.shape)
+    helper.append_op(type="lm_head_loss",
+                     inputs={"X": [input], "W": [w], "Label": [label]},
+                     outputs={"Loss": [loss]})
+    return loss
+
+
 def square_error_cost(input, label, name=None):
     helper = LayerHelper("square_error_cost", name=name)
     out = helper.create_variable_for_type_inference(input.dtype, input.shape)

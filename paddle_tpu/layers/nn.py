@@ -224,6 +224,38 @@ def layer_norm(input, scale=True, shift=True, begin_norm_axis=1,
     return helper.append_activation(out, act)
 
 
+def rms_norm(input, epsilon=1e-6, param_attr=None, name=None):
+    """``x / sqrt(mean(x^2) + epsilon) * g`` over the last axis (no
+    reference analog: the pre-norm decoders' RMSNorm; statistics in
+    float32)."""
+    helper = LayerHelper("rms_norm", name=name)
+    scale = helper.create_parameter(
+        param_attr, [int(input.shape[-1])], input.dtype,
+        default_initializer=ConstantInitializer(1.0))
+    out = helper.create_variable_for_type_inference(input.dtype, input.shape)
+    helper.append_op(type="rms_norm",
+                     inputs={"X": [input], "Scale": [scale]},
+                     outputs={"Y": [out]}, attrs={"epsilon": epsilon})
+    return out
+
+
+def rotary_embedding(input, head_dim, rope_theta=10000.0,
+                     rope_type="default", name=None, **yarn):
+    """Rotary position embedding (rotate-half form) of every head of a
+    ``[B, S, heads * head_dim]`` projection at positions ``0..S-1``.
+    ``rope_type="yarn"`` takes ``factor``,
+    ``original_max_position_embeddings``, ``beta_fast``, ``beta_slow``
+    and ``attention_factor`` as the published configs name them."""
+    helper = LayerHelper("rotary_embedding", name=name)
+    out = helper.create_variable_for_type_inference(input.dtype, input.shape)
+    helper.append_op(type="rotary_embedding", inputs={"X": [input]},
+                     outputs={"Out": [out]},
+                     attrs=dict(yarn, head_dim=int(head_dim),
+                                rope_theta=float(rope_theta),
+                                rope_type=rope_type))
+    return out
+
+
 def embedding(input, size, is_sparse=False, is_distributed=False,
               padding_idx=None, param_attr=None, dtype="float32", name=None):
     """ref: layers/nn.py embedding (lookup_table_v2).  ``is_sparse`` is a

@@ -6,6 +6,7 @@ from . import mnist      # noqa: F401
 from . import resnet     # noqa: F401
 from . import bert       # noqa: F401
 from . import decoder    # noqa: F401
+from . import decoder_lm  # noqa: F401
 from . import transformer  # noqa: F401
 from . import ernie      # noqa: F401
 from . import word2vec   # noqa: F401

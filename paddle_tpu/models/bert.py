@@ -118,7 +118,7 @@ def encoder_layer(x, attn_bias, cfg: BertConfig, name: str, is_test=False):
 
 
 def fused_attention(q, k, v, attn_bias, n_head, dropout_rate, is_test,
-                    name, causal=False):
+                    name, causal=False, window=None, num_kv_heads=None):
     from ..framework.layer_helper import LayerHelper
     helper = LayerHelper("fused_attention", name=f"{name}_attn")
     out = helper.create_variable_for_type_inference(q.dtype, q.shape)
@@ -128,10 +128,17 @@ def fused_attention(q, k, v, attn_bias, n_head, dropout_rate, is_test,
     # causality is an OP attr, not a baked [S, S] bias constant: the mask
     # is built from traced shapes inside the op, keeping the graph
     # length-polymorphic for bucketed compilation (SURVEY hard part #3)
+    attrs = {"n_head": n_head, "dropout_rate": dropout_rate,
+             "is_test": is_test, "causal": causal}
+    # a decoder's sliding window and grouped K/V heads (models/
+    # decoder_lm.py): stamped only when set, so every other program's op
+    # is what it was
+    if window:
+        attrs["window"] = int(window)
+    if num_kv_heads and num_kv_heads != n_head:
+        attrs["num_kv_heads"] = int(num_kv_heads)
     helper.append_op(type="fused_attention", inputs=inputs,
-                     outputs={"Out": [out]},
-                     attrs={"n_head": n_head, "dropout_rate": dropout_rate,
-                            "is_test": is_test, "causal": causal})
+                     outputs={"Out": [out]}, attrs=attrs)
     return out
 
 

@@ -10,12 +10,16 @@ CPU/interpret fallback: the one-tile kernels
 (ops/pallas/attention_tile.py) when the whole problem is one tile —
 non-causal, Sq == Sk == 128, which they take in this (B, S, H*D) layout —
 and the blockwise flash kernel (ops/pallas/flash_attention.py, on
-head-split operands) for causal, cached, ring and longer sequences.
+head-split operands) for causal, cached, ring and longer sequences; and
+for a decoder's grouped K/V heads and sliding window (attrs
+``num_kv_heads``, ``window``) the kernels of ops/pallas/flash_gqa.py,
+which also take the op's layout and skip the key blocks outside the
+window.
 
 Routing goes through the registry's Pallas channel
 (``pallas_route("fused_attention", ...)`` — ops/op_specs.py registers the
-``attention_tile``, ``flash_attention``, ``cached_flash_attention`` and
-``ring_flash_attention`` routes; which one is read from shapes and attrs,
+``attention_tile``, ``flash_attention``, ``flash_gqa_attention``,
+``cached_flash_attention`` and ``ring_flash_attention`` routes; which one is read from shapes and attrs,
 never from a flag or a model name), so the gate is
 statically enumerable, every hit/fallback lands in
 ``observability.metrics`` counters labeled by op + reason, and fallback
@@ -134,6 +138,32 @@ def lower_attention_tile(ctx, ins, attrs):
         dropout_rate=rate, seed=seed)}
 
 
+def gqa_attrs(attrs):
+    """(window, K/V heads) when the op is a decoder's grouped-head or
+    windowed attention, else None: the attrs alone decide (the plain,
+    one-tile and flash routes never see such an op)."""
+    window, n_kv = attrs.get("window"), attrs.get("num_kv_heads")
+    if not window and not n_kv:
+        return None
+    return int(window or 0), int(n_kv or attrs["n_head"])
+
+
+def lower_gqa_attention(ctx, ins, attrs, use_flash=True):
+    """Causal attention over grouped K/V heads with an optional window:
+    the ``flash_gqa_attention`` Pallas route, or the same mathematics
+    with the scores materialised (CPU, tests)."""
+    from .pallas import flash_gqa
+    window, n_kv = gqa_attrs(attrs)
+    if not attrs.get("causal") or x(ins, "AttnBias") is not None \
+            or attrs.get("dropout_rate"):
+        raise ValueError("fused_attention with num_kv_heads/window is "
+                         "causal, without bias or dropout")
+    fn = flash_gqa.flash_gqa_bsd if use_flash else flash_gqa.reference
+    return {"Out": fn(x(ins, "Q"), x(ins, "K"), x(ins, "V"),
+                      n_head=attrs["n_head"], n_kv_head=n_kv,
+                      window=window)}
+
+
 def lower_cached_attention(ctx, ins, attrs, use_flash=False):
     """Cache-read attention for the paged decode runtime: K/V come from
     the block pools THROUGH the per-sequence block table instead of a
@@ -220,6 +250,13 @@ def _fused_attention(ctx, ins, attrs):
         if route is not None:
             return route.lower(ctx, ins, attrs)
         return lower_ring_attention(ctx, ins, attrs, use_flash=False)
+    # a decoder's grouped K/V heads or window: its own kernels
+    if gqa_attrs(attrs) is not None:
+        route, _ = pallas_route("fused_attention", ins, attrs,
+                                kernel="flash_gqa_attention")
+        if route is not None:
+            return route.lower(ctx, ins, attrs)
+        return lower_gqa_attention(ctx, ins, attrs, use_flash=False)
     # one tile (Sq == Sk == 128, non-causal) has its own kernels; every
     # other shape the blockwise kernel tiles is the flash route's
     route, _ = pallas_route("fused_attention", ins, attrs,

@@ -509,15 +509,27 @@ def _infer_fused_attention(ins, attrs):
                 kind="shape")
         return {"Out": [VarSig(q.shape, q.dtype)]}
     k, v = _sig(ins, "K"), _sig(ins, "V")
+    # grouped K/V heads: K and V are num_kv_heads / n_head as wide as Q
+    n_kv, n_head = attrs.get("num_kv_heads"), attrs.get("n_head") or 1
+    want = q.shape[-1]
+    if n_kv and want >= 0:
+        if n_head % int(n_kv) or want % n_head:
+            raise SpecMismatch(
+                f"fused_attention: {n_head} query heads of width "
+                f"{want} do not divide over {n_kv} K/V heads",
+                kind="shape")
+        want = want // n_head * int(n_kv)
     for other, nm in ((k, "K"), (v, "V")):
         if other is None or other.shape is None:
             continue
         if len(other.shape) == len(q.shape) and \
-                other.shape[-1] >= 0 and q.shape[-1] >= 0 and \
-                other.shape[-1] != q.shape[-1]:
+                other.shape[-1] >= 0 and want >= 0 and \
+                other.shape[-1] != want:
             raise SpecMismatch(
                 f"fused_attention: {nm} hidden width {other.shape[-1]} "
-                f"!= Q hidden width {q.shape[-1]}", kind="shape")
+                f"!= {want} (Q's width"
+                + (f" over {n_head} heads times {n_kv})" if n_kv else ")"),
+                kind="shape")
     return {"Out": [VarSig(q.shape, q.dtype)]}
 
 
@@ -957,6 +969,88 @@ def _flops_moe_expert_ffn(ins, outs, attrs):
     return 4.0 * e * b * m * h
 
 
+def _infer_moe_topk_router(ins, attrs):
+    """TopkWeight [N, k] float32 and TopkIndex [N, k] int32 over the
+    flattened tokens; W's rows must match X's width."""
+    xv, w = _sig(ins, "X"), _sig(ins, "W")
+    if xv is None or xv.shape is None:
+        return None
+    if w is not None and w.shape is not None and len(w.shape) == 2 and \
+            w.shape[0] >= 0 and xv.shape[-1] >= 0 and \
+            w.shape[0] != xv.shape[-1]:
+        raise SpecMismatch(
+            f"moe_topk_router: W rows {w.shape[0]} != X width "
+            f"{xv.shape[-1]}", kind="shape")
+    lead = xv.shape[:-1]
+    n = _numel(lead) if _known(lead) else -1
+    k = int(attrs.get("top_k", 1))
+    return {"TopkWeight": [VarSig((n, k), "float32")],
+            "TopkIndex": [VarSig((n, k), "int32")]}
+
+
+def _infer_moe_grouped_ffn(ins, attrs):
+    xv, wg = _sig(ins, "X"), _sig(ins, "WGate")
+    if xv is None or xv.shape is None:
+        return None
+    for slot in ("WGate", "WUp", "WDown"):
+        w = _sig(ins, slot)
+        if w is not None and w.shape is not None and len(w.shape) != 3:
+            raise SpecMismatch(
+                f"moe_grouped_ffn: {slot} must be 3-D [E_local, in, out], "
+                f"got {list(w.shape)}", kind="shape")
+    out = {"Out": [VarSig(xv.shape, xv.dtype)]}
+    if wg is not None and wg.shape is not None:
+        out["ExpertCount"] = [VarSig((wg.shape[0],), "int32")]
+    return out
+
+
+def _flops_moe_grouped_ffn(ins, outs, attrs):
+    """Three grouped products over the assignments EXPECTED here under
+    uniform routing: 6 * N * k * (E_local / E) * d * f (the real count is
+    the op's ExpertCount output)."""
+    xv, wg = _sig(ins, "X"), _sig(ins, "WGate")
+    idx = _sig(ins, "TopkIndex")
+    if xv is None or wg is None or idx is None or not _known(xv.shape) \
+            or not _known(wg.shape) or not _known(idx.shape):
+        return None
+    e_local, d, f = wg.shape
+    share = e_local / float(attrs.get("num_experts") or e_local)
+    return 6.0 * _numel(idx.shape) * share * d * f
+
+
+def _infer_lm_head_loss(ins, attrs):
+    xv, w, lab = _sig(ins, "X"), _sig(ins, "W"), _sig(ins, "Label")
+    if xv is not None and w is not None and xv.shape is not None and \
+            w.shape is not None and len(w.shape) == 2 and \
+            xv.shape[-1] >= 0 and w.shape[0] >= 0 and \
+            xv.shape[-1] != w.shape[0]:
+        raise SpecMismatch(
+            f"lm_head_loss: W rows {w.shape[0]} != X width "
+            f"{xv.shape[-1]}", kind="shape")
+    if lab is None or lab.shape is None:
+        return None
+    return {"Loss": [VarSig(lab.shape, "float32")]}
+
+
+def _flops_lm_head_loss(ins, outs, attrs):
+    lab, w = _sig(ins, "Label"), _sig(ins, "W")
+    if lab is None or w is None or not _known(lab.shape) \
+            or not _known(w.shape):
+        return None
+    return 2.0 * _numel(lab.shape) * w.shape[0] * w.shape[1]
+
+
+def _pl_gmm_supported(ins, attrs, axis_sizes=None):
+    """Grouped-matmul route gate (ops/pallas/grouped_matmul.py): whole
+    weight blocks per expert, so both widths must be lane-aligned."""
+    wg = _shape_of(_sig(ins, "WGate"))
+    if wg is None or len(wg) != 3 or min(wg) < 0:
+        return False, "shape-unknown"
+    if wg[1] % 128 or wg[2] % 128:
+        return False, f"widths:{wg[1]}x{wg[2]}%128"
+    return True, ""
+
+
 def _flops_moe_combine(ins, outs, attrs):
     """The combine einsum gsec,egcm→gsm: 2·G·S·E·C·m."""
     comb, xv = _sig(ins, "Combine"), _sig(ins, "X")
@@ -1238,6 +1332,31 @@ def _pl_ring_supported(ins, attrs, axis_sizes=None):
     return _flash_tiles(s, sk, d)
 
 
+def _gqa_stamped(attrs, axis_sizes=None):
+    return bool(attrs.get("window") or attrs.get("num_kv_heads"))
+
+
+def _pl_gqa_supported(ins, attrs, axis_sizes=None):
+    """Grouped-head / windowed decoder attention gate
+    (ops/pallas/flash_gqa.py): causal self-attention, no bias, the
+    sequence in tiles of 128, heads of a multiple of 128 lanes."""
+    from .pallas.flash_gqa import supported
+    dims = _attn_bhsd(ins, attrs)
+    if dims is None:
+        return False, "shape-unknown"
+    b, h, s, sk, d = dims
+    if s != sk or not attrs.get("causal") or \
+            _sig(ins, "AttnBias") is not None:
+        return False, "gqa-needs-causal-self-attention-without-bias"
+    return supported(s, d, h, int(attrs.get("num_kv_heads") or h),
+                     backend="tpu")
+
+
+def _lower_gqa_attention(ctx, ins, attrs):
+    from .attention_ops import lower_gqa_attention
+    return lower_gqa_attention(ctx, ins, attrs, use_flash=True)
+
+
 def _ring_stamped(attrs, axis_sizes):
     ax = attrs.get("_seq_axis")
     return bool(ax) and (axis_sizes is None or ax in (axis_sizes or {}))
@@ -1408,9 +1527,16 @@ _FLASH_KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
 _PL_FLASH = PallasLowering(
     "flash_attention", flag="use_flash_attention", attr="use_flash",
     match=lambda attrs, ax: not _ring_stamped(attrs, ax)
-    and not attrs.get("_cached"),
+    and not attrs.get("_cached") and not _gqa_stamped(attrs),
     supported=_pl_flash_supported, lower=_lower_flash_attention,
     kernels=_FLASH_KERNELS)
+# a decoder's grouped K/V heads and sliding window: attrs alone pick the
+# route, so no other attention (and neither cell that has one) sees it
+_PL_GQA = PallasLowering(
+    "flash_gqa_attention", flag="use_flash_attention", attr="use_flash",
+    match=_gqa_stamped,
+    supported=_pl_gqa_supported, lower=_lower_gqa_attention,
+    kernels=("flash_gqa_fwd", "flash_gqa_bwd_dq", "flash_gqa_bwd_dkv"))
 # the plain (no ring, no KV pool) fused_attention tries this route first:
 # at one tile the blockwise kernel's machinery is pure cost (PERF.md,
 # PR 28); any other shape falls through to _PL_FLASH
@@ -1434,6 +1560,10 @@ _PL_CACHED = PallasLowering(
     and not _ring_stamped(attrs, ax),
     supported=_pl_cached_supported, lower=_lower_cached_flash_attention,
     kernels=_FLASH_KERNELS)
+_PL_GMM = PallasLowering(
+    "moe_grouped_matmul", flag="use_pallas_fused",
+    supported=_pl_gmm_supported,
+    kernels=("moe_gmm", "moe_gmm_wgrad"))
 _PL_ADAM = PallasLowering(
     "fused_adam", flag="use_pallas_fused",
     supported=_pl_adam_supported,
@@ -1536,7 +1666,7 @@ def register_default_specs():
     op_spec("fused_attention", infer=_infer_fused_attention,
             mem_backward_extra=_attention_probs_bytes,
             flops=_flops_fused_attention,
-            pallas=(_PL_RING, _PL_CACHED, _PL_TILE, _PL_FLASH))
+            pallas=(_PL_RING, _PL_CACHED, _PL_GQA, _PL_TILE, _PL_FLASH))
     op_spec("cache_write", infer=_infer_cache_write)
     op_spec("decode_chain", infer=_infer_decode_chain)
 
@@ -1636,6 +1766,18 @@ def register_default_specs():
             flops=_flops_moe_expert_ffn)
     op_spec("moe_combine", infer=same_as_input(),
             flops=_flops_moe_combine)
+    # the pre-norm rotary decoder with dropless experts
+    # (ops/decoder_lm_ops.py)
+    op_spec("rms_norm", infer=same_as_input("X", "Y"),
+            flops=_flops_elemwise(4))
+    op_spec("rotary_embedding", infer=same_as_input(),
+            flops=_flops_elemwise(3))
+    op_spec("moe_topk_router", infer=_infer_moe_topk_router)
+    op_spec("moe_grouped_ffn", infer=_infer_moe_grouped_ffn,
+            flops=_flops_moe_grouped_ffn, pallas=(_PL_GMM,))
+    op_spec("moe_load_stats", infer=same_as_input("Acc", "AccOut"))
+    op_spec("lm_head_loss", infer=_infer_lm_head_loss,
+            flops=_flops_lm_head_loss)
     # vocab-parallel embedding: Out = Ids.shape + [dim] exactly like
     # lookup_table_v2 (the psum keeps the global [.., dim] width).
     # Without this the tp-BERT shape propagation stalled at op 0 and

@@ -22,5 +22,5 @@ from .tp_layers import (column_parallel_fc, row_parallel_fc,  # noqa: F401
                         parallel_multihead_attention)
 from .ring_attention import ring_attention  # noqa: F401
 from .pipeline import (gpipe_spmd, PipelineOptimizer)  # noqa: F401
-from .moe import (moe_ffn, collect_aux_losses,  # noqa: F401
-                  apply_expert_sharding)
+from .moe import (moe_ffn, moe_dropless_ffn,  # noqa: F401
+                  collect_aux_losses, apply_expert_sharding)

@@ -46,6 +46,17 @@ def _quant_attr(quant_spec):
     return CompressionSpec.from_attr(quant_spec).to_attr()
 
 
+def _suffixed(attr, suffix):
+    """One shared param_attr names several params — suffix each."""
+    from ..framework.layer_helper import ParamAttr
+    a = ParamAttr._to_attr(attr)
+    if a and getattr(a, "name", None):
+        import copy
+        a = copy.copy(a)
+        a.name = f"{a.name}_{suffix}"
+    return a
+
+
 def moe_ffn(x: Variable, num_experts: int, ffn_hidden: int,
             top_k: int = 2, capacity_factor: float = 1.25,
             ep_degree: Optional[int] = None, axis_name: str = "ep",
@@ -69,21 +80,11 @@ def moe_ffn(x: Variable, num_experts: int, ffn_hidden: int,
     helper = LayerHelper(name or "moe_ffn", name=name)
     m = int(x.shape[-1])
 
-    def _sub(attr, suffix):
-        """One shared param_attr names three params — suffix each."""
-        from ..framework.layer_helper import ParamAttr
-        a = ParamAttr._to_attr(attr)
-        if a and getattr(a, "name", None):
-            import copy
-            a = copy.copy(a)
-            a.name = f"{a.name}_{suffix}"
-        return a
-
-    gate_w = helper.create_parameter(_sub(param_attr, "gate"),
+    gate_w = helper.create_parameter(_suffixed(param_attr, "gate"),
                                      [m, num_experts], x.dtype)
-    w1 = helper.create_parameter(_sub(param_attr, "w1"),
+    w1 = helper.create_parameter(_suffixed(param_attr, "w1"),
                                  [num_experts, m, ffn_hidden], x.dtype)
-    w2 = helper.create_parameter(_sub(param_attr, "w2"),
+    w2 = helper.create_parameter(_suffixed(param_attr, "w2"),
                                  [num_experts, ffn_hidden, m], x.dtype)
     if ep > 1:
         # expert dim sharded; grads arrive pre-summed through the
@@ -93,10 +94,10 @@ def moe_ffn(x: Variable, num_experts: int, ffn_hidden: int,
         w2.dist_attr = ShardSpec((axis_name, None, None))
     ffn_inputs: Dict[str, list] = {"W1": [w1], "W2": [w2]}
     if bias_attr is not False:
-        b1 = helper.create_parameter(_sub(bias_attr, "b1"),
+        b1 = helper.create_parameter(_suffixed(bias_attr, "b1"),
                                      [num_experts, ffn_hidden], x.dtype,
                                      is_bias=True)
-        b2 = helper.create_parameter(_sub(bias_attr, "b2"),
+        b2 = helper.create_parameter(_suffixed(bias_attr, "b2"),
                                      [num_experts, m], x.dtype, is_bias=True)
         if ep > 1:
             b1.dist_attr = ShardSpec((axis_name, None))
@@ -159,6 +160,76 @@ def moe_ffn(x: Variable, num_experts: int, ffn_hidden: int,
     # loss without threading lists through their call stacks
     collect_aux_losses(helper.main_program, peek=True).append(aux)
     return out, aux
+
+
+def moe_dropless_ffn(x: Variable, num_experts: int, ffn_hidden: int,
+                     top_k: int, held_experts: Optional[Tuple[int, int]]
+                     = None, norm_topk_prob: bool = True, param_attr=None,
+                     name: Optional[str] = None) -> Variable:
+    """Dropless SiLU-gated expert block: ``softmax(x W_r)`` over ALL
+    ``num_experts`` without a capacity, the ``top_k`` largest
+    (renormalised under ``norm_topk_prob``), and
+    ``sum_k w_k (silu(x W_g) * x W_u) W_d`` over the experts HELD HERE,
+    ``held_experts = (lo, hi)`` — what every chip of an expert-parallel
+    layer computes before the exchange adds the parts; ``None`` holds
+    them all.  Assignments to experts held elsewhere are skipped; none to
+    a held expert is ever dropped.  Per-expert counts accumulate on the
+    device in a persistable counter that ``PreparedStep.stats`` reads
+    (``moe_assignments_local``, ``moe_expert_load_max|mean``)."""
+    from ..framework.initializer import ConstantInitializer
+    from ..ops.decoder_lm_ops import LOAD_STATS_EXTRA
+    lo, hi = held_experts if held_experts is not None else (0, num_experts)
+    if not 0 <= lo < hi <= num_experts:
+        raise ValueError(f"held_experts {held_experts} outside "
+                         f"0..{num_experts}")
+    helper = LayerHelper(name or "moe_dropless_ffn", name=name)
+    d, e_local = int(x.shape[-1]), hi - lo
+
+    def param(suffix, shape):
+        return helper.create_parameter(_suffixed(param_attr, suffix), shape,
+                                       x.dtype)
+
+    router_w = param("router_w", [d, num_experts])
+    wg = param("expert_gate_w", [e_local, d, ffn_hidden])
+    wu = param("expert_up_w", [e_local, d, ffn_hidden])
+    wd = param("expert_down_w", [e_local, ffn_hidden, d])
+    weight = helper.create_variable_for_type_inference("float32",
+                                                       (-1, top_k))
+    index = helper.create_variable_for_type_inference(
+        "int32", (-1, top_k), stop_gradient=True)
+    helper.append_op(
+        type="moe_topk_router", inputs={"X": [x], "W": [router_w]},
+        outputs={"TopkWeight": [weight], "TopkIndex": [index]},
+        attrs={"top_k": top_k, "norm_topk_prob": norm_topk_prob})
+    out = helper.create_variable_for_type_inference(x.dtype, x.shape)
+    count = helper.create_variable_for_type_inference(
+        "int32", (e_local,), stop_gradient=True)
+    helper.append_op(
+        type="moe_grouped_ffn",
+        inputs={"X": [x], "TopkWeight": [weight], "TopkIndex": [index],
+                "WGate": [wg], "WUp": [wu], "WDown": [wd]},
+        outputs={"Out": [out], "ExpertCount": [count]},
+        attrs={"expert_offset": lo, "num_experts": num_experts})
+    # the load counter: a persistable the step carries on the device
+    acc_name = f"{helper.name}.load_stats"
+    shape = [e_local + LOAD_STATS_EXTRA]
+    acc = helper.main_program.global_block().create_var(
+        name=acc_name, shape=shape, dtype="int32", persistable=True,
+        stop_gradient=True)
+    sb = helper.startup_program.global_block()
+    ConstantInitializer(0)(sb.create_var(
+        name=acc_name, shape=shape, dtype="int32", persistable=True), sb)
+    helper.append_op(type="moe_load_stats",
+                     inputs={"Count": [count], "Acc": [acc]},
+                     outputs={"AccOut": [acc]}, attrs={})
+    # what PreparedStep.wait() adds to its stats from the counter's gain
+    helper.main_program.__dict__.setdefault("_device_counters", {})[
+        acc_name] = (
+        ("moe_assignments_local", lambda g: int(g[:e_local].sum())),
+        ("moe_expert_load_max", lambda g: int(g[e_local])),
+        ("moe_expert_load_mean", lambda g: float(g[:e_local].sum())
+         / e_local))
+    return out
 
 
 def collect_aux_losses(program, peek: bool = False):

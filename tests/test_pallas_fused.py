@@ -95,6 +95,24 @@ def test_adam_update_matches_composition():
     np.testing.assert_allclose(np.asarray(po), p_ref, rtol=1e-4, atol=1e-6)
 
 
+def test_adam_update_stacked_3d_param():
+    rng = np.random.RandomState(5)
+    shape = (3, 16, 2304)               # stacked experts: [E, rows, cols]
+    p, g = (rng.randn(*shape).astype(np.float32) for _ in range(2))
+    m = rng.randn(*shape).astype(np.float32) * 0.1
+    v = np.abs(rng.randn(*shape)).astype(np.float32) * 0.01
+    po, mo, vo = F.adam_update(*(jnp.asarray(t) for t in (p, g, m, v)),
+                               0.01, beta1=0.9, beta2=0.999, eps=1e-8,
+                               interpret=True)
+    m_ref = 0.9 * m + 0.1 * g
+    v_ref = 0.999 * v + 0.001 * g * g
+    np.testing.assert_allclose(np.asarray(mo), m_ref, rtol=1e-4, atol=1e-7)
+    np.testing.assert_allclose(np.asarray(vo), v_ref, rtol=1e-4, atol=1e-7)
+    np.testing.assert_allclose(
+        np.asarray(po), p - 0.01 * m_ref / (np.sqrt(v_ref) + 1e-8),
+        rtol=1e-4, atol=1e-6)
+
+
 def test_adam_update_2d_param_shape_roundtrip():
     rng = np.random.RandomState(4)
     p = rng.randn(16, 128).astype(np.float32)

@@ -78,3 +78,72 @@ def test_attention_tile_kernels_compile_for_v5e(one_chip, no_compile_cache,
         x, x, x, x, bias, sds((1,), jnp.int32)).compile()
     txt = compiled.as_text()
     assert "attn_tile_fwd" in txt and "attn_tile_bwd" in txt
+
+
+@pytest.mark.parametrize("window", [1024, None],
+                         ids=["sliding-1024", "full-causal"])
+def test_flash_gqa_kernels_compile_for_v5e(one_chip, no_compile_cache,
+                                           window):
+    """Mellum2's attention at the timed size: 8 192 tokens, 32 query
+    heads of 128 on 4 K/V heads, bf16, forward and both backward
+    kernels, in the op's own (B, S, H * D) layout."""
+    from paddle_tpu.ops.pallas import flash_gqa as fg
+
+    def sds(width):
+        return jax.ShapeDtypeStruct((1, 8192, width), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    def step(q, k, v, g):
+        out, vjp = jax.vjp(lambda *a: fg.flash_gqa_bsd(
+            *a, n_head=32, n_kv_head=4, window=window), q, k, v)
+        return (out,) + vjp(g)
+
+    txt = jax.jit(step).lower(sds(4096), sds(512), sds(512),
+                              sds(4096)).compile().as_text()
+    for name in ("flash_gqa_fwd", "flash_gqa_bwd_dq", "flash_gqa_bwd_dkv"):
+        assert name in txt
+
+
+@pytest.mark.parametrize("tile_m", [128, 256])
+def test_grouped_matmul_kernels_compile_for_v5e(one_chip, no_compile_cache,
+                                                tile_m):
+    """Mellum2's experts at the timed size: 8 192 tokens top-8 over 64,
+    16 held, 2304 x 896, bf16 — the worst-case buffer (65 536 rows and a
+    tile an expert)."""
+    from paddle_tpu.ops.decoder_lm_ops import grouped_ffn
+
+    def sds(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def step(x, w, idx, wg, wu, wd, g):
+        out, vjp = jax.vjp(lambda *a: grouped_ffn(
+            a[0], a[1], idx, *a[2:], backend="pallas",
+            tile_m=tile_m)[0], x, w, wg, wu, wd)
+        return (out,) + vjp(g)
+
+    txt = jax.jit(step).lower(
+        sds((8192, 2304)), sds((8192, 8), jnp.float32),
+        sds((8192, 8), jnp.int32), sds((16, 2304, 896)),
+        sds((16, 2304, 896)), sds((16, 896, 2304)),
+        sds((8192, 2304))).compile().as_text()
+    assert "moe_gmm" in txt and "moe_gmm_wgrad" in txt
+
+
+@pytest.mark.parametrize("shape", [(16, 2304, 896), (2304, 24576),
+                                   (768, 3072), (30522, 768), (2304, 64)],
+                         ids=lambda s: "x".join(map(str, s)))
+def test_fused_adam_compiles_for_v5e(one_chip, no_compile_cache, shape):
+    """The fused Adam kernel at the models' parameter shapes (stacked
+    experts, the head's slice, BERT's FFN and embedding, a router)."""
+    from paddle_tpu.ops.pallas import fused_ops as F
+
+    def sds():
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    def step(p, g, m, v):
+        return F.adam_update(p, g, m, v, 1e-3, beta1=0.9, beta2=0.999,
+                             eps=1e-8)
+
+    txt = jax.jit(step, donate_argnums=(0, 2, 3)).lower(
+        sds(), sds(), sds(), sds()).compile().as_text()
+    assert "fused_adam" in txt
