@@ -329,21 +329,37 @@ def leg_kernels(S: Sizes):
     _say("  ... at the gate bounds:")
     row_kernels(*S.bound)
 
-    # -- fused Adam: the gate's floor, a ragged row count, and the
-    # word-embedding-sized tensor (partial last block) -------------------
-    for n in (F.ADAM_MIN_NUMEL, 128 * 1027,
-              S.cfg.vocab_size * S.cfg.hidden_size)[:1 if S.dry else 3]:
-        assert n % 128 == 0, n
-        p0, g0, m0, v0 = randn(n), randn(n), randn(n), jnp.abs(randn(n))
-        po, mo, vo = jax.jit(lambda *a: F.adam_update(
-            *a, jnp.float32(0.01), beta1=0.9, beta2=0.999, eps=1e-8,
-            interpret=interp))(p0, g0, m0, v0)
-        m_ref = 0.9 * m0 + 0.1 * g0
-        v_ref = 0.999 * v0 + 0.001 * g0 * g0
-        p_ref = p0 - 0.01 * m_ref / (jnp.sqrt(v_ref) + 1e-8)
-        e = max(_rel(po, p_ref), _rel(mo, m_ref), _rel(vo, v_ref))
-        _say(f"  fused_adam n={n}: rel err {e:.2e}")
-        assert e < 1e-5, (n, e)
+    # -- Adam: the op is one XLA composition (no kernel: PERF.md section
+    # 6, PR 30), on the chip against float64 on the host, at the
+    # trainers' shapes: 1-D (a bias, a ZeRO shard with a ragged row
+    # count, the word embedding's numel), stacked experts, the word
+    # embedding (30522 rows, two past a sublane tile), a router's 64
+    # columns (half a lane tile) ----------------------------------------
+    from paddle_tpu.ops.registry import get_op
+    h, vocab = S.cfg.hidden_size, S.cfg.vocab_size
+    shapes = ((1024,), (128 * 1027,), (vocab * h,),
+              (16, 3 * h, 7 * 128), (vocab, h), (3 * h, 64))
+    lr, b1p, b2p = (jnp.full((1,), c, jnp.float32)
+                    for c in (0.01, 0.9, 0.999))
+    for shape in shapes[:1] + shapes[3:] if S.dry else shapes:
+        p0, g0, m0, v0 = (randn(*shape), randn(*shape), randn(*shape),
+                          jnp.abs(randn(*shape)))
+        out = jax.jit(lambda p, g, m, v: get_op("adam")(None, {
+            "Param": [p], "Grad": [g], "Moment1": [m], "Moment2": [v],
+            "LearningRate": [lr], "Beta1Pow": [b1p], "Beta2Pow": [b2p]},
+            {}))(p0, g0, m0, v0)
+        p64, g64, m64, v64 = (np.asarray(t, np.float64)
+                              for t in (p0, g0, m0, v0))
+        m_ref = 0.9 * m64 + 0.1 * g64
+        v_ref = 0.999 * v64 + 0.001 * g64 * g64
+        p_ref = p64 - 0.01 * np.sqrt(1 - 0.999) / (1 - 0.9) * m_ref / (
+            np.sqrt(v_ref) + 1e-8)
+        e = max(_rel(out["ParamOut"], p_ref), _rel(out["Moment1Out"], m_ref),
+                _rel(out["Moment2Out"], v_ref))
+        _say(f"  adam {shape}: rel err {e:.2e}")
+        assert e < 1e-5, (shape, e)
+        assert all(out[k].shape == shape for k in
+                   ("ParamOut", "Moment1Out", "Moment2Out")), shape
 
     # -- quantized-collective receive stage (needs no mesh: the kernels
     # take the post-all_to_all payload) ----------------------------------
@@ -430,13 +446,6 @@ def _check_routes(routes, S: Sizes, want_hits, allowed_fallbacks):
         assert any(re.fullmatch(pat, f"{kernel}|{reason}")
                    for pat in allowed_fallbacks), \
             f"unexpected Pallas fallback {op} -> {kernel}: {reason}"
-
-
-#: the fused-Adam fallbacks the models' shapes explain: biases, LayerNorm
-#: scales, the 2-class head and small ZeRO-1 shards are below the kernel's
-#: floor; the vocab-sized bias is not a lane multiple
-ADAM_SHAPE_FALLBACKS = (r"fused_adam\|numel:\d+<\d+",
-                        r"fused_adam\|numel:\d+%128")
 
 
 def _assert_on_device(scope, device):
@@ -526,8 +535,8 @@ def leg_trainer(S: Sizes, platform: str):
 
     _check_routes(
         _routes_since(routes0), S,
-        want_hits=("attention_tile", "fused_layer_norm", "fused_adam"),
-        allowed_fallbacks=ADAM_SHAPE_FALLBACKS)
+        want_hits=("attention_tile", "fused_layer_norm"),
+        allowed_fallbacks=())
 
     # -- A/B: dropout off, Pallas flags on vs off, same weights (same
     # startup seed) and batch; every step's loss within AB_REL_TOL ------
@@ -698,9 +707,8 @@ def leg_decoder_lm(S: Sizes, platform: str):
     assert 0.5 * expect < stats["moe_assignments_local"] < 2 * expect
     _check_routes(
         _routes_since(routes0), S,
-        want_hits=("flash_gqa_attention", "moe_grouped_matmul",
-                   "fused_adam"),
-        allowed_fallbacks=ADAM_SHAPE_FALLBACKS)
+        want_hits=("flash_gqa_attention", "moe_grouped_matmul"),
+        allowed_fallbacks=())
 
 
 # ---------------------------------------------------------------------------
@@ -934,8 +942,8 @@ def leg_four_chips(S: Sizes, platform: str):
              f"the HLO; smoke timing first step {t_first:.1f} s")
     _check_routes(
         _routes_since(routes0), S,
-        want_hits=("attention_tile", "fused_layer_norm", "fused_adam"),
-        allowed_fallbacks=ADAM_SHAPE_FALLBACKS)
+        want_hits=("attention_tile", "fused_layer_norm"),
+        allowed_fallbacks=())
 
     # -- C2: parity — dp4 vs one chip, one global batch, dropout off ----
     cfg = S.nodrop(layers=cut)
@@ -984,9 +992,8 @@ def leg_four_chips(S: Sizes, platform: str):
          f"{len(split)} persistables sharded over tp (e.g. {split[0]} "
          f"{tuple(w.shape)} as {w.sharding.shard_shape(w.shape)})")
 
-    # -- C4: ZeRO-1 sharded update — the fused Adam kernel on the
-    # 128-aligned flat state shards, under shard_map ---------------------
-    routes0 = _route_counters()
+    # -- C4: ZeRO-1 sharded update — Adam on the 128-aligned flat state
+    # shards, under shard_map --------------------------------------------
     _, startupz, totalz, progz = fleet_program(
         S.nodrop(layers=cut), sharded_update=True)
     with fluid.scope_guard(fluid.Scope()):
@@ -995,9 +1002,6 @@ def leg_four_chips(S: Sizes, platform: str):
         z2 = _mesh_run(exe, progz, small, totalz)
     assert z2 < z1, (z1, z2)
     assert abs(z1 - four) / abs(four) < DP_PARITY_REL_TOL, (z1, four)
-    _check_routes(
-        _routes_since(routes0), S, want_hits=("fused_adam",),
-        allowed_fallbacks=ADAM_SHAPE_FALLBACKS)
     _say(f"  ZeRO-1 sharded update, {cut} layer(s): loss {z1:.5f} -> "
          f"{z2:.5f}, first step equal to plain dp4")
 

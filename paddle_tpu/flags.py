@@ -44,7 +44,7 @@ _register("rpc_retry_times", 3)
 # slower — debug only
 _register("check_nan_inf_per_op", False)
 _register("use_flash_attention", True)     # pallas kernel gate (TPU-new)
-_register("use_pallas_fused", True)        # fused LN/bias-gelu/adam kernels
+_register("use_pallas_fused", True)        # fused LN/add-LN/bias-gelu kernels
 # reuse the device copy of a feed array fed repeatedly: sound only when the
 # caller promises not to mutate the buffer in place, signalled by freezing
 # it (arr.flags.writeable = False) — the analog of the reference's

@@ -1362,24 +1362,6 @@ def _ring_stamped(attrs, axis_sizes):
     return bool(ax) and (axis_sizes is None or ax in (axis_sizes or {}))
 
 
-def _pl_adam_supported(ins, attrs, axis_sizes=None):
-    if attrs.get("lazy_mode") and ins.get("SparseRows"):
-        return False, "sparse-rows"
-    shapes = [_shape_of(_sig(ins, slot))
-              for slot in ("Param", "Grad", "Moment1")]
-    if any(sh is None or any(d < 0 for d in sh) for sh in shapes):
-        return False, "shape-unknown"
-    if not shapes[0] == shapes[1] == shapes[2]:
-        return False, "param-grad-moment-shapes"
-    from .pallas.fused_ops import ADAM_MIN_NUMEL
-    n = _numel(shapes[0])
-    if n % 128:
-        return False, f"numel:{n}%128"
-    if n < ADAM_MIN_NUMEL:
-        return False, f"numel:{n}<{ADAM_MIN_NUMEL}"
-    return True, ""
-
-
 def _rows_last_dim(sig, bna):
     sh = _shape_of(sig)
     if sh is None or any(d < 0 for d in sh[bna:]):
@@ -1564,10 +1546,6 @@ _PL_GMM = PallasLowering(
     "moe_grouped_matmul", flag="use_pallas_fused",
     supported=_pl_gmm_supported,
     kernels=("moe_gmm", "moe_gmm_wgrad"))
-_PL_ADAM = PallasLowering(
-    "fused_adam", flag="use_pallas_fused",
-    supported=_pl_adam_supported,
-    kernels=("fused_adam",))
 _PL_LN = PallasLowering(
     "fused_layer_norm", flag="use_pallas_fused",
     supported=_pl_ln_supported,
@@ -1693,13 +1671,11 @@ def register_default_specs():
                  "truncated_gaussian_random"):
         op_spec(name, infer=from_shape_attr())
 
-    # optimizer updates (adam/adamw carry the fused flat-shard kernel
-    # route — the ZeRO-1/ZeRO-3 1-D state shards are its ideal shape)
-    for name in ("sgd", "momentum", "adamax", "adagrad",
+    # optimizer updates: elementwise compositions, fused by XLA in each
+    # tensor's own layout (often into the gradient's producer)
+    for name in ("sgd", "momentum", "adamax", "adagrad", "adam", "adamw",
                  "rmsprop", "lars_momentum", "lamb"):
         op_spec(name, infer=_infer_opt_update)
-    for name in ("adam", "adamw"):
-        op_spec(name, infer=_infer_opt_update, pallas=(_PL_ADAM,))
 
     # meta ops (known to the static layer, no shape opinion)
     for name in ("feed", "fetch", "backward", "pipeline", "assign_value",

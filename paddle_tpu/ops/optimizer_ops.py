@@ -90,21 +90,11 @@ def _adam(ctx, ins, attrs):
                 "Moment2Out": jnp.where(rowsel, m2_new, m2),
                 "Beta1PowOut": b1p * beta1, "Beta2PowOut": b2p * beta2}
 
-    # fused one-pass update (input/output aliased): gate lives in the
-    # registry's pallas channel — the ZeRO-1/ZeRO-3 flat state shards
-    # are the kernel's ideal shape (1-D, 128-aligned via the sharded
-    # optimizer's shard padding)
-    from .registry import pallas_route
-    route, _ = pallas_route("adam", ins, attrs)
-    if route is not None:
-        from .pallas.fused_ops import adam_update
-        p_out, m1_out, m2_out = adam_update(
-            p, g, m1, m2, jnp.reshape(lr_t, ()),
-            beta1=beta1, beta2=beta2, eps=eps)
-        return {"ParamOut": p_out, "Moment1Out": m1_out,
-                "Moment2Out": m2_out, "Beta1PowOut": b1p * beta1,
-                "Beta2PowOut": b2p * beta2}
-
+    # one elementwise composition, left to XLA on purpose: it fuses the
+    # update in the tensor's own tiled layout, over the donated buffers,
+    # often into the matmul that produces the gradient.  A kernel needs a
+    # 2-D view of every tensor, and a view that is not free relays p, g,
+    # m and v; measured on the v5e, PERF.md section 6 (PR 30).
     m1_out = beta1 * m1 + (1 - beta1) * g
     m2_out = beta2 * m2 + (1 - beta2) * g * g
     p_out = p - lr_t.astype(p.dtype) * (

@@ -1,8 +1,7 @@
-"""Fused elementwise/normalisation/optimizer Pallas kernels — the TPU
-analog of the reference's hand-fused CUDA kernels (ref:
+"""Fused elementwise/normalisation Pallas kernels — the TPU analog of the
+reference's hand-fused CUDA kernels (ref:
 operators/fused/fused_layernorm_residual_dropout_bias.h,
-operators/fused/fused_bias_gelu (jit/gen_base.h family),
-operators/optimizers/adam_op.cu's fused update).
+operators/fused/fused_bias_gelu (jit/gen_base.h family)).
 
 XLA already fuses most elementwise chains; these kernels exist for the
 cases where owning the schedule still pays on TPU:
@@ -15,8 +14,6 @@ cases where owning the schedule still pays on TPU:
   separate reduction kernel.
 - ``bias_gelu``: bias-add + tanh-GELU in one pass; backward recomputes
   the activation input (bandwidth over FLOPs).
-- ``adam_update``: m/v/param updated in ONE read/write pass per tensor
-  with input/output aliasing (three separate HBM round-trips otherwise).
 
 The shape gates live in the registry's Pallas channel
 (ops/op_specs.py), which reads the bounds below; callers fall back to
@@ -41,8 +38,6 @@ BLOCK_R = 128          # row-block for [R, D] layouts at model widths
 #: its bound)
 LN_MAX_D = 8192
 BG_MAX_D = 16384
-#: fused Adam: below this a pallas_call costs more than it saves
-ADAM_MIN_NUMEL = 1024
 
 
 def _block_rows(d: int) -> int:
@@ -352,52 +347,3 @@ def _bg_bwd(interpret, res, dy):
 
 
 bias_gelu.defvjp(lambda x2, b, interp: _bg_fwd(x2, b, interp), _bg_bwd)
-
-
-# ---------------------------------------------------------------------------
-# fused Adam update
-# ---------------------------------------------------------------------------
-
-
-def _adam_kernel(lr_ref, p_ref, g_ref, m_ref, v_ref, po_ref, mo_ref,
-                 vo_ref, *, beta1, beta2, eps):
-    g = g_ref[...].astype(jnp.float32)
-    m = beta1 * m_ref[...].astype(jnp.float32) + (1 - beta1) * g
-    v = beta2 * v_ref[...].astype(jnp.float32) + (1 - beta2) * g * g
-    lr_t = lr_ref[0, 0]
-    p = p_ref[...].astype(jnp.float32) - lr_t * m / (jnp.sqrt(v) + eps)
-    po_ref[...] = p.astype(po_ref.dtype)
-    mo_ref[...] = m.astype(mo_ref.dtype)
-    vo_ref[...] = v.astype(vo_ref.dtype)
-
-
-def adam_update(p, g, m, v, lr_t, *, beta1, beta2, eps, interpret=False):
-    """One-pass Adam: returns (p', m', v').  ``lr_t`` is the
-    bias-corrected scalar step size; p/m/v buffers are aliased in-place."""
-    shape, dtype = p.shape, p.dtype
-    n = p.size
-    d = 128
-    r = n // d
-    br = min(BLOCK_R * 8, r)          # elementwise: big blocks amortise
-    p2, g2 = p.reshape(r, d), g.astype(jnp.float32).reshape(r, d)
-    m2, v2 = m.reshape(r, d), v.reshape(r, d)
-    lr2 = jnp.asarray(lr_t, jnp.float32).reshape(1, 1)
-    po, mo, vo = pl.pallas_call(
-        functools.partial(_adam_kernel, beta1=beta1, beta2=beta2, eps=eps),
-        grid=(pl.cdiv(r, br),),
-        in_specs=[pl.BlockSpec((1, 1), lambda i: (0, 0)),
-                  pl.BlockSpec((br, d), lambda i: (i, 0)),
-                  pl.BlockSpec((br, d), lambda i: (i, 0)),
-                  pl.BlockSpec((br, d), lambda i: (i, 0)),
-                  pl.BlockSpec((br, d), lambda i: (i, 0))],
-        out_specs=[pl.BlockSpec((br, d), lambda i: (i, 0)),
-                   pl.BlockSpec((br, d), lambda i: (i, 0)),
-                   pl.BlockSpec((br, d), lambda i: (i, 0))],
-        out_shape=[jax.ShapeDtypeStruct((r, d), dtype),
-                   jax.ShapeDtypeStruct((r, d), m.dtype),
-                   jax.ShapeDtypeStruct((r, d), v.dtype)],
-        input_output_aliases={1: 0, 3: 1, 4: 2},
-        interpret=interpret,
-        name="fused_adam",
-    )(lr2, p2, g2, m2, v2)
-    return po.reshape(shape), mo.reshape(shape), vo.reshape(shape)

@@ -94,7 +94,7 @@ class PallasLowering:
     enumerable table instead of ad-hoc ``flag(...)`` call-sites buried in
     op impls.  Fields:
 
-    * ``kernel`` — route name (``"flash_attention"``, ``"fused_adam"``,
+    * ``kernel`` — route name (``"flash_attention"``, ``"fused_layer_norm"``,
       ``"dequant_accumulate"``, ...), the unit the census reports on;
     * ``flag`` — the flags.py gate; ``attr`` optionally names an op attr
       that overrides the flag per-op (``use_flash``);

@@ -923,11 +923,10 @@ class ShardedUpdateOptimizer(Optimizer):
         # quantized grad scatter pads flat payloads so every rank's shard
         # is a whole number of quantization blocks — the param slice must
         # use the same alignment or param/grad shards would cover
-        # different element ranges.  Unquantized shards align to 128 (the
-        # fused flat-shard Adam kernel's lane layout, ops/pallas/fused_ops
-        # adam_update): zero-padding is update-inert (0 grad keeps 0
-        # param/moments) and shard boundaries don't change the math, but
-        # the 1-D state shards become the kernel's ideal shape on TPU.
+        # different element ranges.  Unquantized shards align to 128, a
+        # whole number of lanes: zero-padding is update-inert (0 grad
+        # keeps 0 param/moments) and shard boundaries don't change the
+        # math.
         if self._quant is not None:
             align = self._quant.block_size
         else:

@@ -14,6 +14,51 @@ def _f32(*shape):
     return rng.randn(*shape).astype(np.float32)
 
 
+# the trainers' parameter shapes at CPU size, same divisibility
+# (tests/test_tpu_compile.py compiles the real ones for the v5e), and
+# the op's first case
+ADAM_CASES = [
+    pytest.param((3, 3), "float32", id="3x3"),
+    pytest.param((3, 16, 2304), "float32",
+                 id="stacked-3d-lane-aligned"),     # experts [16,2304,896]
+    pytest.param((42, 384), "float32",
+                 id="2d-lane-aligned"),     # [30522,768]: rows % 8 == 2
+    pytest.param((36, 64), "float32", id="last-dim-64"),    # a router
+    pytest.param((8, 12, 128), "float32", id="stacked-rows-not-8"),
+    pytest.param((128 * 11,), "float32",
+                 id="1d-flat-shard"),               # ZeRO shard, padded
+    pytest.param((4, 16, 256), "bfloat16", id="bf16-gradient"),
+]
+
+
+def _adam_operands(shape, g_dtype, seed=3):
+    import ml_dtypes
+    r = np.random.RandomState(seed)
+    p = r.randn(*shape).astype(np.float32)
+    g = r.randn(*shape).astype(np.float32)
+    if g_dtype == "bfloat16":
+        g = g.astype(ml_dtypes.bfloat16)
+    m1 = r.randn(*shape).astype(np.float32) * 0.1
+    m2 = np.abs(r.randn(*shape)).astype(np.float32) * 0.01
+    return p, g, m1, m2
+
+
+def _adam_case(p, g, m1, m2, steps, lr=0.01, beta1=0.9, beta2=0.999,
+               eps=1e-8):
+    """(the op's inputs at step ``steps``, (ParamOut, Moment1Out,
+    Moment2Out) by the composition in numpy)."""
+    b1p, b2p = beta1 ** steps, beta2 ** steps
+    ins = {"Param": p, "Grad": g, "Moment1": m1, "Moment2": m2,
+           "LearningRate": np.array([lr], np.float32),
+           "Beta1Pow": np.array([b1p], np.float32),
+           "Beta2Pow": np.array([b2p], np.float32)}
+    g = np.asarray(g, np.float32)
+    m1o = beta1 * m1 + (1 - beta1) * g
+    m2o = beta2 * m2 + (1 - beta2) * g * g
+    lr_t = lr * np.sqrt(1 - b2p) / (1 - b1p)
+    return ins, (p - lr_t * m1o / (np.sqrt(m2o) + eps), m1o, m2o)
+
+
 class TestElementwise:
     def test_add(self):
         t = make_op_test("elementwise_add")
@@ -380,19 +425,66 @@ class TestOptimOps:
                        {"ParamOut": p - 0.1 * v_out, "VelocityOut": v_out},
                        atol=1e-6)
 
-    def test_adam(self):
+    @pytest.mark.parametrize("shape,g_dtype", ADAM_CASES)
+    def test_adam(self, shape, g_dtype):
+        """The update through the executor, third step (moments and
+        bias powers not at their start): the composition's to 1e-6, a
+        bf16 gradient widened to the moments' f32."""
+        p, g, m1, m2 = _adam_operands(shape, g_dtype)
+        ins, (p_ref, m1_ref, m2_ref) = _adam_case(p, g, m1, m2, steps=3)
+        make_op_test("adam").check_output(
+            ins, {}, {"ParamOut": p_ref, "Moment1Out": m1_ref,
+                      "Moment2Out": m2_ref}, atol=1e-6, rtol=1e-6)
+
+    @pytest.mark.parametrize("shape,g_dtype", ADAM_CASES)
+    def test_adam_keeps_shape_and_dtypes(self, shape, g_dtype):
+        """Parameter and moments come back in the input's shape and
+        dtype whatever the gradient's, and the bias powers advance."""
+        p, g, m1, m2 = _adam_operands(shape, g_dtype, seed=4)
+        ins, _ = _adam_case(p, g, m1, m2, steps=1)
         t = make_op_test("adam")
-        p, g = _f32(3, 3), _f32(3, 3)
-        m1, m2 = np.zeros((3, 3), np.float32), np.zeros((3, 3), np.float32)
-        lr = np.array([0.01], np.float32)
-        b1p = np.array([0.9], np.float32)
-        b2p = np.array([0.999], np.float32)
-        m1o = 0.1 * g
-        m2o = 0.001 * g * g
-        lr_t = 0.01 * np.sqrt(1 - 0.999) / (1 - 0.9)
-        exp = p - lr_t * m1o / (np.sqrt(m2o) + 1e-8)
-        t.check_output({"Param": p, "Grad": g, "LearningRate": lr,
-                        "Moment1": m1, "Moment2": m2,
-                        "Beta1Pow": b1p, "Beta2Pow": b2p}, {},
-                       {"ParamOut": exp, "Moment1Out": m1o,
-                        "Moment2Out": m2o}, atol=1e-5)
+        got = t.check_output(ins, {}, {
+            "Beta1PowOut": np.array([0.9 * 0.9], np.float32),
+            "Beta2PowOut": np.array([0.999 * 0.999], np.float32)})
+        assert all(r.shape == (1,) for r in got)
+        import jax.numpy as jnp
+        from paddle_tpu.ops.registry import get_op
+        outs = get_op("adam")(
+            None, {k: [jnp.asarray(v)] for k, v in ins.items()}, {})
+        for slot in ("ParamOut", "Moment1Out", "Moment2Out"):
+            assert outs[slot].shape == tuple(shape), slot
+            assert outs[slot].dtype == np.float32, slot
+            assert np.isfinite(np.asarray(outs[slot])).all(), slot
+
+    @pytest.mark.parametrize("shape,g_dtype", ADAM_CASES)
+    def test_adam_in_place_steps(self, shape, g_dtype):
+        """Three steps of one jitted update over DONATED parameter and
+        moments (how a prepared step runs it) equal three steps of the
+        composition on the host."""
+        import jax
+        import jax.numpy as jnp
+        from paddle_tpu.ops.registry import get_op
+        p, g, m1, m2 = _adam_operands(shape, g_dtype, seed=5)
+
+        def step(p, m1, m2, b1p, b2p):
+            out = get_op("adam")(None, {
+                "Param": [p], "Grad": [jnp.asarray(g)], "Moment1": [m1],
+                "Moment2": [m2], "Beta1Pow": [b1p], "Beta2Pow": [b2p],
+                "LearningRate": [jnp.full((1,), 0.01, jnp.float32)]}, {})
+            return tuple(out[k] for k in (
+                "ParamOut", "Moment1Out", "Moment2Out", "Beta1PowOut",
+                "Beta2PowOut"))
+
+        state = tuple(jnp.asarray(t) for t in (
+            p, m1, m2, np.array([0.9], np.float32),
+            np.array([0.999], np.float32)))
+        jitted = jax.jit(step, donate_argnums=(0, 1, 2))
+        want = (p, m1, m2)
+        for k in range(1, 4):
+            state = jitted(*state)
+            _, want = _adam_case(want[0], g, want[1], want[2], steps=k)
+        # three steps of f32 rounding, host against XLA, on values of a
+        # few units: some tens of ulps
+        for got, ref in zip(state, want):
+            np.testing.assert_allclose(np.asarray(got), ref, atol=1e-5,
+                                       rtol=1e-5)

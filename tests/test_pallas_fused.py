@@ -1,7 +1,6 @@
 """Fused Pallas kernel numerics (interpret mode on CPU; chip_smoke.py leg K
 re-validates on hardware).  Reference: the jnp compositions these kernels
-replace (ref CUDA analogs: operators/fused/ fused_elemwise kernels,
-optimizers/adam_op.cu)."""
+replace (ref CUDA analogs: operators/fused/ fused_elemwise kernels)."""
 
 import numpy as np
 import pytest
@@ -73,55 +72,3 @@ def test_bias_gelu_fwd_bwd_match_jnp():
     for a, b_ in zip(gk, gr):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
                                    rtol=2e-4, atol=2e-5)
-
-
-def test_adam_update_matches_composition():
-    rng = np.random.RandomState(3)
-    n = 8 * 1024
-    p = rng.randn(n).astype(np.float32)
-    g = rng.randn(n).astype(np.float32)
-    m = rng.randn(n).astype(np.float32) * 0.1
-    v = np.abs(rng.randn(n)).astype(np.float32) * 0.01
-    beta1, beta2, eps, lr_t = 0.9, 0.999, 1e-8, 0.01
-    po, mo, vo = F.adam_update(jnp.asarray(p), jnp.asarray(g),
-                               jnp.asarray(m), jnp.asarray(v), lr_t,
-                               beta1=beta1, beta2=beta2, eps=eps,
-                               interpret=True)
-    m_ref = beta1 * m + (1 - beta1) * g
-    v_ref = beta2 * v + (1 - beta2) * g * g
-    p_ref = p - lr_t * m_ref / (np.sqrt(v_ref) + eps)
-    np.testing.assert_allclose(np.asarray(mo), m_ref, rtol=1e-4, atol=1e-7)
-    np.testing.assert_allclose(np.asarray(vo), v_ref, rtol=1e-4, atol=1e-7)
-    np.testing.assert_allclose(np.asarray(po), p_ref, rtol=1e-4, atol=1e-6)
-
-
-def test_adam_update_stacked_3d_param():
-    rng = np.random.RandomState(5)
-    shape = (3, 16, 2304)               # stacked experts: [E, rows, cols]
-    p, g = (rng.randn(*shape).astype(np.float32) for _ in range(2))
-    m = rng.randn(*shape).astype(np.float32) * 0.1
-    v = np.abs(rng.randn(*shape)).astype(np.float32) * 0.01
-    po, mo, vo = F.adam_update(*(jnp.asarray(t) for t in (p, g, m, v)),
-                               0.01, beta1=0.9, beta2=0.999, eps=1e-8,
-                               interpret=True)
-    m_ref = 0.9 * m + 0.1 * g
-    v_ref = 0.999 * v + 0.001 * g * g
-    np.testing.assert_allclose(np.asarray(mo), m_ref, rtol=1e-4, atol=1e-7)
-    np.testing.assert_allclose(np.asarray(vo), v_ref, rtol=1e-4, atol=1e-7)
-    np.testing.assert_allclose(
-        np.asarray(po), p - 0.01 * m_ref / (np.sqrt(v_ref) + 1e-8),
-        rtol=1e-4, atol=1e-6)
-
-
-def test_adam_update_2d_param_shape_roundtrip():
-    rng = np.random.RandomState(4)
-    p = rng.randn(16, 128).astype(np.float32)
-    g = rng.randn(16, 128).astype(np.float32)
-    m = np.zeros_like(p)
-    v = np.zeros_like(p)
-    po, mo, vo = F.adam_update(jnp.asarray(p), jnp.asarray(g),
-                               jnp.asarray(m), jnp.asarray(v), 0.1,
-                               beta1=0.9, beta2=0.999, eps=1e-8,
-                               interpret=True)
-    assert po.shape == p.shape and mo.shape == p.shape
-    assert np.isfinite(np.asarray(po)).all()

@@ -1,7 +1,7 @@
 """The per-op Pallas lowering tier (ops/registry.py pallas channel):
 static routing report, hit/fallback metrics counters, interpret-mode
-parity of the grafted kernels (ring-attention-via-flash, flat-shard
-Adam, dequant-accumulate), and the KERNEL_CENSUS_r15.json artifact
+parity of the grafted kernels (ring-attention-via-flash,
+dequant-accumulate), and the KERNEL_CENSUS_r15.json artifact
 contract produced by tools/verify_lowering.py --census."""
 
 import json
@@ -49,10 +49,8 @@ def test_routing_report_flash_hit_at_128_fallback_at_64():
     assert rep["summary"]["attention_tile"]["fallback"] == 0
     assert "flash_attention" not in rep["summary"]
     assert rep["summary"]["fused_layer_norm"]["pallas"] > 0
-    # BERT-tiny's 128-wide square params tile the fused-Adam layout;
-    # the small bias/scale leaves fall back with the size floor named
-    assert rep["summary"]["fused_adam"]["pallas"] > 0
-    assert rep["summary"]["fused_adam"]["fallback"] > 0
+    # the optimizer update has no kernel route: XLA's own fusion
+    assert "fused_adam" not in rep["summary"]
     rep64 = kernel_routing_report(main_p,
                                   feed_shapes=_feed_arrays(cfg, 64),
                                   backend="tpu")
@@ -292,14 +290,15 @@ def test_fallback_warning_names_effective_backend(caplog):
 def test_pallas_table_enumerates_the_tier():
     from paddle_tpu.ops.registry import pallas_table
     table = pallas_table()
-    for op in ("fused_attention", "adam", "adamw", "layer_norm",
+    for op in ("fused_attention", "layer_norm",
                "fused_add_layernorm", "fused_elemwise_activation",
                "multihead_matmul", "c_quant_allreduce_sum",
                "c_fused_quant_allreduce_sum", "quant_reduce_scatter"):
         assert op in table, op
+    assert "adam" not in table and "adamw" not in table
     kernels = {r.kernel for routes in table.values() for r in routes}
     assert {"attention_tile", "flash_attention", "ring_flash_attention",
-            "fused_adam", "dequant_accumulate"} <= kernels
+            "fused_layer_norm", "dequant_accumulate"} <= kernels
 
 
 # ---------------------------------------------------------------------------
@@ -400,36 +399,9 @@ def test_flash_with_lse_grads_include_lse_cotangent():
                                    atol=5e-4, err_msg=f"d{name}")
 
 
-def test_flat_shard_adam_matches_per_leaf_chain():
-    """The fused kernel on a ZeRO-style flat 128-aligned shard vs the
-    per-leaf elementwise chain it replaces."""
-    from paddle_tpu.ops.pallas.fused_ops import adam_update
-
-    rng = np.random.RandomState(3)
-    n = 5 * 1024 + 384            # 128-aligned, not a power of two
-    p = rng.randn(n).astype(np.float32)
-    g = rng.randn(n).astype(np.float32)
-    m = rng.randn(n).astype(np.float32) * 0.1
-    v = np.abs(rng.randn(n)).astype(np.float32) * 0.01
-    beta1, beta2, eps, lr_t = 0.9, 0.999, 1e-8, 0.01
-    po, mo, vo = adam_update(jnp.asarray(p), jnp.asarray(g),
-                             jnp.asarray(m), jnp.asarray(v), lr_t,
-                             beta1=beta1, beta2=beta2, eps=eps,
-                             interpret=True)
-    m_ref = beta1 * m + (1 - beta1) * g
-    v_ref = beta2 * v + (1 - beta2) * g * g
-    p_ref = p - lr_t * m_ref / (np.sqrt(v_ref) + eps)
-    np.testing.assert_allclose(np.asarray(po), p_ref, rtol=1e-4,
-                               atol=1e-6)
-    np.testing.assert_allclose(np.asarray(mo), m_ref, rtol=1e-4,
-                               atol=1e-7)
-    np.testing.assert_allclose(np.asarray(vo), v_ref, rtol=1e-4,
-                               atol=1e-7)
-
-
 def test_sharded_update_pads_flat_shards_to_128():
-    """ZeRO-1 flat shards are 128-aligned (the fused-Adam kernel's lane
-    layout) and the grad scatter carries the matching align attr."""
+    """ZeRO-1 flat shards are 128-aligned (whole lanes) and the grad
+    scatter carries the matching align attr."""
     import paddle_tpu.fluid as fluid
     from paddle_tpu.framework.core import Program, program_guard
     from paddle_tpu.optimizer import ShardedUpdateOptimizer
@@ -537,11 +509,10 @@ def test_kernel_census_artifact_contract():
     for k in ("attn_tile_fwd", "attn_tile_bwd"):
         assert k in secs["single_device_bert_tiny_seq128"]["kernels"]
     assert "flash_fwd" not in secs["single_device_bert_tiny_seq128"]["kernels"]
-    assert "fused_adam" in secs["single_device_bert_tiny_seq128"]["kernels"]
+    assert "fused_adam" not in secs["single_device_bert_tiny_seq128"]["kernels"]
     assert "flash_fwd" in secs["ring_attention_sp4"]["kernels"]
     for k in ("flash_bwd_dq", "flash_bwd_dkv"):
         assert k in secs["ring_attention_sp4_grad"]["kernels"]
-    assert "fused_adam" in secs["zero1_dp8_flat_shard_adam"]["kernels"]
     assert "dequant_accumulate_requant" in secs["quant_int8_dp8"]["kernels"]
     assert "dequant_accumulate" in secs["quant_int4_dp8"]["kernels"]
     for s in secs.values():
@@ -551,7 +522,7 @@ def test_kernel_census_artifact_contract():
     # end-to-end wire-tier contract
     par = art["parity"]
     for key in ("ring_flash_vs_einsum_fwd", "ring_flash_vs_einsum_grad",
-                "flat_shard_adam", "dequant_acc_int8", "dequant_acc_int4"):
+                "dequant_acc_int8", "dequant_acc_int4"):
         assert par[key]["measured"] <= par[key]["bound"], key
     assert par["ring_flash_vs_einsum_fwd"]["bound"] <= 1e-5
     assert secs["quant_int8_dp8"]["wire_tier_parity_bound"] == 5e-2
@@ -559,7 +530,7 @@ def test_kernel_census_artifact_contract():
     # the embedded static routing report agrees with the module census
     rep = secs["single_device_bert_tiny_seq128"]["routing_report"]
     assert rep["summary"]["attention_tile"]["pallas"] > 0
-    assert rep["summary"]["fused_adam"]["pallas"] > 0
+    assert rep["summary"]["fused_layer_norm"]["pallas"] > 0
 
 
 def test_census_selftest_wired_into_preflight():

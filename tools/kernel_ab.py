@@ -1,5 +1,5 @@
 """A/B bench of the Pallas kernel families at bench shapes (VERDICT r3
-next-round #2): flash attention and the fused LN/add-LN/bias-GELU/Adam
+next-round #2): flash attention and the fused LN/add-LN/bias-GELU
 kernels, flag on vs off, same window, same methodology as bench.py
 (device-resident feeds, pipelined dispatch, one final sync).
 
@@ -32,7 +32,7 @@ ARTIFACT = "KERNEL_AB_r14.json"
 CONFIGS = (
     ("baseline (no pallas)", False, False),
     ("+flash_attention", True, False),
-    ("+fused_ln_adam", False, True),
+    ("+fused_ln", False, True),
     ("both (bench default)", True, True),
 )
 
@@ -173,14 +173,13 @@ def selftest():
     ladder = cross_lower_flag_ladder()
     base = set(ladder["baseline (no pallas)"])
     flash = set(ladder["+flash_attention"])
-    fused = set(ladder["+fused_ln_adam"])
+    fused = set(ladder["+fused_ln"])
     both = set(ladder["both (bench default)"])
     ok = ok and not base                     # flags off → NO pallas calls
     # seq 128 is one tile: fused_attention lowers to the one-tile pair
     # (attn_tile_fwd / attn_tile_bwd), not the blockwise flash_* three
     ok = ok and {"attn_tile_fwd", "attn_tile_bwd"} <= flash
-    ok = ok and {"fused_layer_norm_fwd", "fused_layer_norm_bwd",
-                 "fused_adam"} <= fused
+    ok = ok and {"fused_layer_norm_fwd", "fused_layer_norm_bwd"} <= fused
     ok = ok and (flash | fused) <= both
     art["cross_lowered_kernels"] = ladder
     with open(ARTIFACT, "w") as f:

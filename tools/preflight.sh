@@ -30,11 +30,11 @@ echo "   NaN, perfetto timeline merge) =="
 python tools/obs_probe.py --selftest
 
 echo "== preflight: kernel A/B probe (pallas flag ladder: flash attention"
-echo "   + fused LN/Adam, CPU-safe interpret-mode leg, JSON artifact) =="
+echo "   + fused LN, CPU-safe interpret-mode leg, JSON artifact) =="
 python tools/kernel_ab.py --selftest
 
 echo "== preflight: pallas kernel census (TPU cross-lowering: flash attn"
-echo "   incl. ring inner step, flat-shard Adam, dequant-accumulate all"
+echo "   incl. ring inner step, fused LayerNorm, dequant-accumulate all"
 echo "   present as tpu_custom_calls; interpret-mode parity bounds) =="
 python tools/verify_lowering.py --selftest
 

@@ -469,15 +469,15 @@ def selftest(memory=False) -> int:
         return 1
 
     # kernel-routing report (the Pallas tier, statically): the training
-    # program must yield a non-empty report whose fused-Adam summary has
-    # hits (the 128-wide BERT-tiny params tile), every row carries a
-    # route + reason, and the --kernels --json payload embeds it
+    # program must yield a non-empty report whose fused-LayerNorm summary
+    # has hits (BERT-tiny's 128-wide rows), every row carries a route +
+    # reason, and the --kernels --json payload embeds it
     from paddle_tpu.framework.analysis import kernel_routing_report
     krep = kernel_routing_report(main, fetch_names=[total.name])
-    if not krep["rows"] or "fused_adam" not in krep["summary"] or \
-            krep["summary"]["fused_adam"]["pallas"] < 1:
+    if not krep["rows"] or "fused_layer_norm" not in krep["summary"] or \
+            krep["summary"]["fused_layer_norm"]["pallas"] < 1:
         print("proglint selftest: kernel-routing report empty or missing "
-              "fused_adam hits: " + json.dumps(krep["summary"]))
+              "fused_layer_norm hits: " + json.dumps(krep["summary"]))
         return 1
     if any(r["route"] not in ("pallas", "fallback") or not r["reason"]
            for r in krep["rows"]):

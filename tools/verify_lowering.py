@@ -19,11 +19,9 @@ Two modes:
   gate, not a string match):
 
     - single-device BERT-tiny train step at seq 128 → flash attention
-      fwd+bwd, fused LayerNorm fwd+bwd, fused Adam;
+      fwd+bwd, fused LayerNorm fwd+bwd;
     - sp4 ring attention fwd+grad → the blockwise flash kernels inside
       the rotated-KV scan (the einsum inner step replaced);
-    - dp8 BERT-tiny ZeRO-1 sharded update → fused Adam over the flat
-      1/n state shards;
     - dp8 BERT-tiny int8/int4 bucketed quantized grad sync → the fused
       dequant-upcast-accumulate(-requantize) receive stage;
 
@@ -60,7 +58,6 @@ ARTIFACT = "KERNEL_CENSUS_r15.json"
 PARITY_BOUNDS = {
     "ring_flash_vs_einsum_fwd": 1e-5,
     "ring_flash_vs_einsum_grad": 2e-4,
-    "flat_shard_adam": 1e-5,
     "dequant_acc_int8": 1e-5,
     "dequant_acc_int4": 1e-5,
     "dequant_acc_requant_int8": 2e-6,   # vs jnp requantize, dequantized
@@ -139,7 +136,7 @@ def main():
                  f"({'PURE bf16' if set(gemm_pairs) <= {'bf16xbf16'} else 'MIXED — check mxu_matmul routing'})")
     # seq 128 is one tile: the one-tile attention pair, not flash_*
     want = {"attn_tile_fwd", "attn_tile_bwd",
-            "fused_layer_norm_fwd", "fused_layer_norm_bwd", "fused_adam"}
+            "fused_layer_norm_fwd", "fused_layer_norm_bwd"}
     missing = want - set(kernels)
     lines.append(f"required kernel set: "
                  f"{'COMPLETE' if not missing else f'MISSING {missing}'}")
@@ -171,7 +168,7 @@ def _section(name, txt, required):
 def census_single_device():
     """BERT-tiny seq-128 train step, single device: the one-tile
     attention fwd+bwd (seq 128 is one tile; the blockwise flash kernels
-    are the ring legs'), fused LN fwd+bwd and fused Adam all engage."""
+    are the ring legs') and fused LN fwd+bwd engage."""
     import paddle_tpu.fluid as fluid
     from paddle_tpu.framework.core import reset_default_programs
     from paddle_tpu.framework.executor import global_scope
@@ -196,7 +193,7 @@ def census_single_device():
     txt = exported.mlir_module()
     sec = _section("single_device_bert_tiny_seq128", txt,
                    ("attn_tile_fwd", "attn_tile_bwd",
-                    "fused_layer_norm_fwd", "fused_layer_norm_bwd", "fused_adam"))
+                    "fused_layer_norm_fwd", "fused_layer_norm_bwd"))
     # the static report must agree with what the module proves
     from paddle_tpu.framework.analysis import kernel_routing_report
     sec["routing_report"] = kernel_routing_report(
@@ -278,10 +275,9 @@ def census_ring_sp4():
     return sec, gsec, parity
 
 
-def _dp8_step_module(quant_mode=None, sharded_update=False):
-    """Build the dp8 BERT-tiny bucketed train step (optionally ZeRO-1
-    sharded update / int8-int4 wire tier) and cross-lower it for TPU;
-    returns the MLIR text."""
+def _dp8_step_module(quant_mode):
+    """Build the dp8 BERT-tiny bucketed train step on the int8 / int4
+    wire tier and cross-lower it for TPU; returns the MLIR text."""
     import jax
     from jax import export as jexp
 
@@ -298,17 +294,11 @@ def _dp8_step_module(quant_mode=None, sharded_update=False):
     main_p, startup = fluid.Program(), fluid.Program()
     with fluid.program_guard(main_p, startup):
         feeds, total, mlm, nsp = bert.build_pretrain_network(cfg)
-        if sharded_update:
-            from paddle_tpu.optimizer import ShardedUpdateOptimizer
-            ShardedUpdateOptimizer(fluid.optimizer.Adam(1e-4),
-                                   nranks=8).minimize(total)
-        else:
-            fluid.optimizer.Adam(1e-4).minimize(total)
+        fluid.optimizer.Adam(1e-4).minimize(total)
     mesh = make_mesh(8, "dp")
     bs = BuildStrategy()
     bs.fuse_all_reduce_ops = True
-    if quant_mode:
-        bs.allreduce_quant_spec = {"dtype": quant_mode, "block_size": 256}
+    bs.allreduce_quant_spec = {"dtype": quant_mode, "block_size": 256}
     fluid.CompiledProgram(main_p).with_data_parallel(
         loss_name=total.name, mesh=mesh, build_strategy=bs)
     scope = fluid.Scope()
@@ -328,13 +318,6 @@ def _dp8_step_module(quant_mode=None, sharded_update=False):
     return exported.mlir_module()
 
 
-def census_zero1_dp8():
-    """dp8 ZeRO-1 sharded update: the fused Adam kernel engages on the
-    flat 128-aligned 1/n state shards inside shard_map."""
-    txt = _dp8_step_module(sharded_update=True)
-    return _section("zero1_dp8_flat_shard_adam", txt, ("fused_adam",))
-
-
 def census_quant_dp8(mode):
     """dp8 int8/int4 bucketed quantized grad sync: the receive stage is
     the fused dequant-accumulate kernel (int8 round-to-nearest also
@@ -345,34 +328,6 @@ def census_quant_dp8(mode):
     sec = _section(f"quant_{mode}_dp8", txt, required)
     sec["wire_tier_parity_bound"] = WIRE_TIER_BOUNDS[mode]
     return sec
-
-
-def parity_flat_shard_adam():
-    """Interpret-mode fused Adam on a 128-aligned flat shard vs the
-    per-leaf jnp chain."""
-    import jax.numpy as jnp
-
-    from paddle_tpu.ops.pallas.fused_ops import adam_update
-
-    rng = np.random.RandomState(1)
-    n = 9 * 1024 + 128          # flat, 128-aligned, not a power of two
-    p = rng.randn(n).astype(np.float32)
-    g = rng.randn(n).astype(np.float32)
-    m = rng.randn(n).astype(np.float32) * 0.1
-    v = np.abs(rng.randn(n)).astype(np.float32) * 0.01
-    beta1, beta2, eps, lr_t = 0.9, 0.999, 1e-8, 0.01
-    po, mo, vo = adam_update(jnp.asarray(p), jnp.asarray(g),
-                             jnp.asarray(m), jnp.asarray(v), lr_t,
-                             beta1=beta1, beta2=beta2, eps=eps,
-                             interpret=True)
-    m_ref = beta1 * m + (1 - beta1) * g
-    v_ref = beta2 * v + (1 - beta2) * g * g
-    p_ref = p - lr_t * m_ref / (np.sqrt(v_ref) + eps)
-    err = max(float(np.max(np.abs(np.asarray(po) - p_ref))),
-              float(np.max(np.abs(np.asarray(mo) - m_ref))),
-              float(np.max(np.abs(np.asarray(vo) - v_ref))))
-    return {"flat_shard_adam": {"measured": err,
-                                "bound": PARITY_BOUNDS["flat_shard_adam"]}}
 
 
 def parity_dequant_acc():
@@ -424,10 +379,8 @@ def run_census(out_path=ARTIFACT):
     sections = [census_single_device()]
     ring_sec, ring_grad_sec, parity = census_ring_sp4()
     sections += [ring_sec, ring_grad_sec]
-    sections.append(census_zero1_dp8())
     sections.append(census_quant_dp8("int8"))
     sections.append(census_quant_dp8("int4"))
-    parity.update(parity_flat_shard_adam())
     parity.update(parity_dequant_acc())
 
     for name, row in parity.items():
