@@ -12,7 +12,7 @@ checks what comes out by the repo's own means:
   Adam, the int8/int4 dequant-accumulate pair) compiled by Mosaic at the
   shapes the models use and at the bound each routing gate admits, each
   against its jnp reference;
-* **leg A** — the trainer: exactly the program ``bench.py`` builds
+* **leg A** — the trainer: the ``bert_pretrain.s128`` cell's program
   (BERT-base, batch 96, seq 128, 20 masks, pure-bf16 Adam, dropout 0.1)
   through ``fluid.Executor(fluid.TPUPlace(0))``, fed by the
   double-buffered ``DataLoader`` into ``exe.prepare(...).run`` and then
@@ -392,7 +392,7 @@ def leg_kernels(S: Sizes):
 
 
 def _build_pretrain(cfg, seed=0):
-    """The program bench.py builds: BERT pretrain + pure-bf16 Adam."""
+    """The ``s128`` cell's program: BERT pretrain + pure-bf16 Adam."""
     import paddle_tpu.fluid as fluid
     from paddle_tpu.contrib.mixed_precision import decorate
     from paddle_tpu.models import bert

@@ -283,7 +283,7 @@ def apply_overlap_xla_flags(environ=None):
 
 def enable_compile_cache() -> str:
     """Turn on JAX's persistent compilation cache and return its
-    directory.  Call before the first compile (chip_smoke.py, bench.py).
+    directory.  Call before the first compile (chip_smoke.py).
 
     Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX already reads it and
     no path is set here.  Otherwise the cache lives at the fixed

@@ -1542,8 +1542,7 @@ def kernel_routing_report(program: Program, feed_shapes=None,
 
     Returns ``{"backend", "rows": [{op, index, route, kernel, reason,
     kernels}], "summary": {kernel: {"pallas": n, "fallback": n}}}`` —
-    the report tools/proglint.py prints under ``--kernels`` and the
-    kernel census embeds in ``KERNEL_CENSUS_r15.json``."""
+    the report tools/proglint.py prints under ``--kernels``."""
     from ..ops.registry import OP_SPECS, VarSig, pallas_route
     from .memory_analysis import _feed_sigs
 

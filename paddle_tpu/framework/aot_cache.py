@@ -13,9 +13,10 @@ the startup cost, and XLA executables are serializable
 * **key** — a sha256 over the program's CONTENT hash (the versioned
   serialization desc — the per-process ``_uid`` counter is useless
   across restarts) × feed signature × fetch list × donation mode ×
-  trace-time flags × device kind/platform × jax version.  Any of those
-  changing is a different executable; a jax upgrade or a model edit
-  silently misses instead of loading a stale binary;
+  trace-time flags × device kind/platform × jax version × the ids of the
+  devices the executable was built for.  Any of those changing is a
+  different executable; a jax upgrade or a model edit silently misses
+  instead of loading a stale binary;
 * **entry** — one ``<key>.aotx`` file: a pickle of
   ``{format, meta, payload, in_tree, out_tree}`` where ``payload`` is
   the serialized executable and the trees are the pickled arg/result
@@ -81,8 +82,9 @@ def device_identity() -> str:
 
 
 def entry_key(program, feed_signature, fetch_names, donate_state: bool,
-              trace_flags) -> str:
-    """Cache key for one executable (one bucket shape of one program)."""
+              trace_flags, devices) -> str:
+    """Cache key for one executable (one bucket shape of one program),
+    built for ``devices``."""
     blob = json.dumps({
         "program": program_content_hash(program),
         "feed_sig": [list(map(str, item)) for item in feed_signature],
@@ -90,6 +92,7 @@ def entry_key(program, feed_signature, fetch_names, donate_state: bool,
         "donate_state": bool(donate_state),
         "trace_flags": [str(f) for f in trace_flags],
         "device": device_identity(),
+        "device_ids": [d.id for d in devices],
     }, sort_keys=True).encode("utf-8")
     return hashlib.sha256(blob).hexdigest()
 
@@ -98,8 +101,10 @@ def entry_path(cache_dir: str, key: str) -> str:
     return os.path.join(cache_dir, key + _ENTRY_SUFFIX)
 
 
-def load(cache_dir: str, key: str):
-    """Deserialize the cached executable for ``key``, or None.
+def load(cache_dir: str, key: str, devices):
+    """Deserialize the cached executable for ``key`` onto ``devices``
+    (the ones it was built for — ``deserialize_and_load`` would
+    otherwise bind it to every device of the backend), or None.
 
     Counts ``aot_cache_hit``/``aot_cache_miss``; any failure mode
     (corrupt pickle, format drift, PJRT rejection) counts
@@ -123,7 +128,8 @@ def load(cache_dir: str, key: str):
                     f" != {ENTRY_FORMAT}")
             from jax.experimental import serialize_executable as _se
             compiled = _se.deserialize_and_load(
-                entry["payload"], entry["in_tree"], entry["out_tree"])
+                entry["payload"], entry["in_tree"], entry["out_tree"],
+                execution_devices=list(devices))
     except (KeyboardInterrupt, SystemExit):
         raise
     except BaseException:

@@ -623,8 +623,8 @@ def _jax_device_for(place: Place):
     """``TPUPlace(i)`` is device ``i`` of JAX's DEFAULT backend — the
     chip on a TPU host, a host device under ``JAX_PLATFORMS=cpu`` (the
     whole CPU test suite builds ``TPUPlace(0)``).  The place does not
-    check the platform; the chip entry points (chip_smoke.py, bench.py)
-    do, and fail without a TPU.  An index past the last device is an
+    check the platform; the chip entry points (chip_smoke.py,
+    benchmark/run.py) do, and fail without a TPU.  An index past the last device is an
     error, never an alias of another chip."""
     import jax
     if isinstance(place, CPUPlace):
@@ -645,10 +645,10 @@ def is_compiled_with_tpu() -> bool:
 
 def require_tpu() -> dict:
     """The device check of the chip entry points (chip_smoke.py,
-    bench.py): returns ``{"platform", "kind", "count"}`` as JAX reports
-    the default backend, and exits non-zero before any model is built
-    when that backend is not the TPU — a device number is never taken
-    from a CPU."""
+    benchmark/run.py): returns ``{"platform", "kind", "count"}`` as JAX
+    reports the default backend, and exits non-zero before any model is
+    built when that backend is not the TPU — a device number is never
+    taken from a CPU."""
     import jax
     devs = jax.devices()
     if devs[0].platform != "tpu":

@@ -496,7 +496,7 @@ _ZERO_FLOP_PRIMS = frozenset({
 
 # census of the most recent scheduled pipeline lowering (family, tick
 # tables, idle accounting, weight-sharding summary) — read by
-# tools/pipe_probe.py and the telemetry recorder
+# tests/test_pipeline.py and the telemetry recorder
 _LAST_PIPE_REPORT: Dict[str, Any] = {}
 
 
@@ -860,8 +860,8 @@ def _lower_pipelined_schedule(ops, env, ctx, bw_idx, fetch_names,
 
     # the lowering census: tick tables the scan ACTUALLY consumed, the
     # no-op branch's primitive inventory (must be pure data movement),
-    # and the weight-sharding summary — pipe_probe asserts census idle
-    # ticks == simulator bubble ticks and idle compute == 0
+    # and the weight-sharding summary — tests/test_pipeline.py asserts
+    # census idle ticks == simulator bubble ticks and idle compute == 0
     census_idle = int(sum(1 for t in range(T) for r in range(S)
                           if code_rows[t][r] == 0))
     noop = make_noop()
@@ -2580,17 +2580,21 @@ class Executor:
                        flag("overlap_lowering"),
                        flag("guard_nonfinite"), flag("guard_loss_scale"),
                        _faultline.epoch())
+        devices = (self._device,)
         key = aot_cache.entry_key(program, feed_sig, fetch_names,
-                                  donate_state, trace_flags)
-        cached = aot_cache.load(cache_dir, key)
+                                  donate_state, trace_flags, devices)
+        cached = aot_cache.load(cache_dir, key, devices)
         if cached is not None:
             return cached, False
+
+        on_device = jax.sharding.SingleDeviceSharding(self._device)
 
         def _struct(v):
             if not hasattr(v, "shape") or not hasattr(v, "dtype"):
                 v = np.asarray(v)
             return jax.ShapeDtypeStruct(
-                tuple(v.shape), jax.dtypes.canonicalize_dtype(v.dtype))
+                tuple(v.shape), jax.dtypes.canonicalize_dtype(v.dtype),
+                sharding=on_device)
 
         state_structs = {}
         for n in state_in_names:

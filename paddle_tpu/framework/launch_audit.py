@@ -43,9 +43,8 @@ built from artifacts the static layer already has:
 
 Everything here is trace-free: 0 compiles, 0 live device collectives.
 Wired into ``verify_program`` (pipelined/multi-rank profiles),
-``tools/proglint.py --launch``, and the ``tools/launch_probe.py`` census
-(``LAUNCH_AUDIT_r24.json``), which seeds every class above and proves it
-caught.
+``tools/proglint.py --launch``, and the ``tools/launch_probe.py`` census,
+which seeds every class above and proves it caught.
 """
 
 from __future__ import annotations

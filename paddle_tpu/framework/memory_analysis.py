@@ -29,12 +29,12 @@ statically (no trace, no device):
 The transient (XLA "temp") model is deliberately simple and validated
 against ground truth rather than derived from a scheduler simulation
 (``tools/mem_probe.py`` compares it to
-``jit(...).lower().compile().memory_analysis()`` per leg, artifact
-``MEM_ESTIMATE_r09.json`` asserted within ±15 % in tier-1):
+``jit(...).lower().compile().memory_analysis()`` per leg; tier-1 holds
+the smallest rung and the two mesh legs within ±15 %):
 
     transient = RESIDUAL_FACTOR × Σ residual classes
               + Σ op-internal backward extras      (op_spec mem channel)
-              + grads                              (collective programs)
+              + grads                   (collectives' and updates' operands)
 
 where a *residual class* is an alias set of forward intermediates
 collapsed across fusible ops (views, elementwise chains, activations —
@@ -43,9 +43,10 @@ forward value plus the ~half of its cotangents in flight during the
 reverse sweep, op-internal extras come from the op_spec byte-accounting
 channel (attention probability matrices, softmax-CE logit copies — the
 values an op impl materialises that never appear as named Program
-vars), and the grad term is included only when grad-sync collectives
-force the gradient set to materialise (single-program fused updates
-reuse donated state buffers instead — measured, not assumed).
+vars), and the grad term counts each gradient buffer a grad-sync
+collective or an optimizer update reads (XLA finishes the backward sweep
+before the updates: the temp bytes of one program are the same under
+sgd, momentum and adam).
 
 Wired three ways: ``tools/proglint.py --memory`` prints the report;
 ``flag("hbm_budget_gb")`` makes ``Executor.prepare`` /
@@ -250,7 +251,7 @@ class MemoryEstimate:
         self.rng_bytes = 8
         self.residual_bytes = 0        # Σ residual classes (pre-factor)
         self.internal_bytes = 0        # op_spec backward extras
-        self.grad_bytes = 0            # counted when collectives force it
+        self.grad_bytes = 0            # collectives' and updates' operands
         self.output_bytes = 0          # non-aliased outputs (fetches, and
         self.transient_bytes = 0       # written state when not donated)
         # grad-sync collective wire accounting (the op_spec ``wire``
@@ -677,10 +678,7 @@ def analyze_memory(program: Program, feed_shapes=None,
         # grad-sync collectives after the backward op keep BOTH their
         # source and result buffers live (a psum cannot update in place;
         # a reduce_scatter's full-grad input coexists with its 1/n
-        # shard).  The fused single-program update instead streams each
-        # grad straight into the donated state buffers — measured
-        # against XLA buffer assignment, not assumed — so without a
-        # grad-sync zone the gradient set contributes no extra term.
+        # shard).
         scatter_ops = {"zero_reduce_scatter", "quant_reduce_scatter",
                        "c_reducescatter", "reduce_scatter"}
         # each gradient buffer counts at most once as a collective
@@ -732,6 +730,22 @@ def analyze_memory(program: Program, feed_shapes=None,
                 logical, wire = wb
                 est.wire_logical_bytes += logical
                 est.wire_bytes += wire
+        # an optimizer update (Param, Grad -> ParamOut) reads its gradient
+        # as a whole buffer: XLA finishes the backward sweep before the
+        # updates, so the gradient set is live beside the residuals
+        # whatever the optimizer (sgd, momentum and adam show the same
+        # temp bytes).  Gradients a collective above already counted are
+        # the same buffers.
+        for op in ops[bw_idx + 1:]:
+            if "Grad" not in op.inputs or "ParamOut" not in op.outputs:
+                continue
+            for n in op.inputs["Grad"]:
+                if n in seen_in or n in seen_out:
+                    continue
+                seen_in.add(n)
+                v = block._find_var_recursive(n)
+                if v is None or not v.persistable:
+                    est.grad_bytes += var_bytes(n)
         est.transient_bytes = int(RESIDUAL_FACTOR * est.residual_bytes
                                   + est.internal_bytes + est.grad_bytes
                                   + pipe_inflight)

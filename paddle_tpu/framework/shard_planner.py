@@ -29,7 +29,7 @@ the historical min-wire ranking is the overlap-off special case).
 Ties break toward fewer total wire bytes, then more data parallelism
 (fewer collectives on the critical path), then less fsdp, then less
 tp.  The full ranking is emitted as an auditable plan report
-(``PLAN_SEARCH_*.json`` — tools/plan_probe.py).
+(``Plan.as_dict()`` / ``auto_shard_configs["report_path"]``).
 
 Wired through ``DistributedStrategy.auto_shard = True``
 (distributed/fleet.py); usable standalone::

@@ -170,7 +170,7 @@ def dump(reason: str, exc: Optional[BaseException] = None,
 
 def validate_bundle(path: str) -> Dict[str, Any]:
     """Schema-check one bundle file; raises ValueError on violations and
-    returns the parsed bundle otherwise (obs_probe's crash-leg check)."""
+    returns the parsed bundle otherwise."""
     with open(path) as f:
         bundle = json.load(f)
     if bundle.get("schema") != SCHEMA:

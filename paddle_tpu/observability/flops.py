@@ -1,9 +1,8 @@
 """Static per-step FLOPs (the MFU numerator) and device peak FLOPs (the
 denominator).
 
-``tools/flops_audit.py`` validated the bench's hand-derived analytic
-FLOPs against XLA's cost analysis once, offline.  The telemetry recorder
-needs the same number *per program, statically, without a trace*: the
+The telemetry recorder needs the benchmark's analytic FLOP count
+(``benchmark/flops.py``) *per program, statically, without a trace*: the
 op-spec metadata channel (ops/registry.py ``op_spec(..., flops=...)``)
 prices each GEMM-class op from its inferred input signatures —
 ``flops(ins, outs, attrs) -> float`` counting 2 FLOPs per MAC — and
@@ -11,8 +10,8 @@ prices each GEMM-class op from its inferred input signatures —
 propagation the memory analyzer uses.  Backward GEMMs cost 2× forward
 (dX and dW), so a program containing the ``backward`` meta-op prices at
 3× its forward GEMM count — exactly the analytic model
-``bench.bert_flops_per_step`` uses, which FLOPS_AUDIT_r05 pinned at
-1.018× of XLA's own count for BERT-base.
+``benchmark.flops.bert_flops_per_step`` uses
+(tests/test_tpu_lowering.py brackets it with XLA's own count).
 
 Peak FLOPs come from a device-kind table (bf16 dense peak per chip;
 TPU generations the framework targets) with a CPU fallback, overridable
@@ -77,7 +76,7 @@ def device_info(device=None) -> Dict[str, Any]:
 #: NOT GEMM MACs — priced by the spec channel so the differential spec
 #: auditor (framework/spec_audit.py) can reconcile the program total
 #: against XLA cost_analysis, but EXCLUDED from the MFU numerator:
-#: the MFU convention (bench.bert_flops_per_step, FLOPS_AUDIT_r05)
+#: the MFU convention (benchmark.flops.bert_flops_per_step)
 #: counts GEMMs only, and the telemetry band tests pin that ratio.
 NON_GEMM_FLOPS_OPS = frozenset({
     "softmax", "log_softmax", "softmax_with_cross_entropy",

@@ -25,7 +25,7 @@ A non-finite loss triggers the crash flight recorder
 and the diagnostic bundle cross-reference the same ``step_id``.
 
 Schema is versioned (``SCHEMA``); :func:`validate_jsonl` is the
-contract checker tools/obs_probe.py and tier-1 assert.
+contract checker tier-1 asserts.
 """
 
 from __future__ import annotations
@@ -398,7 +398,7 @@ class _StepTimer:
 def validate_jsonl(path: str) -> Dict[str, Any]:
     """Schema-check one telemetry stream; raises ValueError on the first
     violation and returns aggregate facts otherwise (the contract
-    tools/obs_probe.py and tier-1 assert)."""
+    tier-1 asserts)."""
     with open(path) as f:
         lines = [json.loads(l) for l in f if l.strip()]
     if not lines:

@@ -252,14 +252,14 @@ def _expert_exchange(arr, axis, n, direction):
     return arr.transpose(1, 0, 2, 3).reshape(e // n, n * b, m)
 
 
-def _quant_exchange_impl(arr, axis, n, direction, spec_key, use_kernel):
+def _quant_exchange_impl(arr, axis, n, direction, spec_key):
     """Blockwise-quantized expert exchange (EQuARX applied to a2a): each
     per-destination slice is padded to whole quantization blocks,
     quantized (payload + f32 scales), both ride ONE all_to_all each, and
-    the receive side dequantizes via the PR 11 dequant-accumulate route
-    (n=1 degenerates to a fused dequant pass)."""
-    from .quantize_wire import CompressionSpec, quantize_blockwise
-    from .collective_ops import _recv_accumulate
+    the receive side dequantizes (one contribution a slice, nothing to
+    accumulate: the dequant-accumulate kernel takes two peers or more)."""
+    from .quantize_wire import (CompressionSpec, dequantize_blockwise,
+                                quantize_blockwise)
     spec = CompressionSpec(dtype=spec_key[0], block_size=spec_key[1])
     orig = arr.dtype
     if direction == "combine":
@@ -286,8 +286,8 @@ def _quant_exchange_impl(arr, axis, n, direction, spec_key, use_kernel):
                         concat_axis=0)
     sx = lax.all_to_all(s.reshape(n, k), axis, split_axis=0,
                         concat_axis=0)
-    full = _recv_accumulate(qx, sx, spec, 1, n * k, use_kernel)
-    full = full.reshape(n, k * bs)
+    full = dequantize_blockwise(qx.reshape(n * k, -1), sx.reshape(-1),
+                                spec).reshape(n, k * bs)
     if pad:
         full = full[:, :slice_numel]
     recv = full.reshape(recv_shape)
@@ -299,27 +299,23 @@ def _quant_exchange_impl(arr, axis, n, direction, spec_key, use_kernel):
     return out.astype(orig)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4, 5))
-def _quant_expert_exchange(arr, axis, n, direction, spec_key, use_kernel):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4))
+def _quant_expert_exchange(arr, axis, n, direction, spec_key):
     """custom_vjp wrapper: the exchange is a cross-device permutation, so
     its VJP is the opposite-direction exchange of the cotangent — also
     quantized, which is what makes the BACKWARD a2a ride the wire tier
     too.  Rounding is deterministic here (no stochastic-rounding key
     threading through custom_vjp); spec_key = (dtype, block_size)."""
-    return _quant_exchange_impl(arr, axis, n, direction, spec_key,
-                                use_kernel)
+    return _quant_exchange_impl(arr, axis, n, direction, spec_key)
 
 
-def _quant_exchange_fwd(arr, axis, n, direction, spec_key, use_kernel):
-    return _quant_expert_exchange(arr, axis, n, direction, spec_key,
-                                  use_kernel), None
+def _quant_exchange_fwd(arr, axis, n, direction, spec_key):
+    return _quant_expert_exchange(arr, axis, n, direction, spec_key), None
 
 
-def _quant_exchange_bwd(axis, n, direction, spec_key, use_kernel, _res,
-                        ct):
+def _quant_exchange_bwd(axis, n, direction, spec_key, _res, ct):
     back = "combine" if direction == "dispatch" else "dispatch"
-    return (_quant_expert_exchange(ct, axis, n, back, spec_key,
-                                   use_kernel),)
+    return (_quant_expert_exchange(ct, axis, n, back, spec_key),)
 
 
 _quant_expert_exchange.defvjp(_quant_exchange_fwd, _quant_exchange_bwd)
@@ -347,12 +343,8 @@ def _c_expert_alltoall(ctx, ins, attrs):
             out = _expert_exchange(a.astype(jnp.bfloat16), ep_axis, n,
                                    direction)
             return {"Out": out.astype(a.dtype)}
-        from .collective_ops import _quant_route
-        use_kernel = _quant_route("c_expert_alltoall", ins, attrs,
-                                  ep_axis)
         out = _quant_expert_exchange(a, ep_axis, n, direction,
-                                     (spec.dtype, spec.block_size),
-                                     use_kernel)
+                                     (spec.dtype, spec.block_size))
         return {"Out": out}
     return {"Out": _expert_exchange(a, ep_axis, n, direction)}
 

@@ -1734,8 +1734,7 @@ def register_default_specs():
     # tier is sound blockwise); dispatch/ffn/combine are local compute
     # with the capacity-factor flops the planner prices
     op_spec("c_expert_alltoall", infer=_infer_collective_same,
-            collective=True, wire=_WIRE_SPECS["c_expert_alltoall"],
-            pallas=(_PL_DEQUANT_ACC,))
+            collective=True, wire=_WIRE_SPECS["c_expert_alltoall"])
     op_spec("moe_dispatch", infer=_infer_moe_dispatch,
             flops=_flops_moe_dispatch)
     op_spec("moe_expert_ffn", infer=_infer_moe_expert_ffn,

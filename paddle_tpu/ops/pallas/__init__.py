@@ -4,7 +4,7 @@ CUDA kernels (operators/fused/, operators/math/bert_encoder_functor.cu).
 The kernel gates (flash_attention.supported, fused_ops ln/bg/adam gates)
 normally consult ``jax.default_backend()``; when CROSS-LOWERING a step for
 TPU on a CPU host (jax.export ``platforms=("tpu",)`` — the chip-free
-lowering census, tools/verify_lowering.py), wrap the trace in
+lowering census, tests/test_pallas_tier.py), wrap the trace in
 ``lowering_target("tpu")`` so the gates see the *lowering* platform rather
 than the runtime backend."""
 

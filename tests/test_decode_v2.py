@@ -89,6 +89,7 @@ def test_chained_decode_parity_and_sync_accounting():
     # the old engine paid one host sync per decoded token; chained
     # decode pays one per chain (+ prefill fetches)
     assert stats["host_syncs"] < stats["chain_tokens"]
+    assert stats["chains_run"] <= stats["decode_steps"] / 4
     assert stats["decode_steps"] == \
         sum(k * v for k, v in stats["chain_hist"].items())
 
@@ -214,6 +215,7 @@ def test_prefix_partial_block_trailing_tokens_never_shared():
     assert s0["prefix_indexed_blocks"] == 1      # block 1 stays partial
     assert s1["prefix_hits"] - s0["prefix_hits"] == 1
     assert s1["prefill_tokens"] - s0["prefill_tokens"] == 2
+    assert s1["prefix_bytes_saved"] > s0["prefix_bytes_saved"]
     assert s1["cache_blocks_used"] == 0
 
 

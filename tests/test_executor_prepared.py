@@ -3,11 +3,9 @@ N-step bit-exactness vs Executor.run (plain, py_reader-fed, and
 CompiledProgram dp8 paths incl. the ZeRO-1 sharded_update), FetchHandle
 laziness (no device sync until first read), in-flight window
 backpressure, scope staleness guards (checkpoint + Executor.run
-interleaving), pass-variant LRU promotion, and the HOST_OVERHEAD
-artifact contract."""
+interleaving), pass-variant LRU promotion, and the sync bound on the
+transformer bench."""
 
-import json
-import os
 
 import numpy as np
 import pytest
@@ -18,7 +16,6 @@ import paddle_tpu.fluid as fluid
 import paddle_tpu.framework.executor as executor_mod
 from paddle_tpu.framework.core import Program, program_guard
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 STEPS = 4
 
 
@@ -509,31 +506,6 @@ def test_profiler_step_breakdown():
 # ---------------------------------------------------------------------------
 # HOST_OVERHEAD artifact + sync bound on the CPU transformer bench
 # ---------------------------------------------------------------------------
-
-
-def test_host_overhead_artifact_contract():
-    """The committed artifact parses, documents a ≥3× host-overhead
-    reduction (the acceptance bound), and its donation census is
-    consistent with the multichip census artifact's donation ratio."""
-    path = os.path.join(REPO, "HOST_OVERHEAD_r07.json")
-    with open(path) as fh:
-        art = json.load(fh)
-    assert art["metric"] == "executor_host_overhead_per_step"
-    assert art["steps"] > 0
-    assert art["run_host_us_per_step"] > 0
-    assert art["prepared_host_us_per_step"] > 0
-    assert art["speedup"] >= 3.0, art
-    assert 0 < art["donated_args"] <= art["total_args"]
-    assert art["blocking_syncs"] <= art["steps"]
-    assert art["max_inflight_observed"] <= art["inflight_window"]
-    census_path = os.path.join(REPO, "MULTICHIP_CENSUS_r07.json")
-    with open(census_path) as fh:
-        census = json.load(fh)
-    donated, total = census["arg_donation"]
-    assert donated > 0 and donated <= total
-    # both paths donate the state majority: same order of magnitude ratio
-    assert art["donated_args"] / art["total_args"] > 0.5
-    assert donated / total > 0.5
 
 
 def test_prepared_sync_bound_on_transformer_bench():

@@ -9,10 +9,9 @@ Structural contracts (program-level op census) ride along: buckets
 respect the size cap, the sharded program carries reduce_scatter/
 all_gather and NO full-gradient all-reduce, and quantized programs carry
 NO full-precision grad collective (asserted both at program level and on
-the lowered dp8 module census / the MULTICHIP_CENSUS_r10 artifact)."""
+the lowered dp8 module census)."""
 
-import json
-import os
+import functools
 
 import numpy as np
 import pytest
@@ -188,11 +187,9 @@ def test_bf16_compress_composes_with_per_leaf():
 # c_fused_quant_allreduce_sum / quant_reduce_scatter)
 # ---------------------------------------------------------------------------
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 #: dtype-tier parity bounds (loss-trajectory rtol vs fp32 dp8 baseline
-#: over 4 Adam steps) — the same numbers the census artifact records as
-#: ``parity_bounds`` so byte claims travel with their accuracy contract
+#: over 4 Adam steps)
 INT8_RTOL = 5e-2
 INT4_RTOL = 2.5e-1
 
@@ -355,30 +352,22 @@ def test_quant_census_zero_full_precision_collectives():
         assert row["compression_ratio"] >= 3.5, (kind, row)
 
 
-def test_census_artifact_r10_contract():
-    """The committed MULTICHIP_CENSUS_r10.json records the measured
-    wire-byte ratios (int8 ≥3.5× vs fp32, ≥1.9× vs bf16) together with
-    the parity bounds this file asserts, and its rows stay readable by
-    r06/r07-era consumers (count/bytes present; compression_ratio
-    defaults to 1.0 when absent)."""
-    path = os.path.join(REPO, "MULTICHIP_CENSUS_r10.json")
-    with open(path) as fh:
-        art = json.load(fh)
-    quant = art["quant_dp8"]
-    r = quant["ratios"]
-    assert r["int8_vs_fp32"] >= 3.5, r
-    assert r["int8_vs_bf16"] >= 1.9, r
-    assert r["int4_vs_fp32"] >= r["int8_vs_fp32"], r
-    assert quant["parity_bounds"]["int8"] == INT8_RTOL
-    assert quant["parity_bounds"]["int4"] == INT4_RTOL
-    # fp32 rows: wire compression is a no-op (ratio 1.0) and the legacy
-    # fields keep their r06/r07 meaning
-    for kind, row in art["census"].items():
-        assert row["count"] > 0 and "bytes" in row
-        assert row.get("compression_ratio", 1.0) >= 1.0
-    fp32 = quant["modes"]["fp32"]["census"]
-    for row in fp32.values():
-        assert row.get("compression_ratio", 1.0) == 1.0, fp32
+@functools.lru_cache(maxsize=None)
+def _dp8_wire_bytes(tier):
+    from tools.verify_multichip_lowering import lower_dp8_bert_census
+    return sum(r["wire_bytes"]
+               for r in lower_dp8_bert_census(tier).values())
+
+
+@pytest.mark.parametrize("tier,against,floor", [
+    ("bf16", "fp32", 1.7), ("int8", "fp32", 3.5), ("int8", "bf16", 1.9),
+    ("int4", "int8", 1.0)])
+def test_dp8_wire_tier_moves_fewer_bytes(tier, against, floor):
+    """Ring-model wire bytes of the dp8 BERT bucketed grad sync in the
+    TPU-lowered module, tier against tier."""
+    if len(jax.devices()) < 8:
+        pytest.skip("needs the 8-device virtual mesh conftest")
+    assert _dp8_wire_bytes(against) / _dp8_wire_bytes(tier) >= floor
 
 
 # ---------------------------------------------------------------------------

@@ -2,11 +2,9 @@
 seeded program (or timeline pair) per deadlock/divergence class with an
 anchored ``launch-*`` diagnostic, every static proof run with
 ``Executor._compile`` monkeypatched to raise (0 compiles, 0 live
-collectives), the committed ``LAUNCH_AUDIT_r24.json`` artifact
-contract, and the two-process rendezvous drill (abort with exit 43
+collectives), and the two-process rendezvous drill (abort with exit 43
 instead of hanging)."""
 
-import json
 import os
 import sys
 import threading
@@ -34,7 +32,7 @@ sys.path.insert(0, REPO)
 def _no_compiles(monkeypatch):
     """Every static launch proof in this module must run without ONE
     compile — the auditor's whole claim is pre-compile, pre-collective.
-    (The subprocess drill and artifact tests don't compile either.)"""
+    (The subprocess drill does not compile either.)"""
 
     def boom(*a, **k):
         raise AssertionError("launch audit attempted a compile")
@@ -81,10 +79,8 @@ def _pipelined(schedule="1f1b", microbatches=4):
 # ---------------------------------------------------------------------------
 
 
-def test_collective_under_divergent_control_flow_deadlocks():
-    """A collective inside a data-dependent branch: the rank taking the
-    other arm never issues it — verify_program proves the deadlock
-    statically alongside the existing CF-divergence diagnostic."""
+def _branch_wrapped_collective():
+    """A collective inside a data-dependent branch."""
     p = Program()
     b = p.global_block()
     b.create_var(name="x", shape=(8,), is_data=True)
@@ -100,11 +96,36 @@ def test_collective_under_divergent_control_flow_deadlocks():
                 attrs={"true_block": sub, "false_block": sub,
                        "closure_names": ["x"], "true_out_names": ["x"],
                        "false_out_names": ["x"]})
-    result = verify_program(p)
+    return p
+
+
+def test_collective_under_divergent_control_flow_deadlocks():
+    """A collective inside a data-dependent branch: the rank taking the
+    other arm never issues it — verify_program proves the deadlock
+    statically alongside the existing CF-divergence diagnostic."""
+    result = verify_program(_branch_wrapped_collective())
     d = _one(result, LAUNCH_DEADLOCK_CYCLE)
     assert "c_allreduce_sum" in d.message
     # rides with (does not replace) the existing control-flow diagnostic
     assert result.by_code(COLLECTIVE_DIVERGENT_CF)
+
+
+def test_proglint_launch_flag_gates_and_embeds_the_audit():
+    """``proglint --launch --json``: a clean pipelined program passes with
+    its audit embedded; the branch-wrapped collective exits 1
+    naming ``launch-deadlock-cycle``."""
+    import io
+    import json
+    from tools.proglint import lint
+    sink = io.StringIO()
+    assert lint(_pipelined(), launch=True, as_json=True, out=sink) == 0
+    assert json.loads(sink.getvalue())["launch_audit"]["ok"]
+    sink = io.StringIO()
+    assert lint(_branch_wrapped_collective(), launch=True, as_json=True,
+                out=sink) == 1
+    codes = {d["code"] for d in json.loads(sink.getvalue())
+             ["launch_audit"]["diagnostics"]}
+    assert LAUNCH_DEADLOCK_CYCLE in codes
 
 
 def test_cross_stage_collective_span_deadlocks():
@@ -323,18 +344,3 @@ def test_two_process_rendezvous_drill_aborts_not_hangs():
     assert res["aborted_not_hung"], res
     assert res["exit_codes"] == [43, 43], res
     assert res["named_op"] and res["named_rank"], res
-
-
-# ---------------------------------------------------------------------------
-# committed artifact contract
-# ---------------------------------------------------------------------------
-
-
-def test_launch_audit_artifact_contract():
-    """The committed LAUNCH_AUDIT_r24.json passes the probe's own
-    check(): all six static classes caught with 0 compiles and 0 live
-    collectives, clean pipelined audit, drill aborted [43, 43]."""
-    from tools.launch_probe import ARTIFACT, check
-    with open(os.path.join(REPO, ARTIFACT)) as f:
-        art = json.load(f)
-    check(art)

@@ -2,9 +2,8 @@
 (framework/memory_analysis.py): liveness intervals across while/cond
 sub-blocks, sharding- and donation-aware per-device byte accounting,
 seeded defects for the three memory lint classes with callstack-anchored
-diagnostics, the ``hbm_budget_gb`` pre-compile gate, the
-estimator-vs-XLA tolerance leg on CPU, and the ``MEM_ESTIMATE_r09.json``
-artifact contract."""
+diagnostics, the ``hbm_budget_gb`` pre-compile gate, and the
+estimator-vs-XLA tolerance legs on CPU."""
 
 import json
 import os
@@ -402,7 +401,7 @@ def test_check_hbm_budget_api_direct():
 
 
 # ---------------------------------------------------------------------------
-# estimator vs XLA ground truth (live CPU leg + artifact contract)
+# estimator vs XLA ground truth (live CPU legs)
 # ---------------------------------------------------------------------------
 
 
@@ -427,47 +426,23 @@ def test_estimator_within_tolerance_live_cpu_leg():
 @pytest.mark.skipif(
     __import__("jax").device_count() < 8,
     reason="needs the 8-device virtual CPU mesh")
-def test_estimator_within_tolerance_dp8_leg_live():
+@pytest.mark.parametrize("sharded", [False, True], ids=["dp8", "zero1"])
+def test_estimator_within_tolerance_dp8_leg_live(sharded):
     import sys
     sys.path.insert(0, REPO)
     try:
         from tools.mem_probe import multichip_leg
     finally:
         sys.path.pop(0)
-    leg = multichip_leg(sharded=False)
+    leg = multichip_leg(sharded=sharded)
     assert leg["within_tolerance"], leg
     assert leg["estimate"]["args_bytes"] == leg["xla"]["argument_bytes"]
-
-
-def test_mem_estimate_artifact_contract():
-    """The committed MEM_ESTIMATE_r09.json documents every transformer-
-    bench ladder rung plus the dp8 and ZeRO-1 multichip legs inside the
-    ±15% tolerance band (acceptance criterion)."""
-    path = os.path.join(REPO, "MEM_ESTIMATE_r09.json")
-    with open(path) as fh:
-        art = json.load(fh)
-    assert art["metric"] == "static_peak_hbm_estimate_vs_xla"
-    assert art["tolerance"] == 0.15
-    legs = {l["leg"]: l for l in art["legs"]}
-    # every ladder rung + both multichip legs are present
-    ladder = [k for k in legs if k.startswith("transformer_ladder_")]
-    assert len(ladder) >= 3
-    assert "dp8" in legs and "dp8_zero1" in legs
-    for name, leg in legs.items():
-        assert abs(leg["rel_err"]) <= art["tolerance"], (name, leg)
-        assert leg["within_tolerance"], name
-        assert leg["estimate_bytes"] > 0
-        assert leg["xla"]["argument_bytes"] > 0
-        assert leg["xla"]["temp_bytes"] > 0
-        # args accounting is exact on every leg
-        assert leg["estimate"]["args_bytes"] == \
-            leg["xla"]["argument_bytes"], name
-    assert art["all_within_tolerance"] is True
-    assert art["worst_abs_rel_err"] <= art["tolerance"]
-    # ZeRO-1 demonstrably shards the update state: its argument bytes
-    # sit well under the replicated dp8 leg's
-    assert legs["dp8_zero1"]["xla"]["argument_bytes"] < \
-        0.6 * legs["dp8"]["xla"]["argument_bytes"]
+    if sharded:
+        # ZeRO-1 shards the update state: its arguments sit well under
+        # the replicated leg's
+        dp8 = multichip_leg(sharded=False)
+        assert leg["xla"]["argument_bytes"] < \
+            0.6 * dp8["xla"]["argument_bytes"]
 
 
 # ---------------------------------------------------------------------------

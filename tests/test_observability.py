@@ -2,9 +2,8 @@
 executor and serving spans, MFU math against hand-computed FLOPs, flight
 recorder dumps on non-finite loss and a raising op, the labeled metrics
 registry + Prometheus export, the monitor satellite fixes, the profiler
-tracer_option fix, the timeline merge upgrade, the disabled-telemetry
-overhead bound on the prepared hot loop, and the OBS_BENCH_r13 artifact
-contract."""
+tracer_option fix, the timeline merge upgrade, and the
+disabled-telemetry overhead bound on the prepared hot loop."""
 
 import gzip
 import json
@@ -258,8 +257,8 @@ def test_estimate_step_flops_hand_computed_fc():
 
 def test_estimate_step_flops_transformer_matches_analytic():
     """Op-spec pricing of a BERT-tiny pretrain step lands within 10% of
-    the analytic model FLOPS_AUDIT_r05 validated against XLA."""
-    from bench import bert_flops_per_step
+    the benchmark's analytic count (``benchmark/flops.py``)."""
+    from benchmark.flops import bert_flops_per_step
     from paddle_tpu.models import bert
 
     cfg = bert.BertConfig.tiny()
@@ -273,7 +272,7 @@ def test_estimate_step_flops_transformer_matches_analytic():
                                 num_masks=masks)
     est = flops.estimate_step_flops(main, feed_shapes=data,
                                     fetch_names=[total.name])
-    analytic = bert_flops_per_step(cfg, batch, seq, masks)
+    analytic = bert_flops_per_step(vars(cfg), batch, seq, masks)
     assert 0.9 <= est["total_flops"] / analytic <= 1.1
 
 
@@ -705,7 +704,7 @@ def test_disabled_telemetry_overhead_bound():
 
     The hook cost is microbenched directly (10⁵ calls per sample,
     min-of-repeats: stable to a few ns) against the stub-step loop time
-    measured with perf_probe's methodology — a subtraction of two full
+    (a stubbed compiled step) — a subtraction of two full
     loop timings cannot resolve a ~0.2 μs delta on a shared CI host,
     but cost-of-part vs cost-of-whole can."""
     import timeit
@@ -753,29 +752,6 @@ def test_disabled_telemetry_overhead_bound():
     # the loop here is an fc model (~6 μs class — SMALLER than PR 2's
     # 10 μs bench loop, so the ratio bound is tested conservatively)
     assert hook_ns <= 0.05 * loop_ns, (hook_ns, loop_ns)
-
-
-# ---------------------------------------------------------------------------
-# OBS_BENCH_r13 artifact contract (emitted by tools/obs_probe.py)
-# ---------------------------------------------------------------------------
-
-
-def test_obs_bench_artifact_contract():
-    """The committed artifact parses and passes the same bounds the
-    preflight selftest applies: per-step telemetry present, MFU in
-    (0, 1] and within ±10% of the FLOPS_AUDIT-validated analytic FLOPs
-    ÷ the measured step time, a schema-valid flight bundle from the
-    induced mid-run NaN, and the perfetto-merged timeline metadata."""
-    from tools.obs_probe import check
-    path = os.path.join(REPO, "OBS_BENCH_r13.json")
-    with open(path) as fh:
-        art = json.load(fh)
-    check(art)
-    # cross-artifact consistency: the same analytic model family that
-    # FLOPS_AUDIT_r05 validated against XLA's count
-    audit = json.load(open(os.path.join(REPO, "FLOPS_AUDIT_r05.json")))
-    assert audit["metric"] == "bert_step_flops_xla_vs_analytic"
-    assert 0.9 <= audit["value"] <= 1.1
 
 
 def test_span_lands_in_a_bare_jax_profiler_session(tmp_path):

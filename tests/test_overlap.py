@@ -25,13 +25,11 @@ Contracts proven here:
   budget flips the winner while the winner still minimizes exposed
   comm among fitting configs;
 * telemetry: steps carry ``exposed_comm_frac`` ∈ [0, 1];
-* the OVERLAP_CENSUS_r14 / PLAN_SEARCH_r14 artifact contracts;
 * misuse diagnostics (overlap-single-bucket / overlap-tail-sunk) and
   the overlap × localsgd strategy rejection.
 """
 
 import json
-import os
 import re
 
 import numpy as np
@@ -51,7 +49,6 @@ from paddle_tpu.distributed.fleet import (fleet, DistributedStrategy,
                                           distributed_optimizer,
                                           UserDefinedRoleMaker)
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 STEPS = 4
 N_LAYERS = 6
 
@@ -426,11 +423,14 @@ def test_module_ordering_census_interleaves_grad_sync():
     ar = [r for r in rows if r["kind"] == "all_reduce"]
     interleaved = [r for r in ar if r["compute_after"] > 0]
     assert len(interleaved) >= 4, rows
+    n_overlapped = len(ar)
 
     main, startup, loss, mesh = build(False)
     rows = ordering_census(_export_dp8(main, startup, loss.name, mesh))
     ar = [r for r in rows if r["kind"] == "all_reduce"]
     assert all(r["compute_after"] == 0 for r in ar), rows
+    # the tail-fused sync is a couple of giant collectives
+    assert len(ar) <= 2 < n_overlapped
 
 
 # ---------------------------------------------------------------------------
@@ -623,75 +623,3 @@ def test_overlap_rejects_localsgd():
         opt = distributed_optimizer(fluid.optimizer.Adam(5e-3), s)
         with pytest.raises(ValueError, match="overlap_grad_sync"):
             opt.minimize(loss)
-
-
-# ---------------------------------------------------------------------------
-# artifact contracts (tier-1 gates for the committed artifacts)
-# ---------------------------------------------------------------------------
-
-
-def test_overlap_census_artifact_contract():
-    path = os.path.join(REPO, "OVERLAP_CENSUS_r14.json")
-    assert os.path.exists(path), \
-        "run tools/verify_multichip_lowering.py --overlap"
-    with open(path) as f:
-        d = json.load(f)
-    assert d["artifact"] == "OVERLAP_CENSUS"
-    assert d["revision"] == "r14"
-    assert d["ok"] is True
-    sec = d["overlap_dp8"]
-    ov, tail = sec["overlapped"], sec["tail_fused"]
-    # the headline: ≥4 ready-ordered grad-sync collectives interleave
-    # with later backward compute on dp8 BERT; the tail-fused path
-    # (today's ~2 giant tail collectives) interleaves none
-    assert ov["interleaved"] >= 4
-    assert tail["interleaved"] == 0
-    assert ov["grad_sync_collectives"] > tail["grad_sync_collectives"]
-    assert tail["grad_sync_collectives"] <= 2
-    # every interleaved row really precedes compute in the module text
-    for row in ov["ordering"]:
-        assert row["compute_after"] >= 0 and row["line"] >= 0
-    # and the schedule change is numerics-free
-    assert sec["loss_bit_parity_vs_tail_fused"] is True
-    assert sec["loss_bit_parity_vs_tail_sunk_control"] is True
-    assert all(np.isfinite(l) for l in sec["losses"])
-
-
-def test_plan_search_r14_artifact_contract():
-    path = os.path.join(REPO, "PLAN_SEARCH_r14.json")
-    assert os.path.exists(path), "run tools/plan_probe.py"
-    with open(path) as f:
-        d = json.load(f)
-    assert d["artifact"] == "PLAN_SEARCH"
-    assert d["format_version"] >= 2
-    assert d["compiles_attempted"] == 0
-    assert d["configs_priced"] >= 6
-    cfgs = [c for c in d["configs"] if "error" not in c]
-    assert all("exposed_comm_ms" in c and "grad_sync_wire_bytes" in c
-               and "forward_wire_bytes" in c for c in cfgs)
-    winners = [c for c in cfgs if c["winner"]]
-    assert len(winners) == 1 and winners[0]["fits"]
-    fitting = [c for c in cfgs if c["fits"]]
-    best = min(round(c["exposed_comm_ms"] * 1e6) for c in fitting)
-    assert round(winners[0]["exposed_comm_ms"] * 1e6) == best, \
-        "winner does not minimize exposed comm among fitting configs"
-    tied = [c for c in fitting
-            if round(c["exposed_comm_ms"] * 1e6) == best]
-    assert winners[0]["wire_bytes"] == min(c["wire_bytes"] for c in tied)
-    assert any(not c["fits"] for c in cfgs), "budget excluded nothing"
-
-
-def test_kernel_ab_artifact_contract():
-    path = os.path.join(REPO, "KERNEL_AB_r14.json")
-    assert os.path.exists(path), "run tools/kernel_ab.py --selftest"
-    with open(path) as f:
-        d = json.load(f)
-    assert d["artifact"] == "KERNEL_AB"
-    assert len(d["configs"]) == 4
-    flag_pairs = {(r["use_flash_attention"], r["use_pallas_fused"])
-                  for r in d["configs"]}
-    assert flag_pairs == {(False, False), (True, False), (False, True),
-                         (True, True)}
-    for r in d["configs"]:
-        assert np.isfinite(r["final_loss"])
-        assert r["ms_per_step"] > 0 and r["samples_per_sec"] > 0

@@ -1,19 +1,16 @@
 """The per-op Pallas lowering tier (ops/registry.py pallas channel):
 static routing report, hit/fallback metrics counters, interpret-mode
 parity of the grafted kernels (ring-attention-via-flash,
-dequant-accumulate), and the KERNEL_CENSUS_r15.json artifact
-contract produced by tools/verify_lowering.py --census."""
+dequant-accumulate), and the census: every route's ``kernels=`` in the
+TPU-lowered module of the smallest program that hits the route."""
 
-import json
-import os
+import re
 
 import numpy as np
 import pytest
 
 import jax
 import jax.numpy as jnp
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _bert_tiny_train():
@@ -57,6 +54,19 @@ def test_routing_report_flash_hit_at_128_fallback_at_64():
     fb = [r for r in rep64["rows"] if r["op"] == "fused_attention"]
     assert fb and all(r["route"] == "fallback" for r in fb)
     assert all("seq" in r["reason"] for r in fb)
+
+
+def test_proglint_kernels_json_embeds_the_routing_report():
+    import io
+    import json
+    from tools.proglint import lint
+    cfg, main_p, _, total = _bert_tiny_train()
+    sink = io.StringIO()
+    assert lint(main_p, fetch_names=[total.name], kernels=True,
+                as_json=True, out=sink) == 0
+    rows = json.loads(sink.getvalue())["kernel_routing"]["rows"]
+    assert rows and all(r["route"] in ("pallas", "fallback")
+                        and r["reason"] for r in rows)
 
 
 def test_routing_report_zero_compiles(monkeypatch):
@@ -201,19 +211,14 @@ def test_route_table_picks_tile_only_for_one_tile(ins, attrs, axis_sizes,
                                      axis_sizes=axis_sizes, count=False)
     assert route is not None, reason
     assert route.kernel == want
-    if want == "attention_tile":
-        assert route.kernels == ("attn_tile_fwd", "attn_tile_bwd")
-    else:
-        assert route.kernels == ("flash_fwd", "flash_bwd_dq",
-                                 "flash_bwd_dkv")
 
 
 def test_fused_attention_op_counts_tile_hits_and_lowers_its_kernels():
     """Trace-time census: the op impl's plain branch resolves the
     one-tile route (hit counter by op, kernel, reason) and the traced
-    program holds attn_tile_fwd / attn_tile_bwd in the op's (B, S, H*D)
-    layout — no transpose around them; a causal op of the same shape
-    still counts and lowers flash_*."""
+    program holds that route's kernels in the op's (B, S, H*D) layout —
+    no transpose around them; a causal op of the same shape still
+    counts and lowers the blockwise route's."""
     from paddle_tpu.observability import metrics
     from paddle_tpu.ops.pallas import lowering_target
     from paddle_tpu.ops.registry import LoweringContext, get_op
@@ -243,9 +248,11 @@ def test_fused_attention_op_counts_tile_hits_and_lowers_its_kernels():
         assert (hits("attention_tile"), hits("flash_attention")) == (1, 0)
         flash = str(jax.make_jaxpr(jax.grad(loss(True), (0, 1, 2)))(q, q, q))
         assert (hits("attention_tile"), hits("flash_attention")) == (1, 1)
-    assert "attn_tile_fwd" in tile and "attn_tile_bwd" in tile
+    routes = _table_pairs()
+    for name in routes[("fused_attention", "attention_tile")].kernels:
+        assert name in tile
     assert "flash_" not in tile and "transpose[" not in tile
-    for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+    for name in routes[("fused_attention", "flash_attention")].kernels:
         assert name in flash
     assert "attn_tile" not in flash
 
@@ -489,51 +496,186 @@ def test_dequant_kernel_gate_mirrors_kernel():
 
 
 # ---------------------------------------------------------------------------
-# KERNEL_CENSUS_r15.json artifact contract
+# the census: every route's kernels in the TPU-lowered module of its op
 # ---------------------------------------------------------------------------
 
 
-def test_kernel_census_artifact_contract():
-    path = os.path.join(REPO, "KERNEL_CENSUS_r15.json")
-    assert os.path.exists(path), \
-        "run: python tools/verify_lowering.py --census"
-    with open(path) as f:
-        art = json.load(f)
-    assert art["artifact"] == "KERNEL_CENSUS"
-    assert art["revision"] == "r15"
-    assert art["lowered_for"] == "tpu"
-    assert art["ok"] is True
-    secs = art["sections"]
-    # every grafted kernel is present as a custom call in the TPU-
-    # cross-lowered module of its hot path
-    for k in ("attn_tile_fwd", "attn_tile_bwd"):
-        assert k in secs["single_device_bert_tiny_seq128"]["kernels"]
-    assert "flash_fwd" not in secs["single_device_bert_tiny_seq128"]["kernels"]
-    assert "fused_adam" not in secs["single_device_bert_tiny_seq128"]["kernels"]
-    assert "flash_fwd" in secs["ring_attention_sp4"]["kernels"]
-    for k in ("flash_bwd_dq", "flash_bwd_dkv"):
-        assert k in secs["ring_attention_sp4_grad"]["kernels"]
-    assert "dequant_accumulate_requant" in secs["quant_int8_dp8"]["kernels"]
-    assert "dequant_accumulate" in secs["quant_int4_dp8"]["kernels"]
-    for s in secs.values():
-        assert s["complete"], s["leg"]
-        assert s["tpu_custom_call_sites"] > 0
-    # parity recorded and within bounds; quantized legs carry PR 6's
-    # end-to-end wire-tier contract
-    par = art["parity"]
-    for key in ("ring_flash_vs_einsum_fwd", "ring_flash_vs_einsum_grad",
-                "dequant_acc_int8", "dequant_acc_int4"):
-        assert par[key]["measured"] <= par[key]["bound"], key
-    assert par["ring_flash_vs_einsum_fwd"]["bound"] <= 1e-5
-    assert secs["quant_int8_dp8"]["wire_tier_parity_bound"] == 5e-2
-    assert secs["quant_int4_dp8"]["wire_tier_parity_bound"] == 2.5e-1
-    # the embedded static routing report agrees with the module census
-    rep = secs["single_device_bert_tiny_seq128"]["routing_report"]
-    assert rep["summary"]["attention_tile"]["pallas"] > 0
-    assert rep["summary"]["fused_layer_norm"]["pallas"] > 0
+def _tpu_kernel_names(fn, *args):
+    """``kernel_name``s of the ``tpu_custom_call``s in ``fn`` cross-lowered
+    for TPU on this host (no chip: a kernel Mosaic's front end refuses
+    fails the export)."""
+    from paddle_tpu.ops.pallas import lowering_target
+    with lowering_target("tpu"):
+        txt = jax.export.export(jax.jit(fn), platforms=("tpu",))(
+            *args).mlir_module()
+    return set(re.findall(r'kernel_name = "(\w+)"', txt))
 
 
-def test_census_selftest_wired_into_preflight():
-    with open(os.path.join(REPO, "tools", "preflight.sh")) as f:
-        sh = f.read()
-    assert "verify_lowering.py --selftest" in sh
+def _op_fn(op, ins, attrs, out="Out", grad=True, mesh=None, specs=None,
+           is_test=False):
+    """``(fn, args)``: the registered impl of ``op`` over the float arrays
+    of ``ins`` (slot -> array), differentiated when ``grad``; under
+    ``shard_map`` over ``mesh`` with ``specs`` (slot -> PartitionSpec)
+    when the route needs a mesh axis."""
+    from paddle_tpu.ops.registry import LoweringContext, get_op
+    impl = get_op(op)
+    slots = list(ins)
+    axis_names = tuple(mesh.axis_names) if mesh is not None else ()
+
+    def fwd(*arrays):
+        ctx = LoweringContext(jax.random.PRNGKey(0), mesh=mesh,
+                              axis_names=axis_names, is_test=is_test)
+        o = impl(ctx, {s: [a] for s, a in zip(slots, arrays)},
+                 attrs)[out]
+        return o[0] if isinstance(o, (list, tuple)) else o
+
+    args = [ins[s] for s in slots]
+    run = fwd
+    if grad:
+        wrt = tuple(i for i, a in enumerate(args)
+                    if jnp.issubdtype(a.dtype, jnp.floating))
+        # the value too: a gradient alone drops the forward kernel
+        run = jax.value_and_grad(
+            lambda *a: jnp.sum(fwd(*a).astype(jnp.float32)), wrt)
+    if mesh is not None:
+        from jax.sharding import PartitionSpec as P
+        in_specs = tuple(specs.get(s, P()) for s in slots)
+        out_specs = (P(), tuple(in_specs[i] for i in wrt)) if grad \
+            else specs.get(out, P())
+        run = jax.shard_map(run, mesh=mesh, in_specs=in_specs,
+                            out_specs=out_specs, check_vma=False)
+    return run, args
+
+
+def _mesh(n, axis):
+    from jax.sharding import Mesh
+    return Mesh(np.array(jax.devices()[:n]), (axis,))
+
+
+def _f32(*shape):
+    return jnp.zeros(shape, jnp.float32)
+
+
+def _attention(attrs, s=128, hidden=128, bias=False, **kw):
+    ins = {"Q": _f32(2, s, hidden), "K": _f32(2, s, hidden),
+           "V": _f32(2, s, hidden)}
+    if bias:
+        ins["AttnBias"] = _f32(2, 1, s, s)
+    return [_op_fn("fused_attention", ins, attrs, **kw)]
+
+
+def _ring_attention():
+    from jax.sharding import PartitionSpec as P
+    seq = P(None, "sp")
+    return _attention({"n_head": 2, "_seq_axis": "sp"}, s=512,
+                      mesh=_mesh(4, "sp"),
+                      specs={"Q": seq, "K": seq, "V": seq})
+
+
+def _cached_attention():
+    pool = _f32(4, 128, 128)
+    ins = {"Q": _f32(1, 128, 128), "KPool": pool, "VPool": pool,
+           "BlockTable": jnp.zeros((1, 1), jnp.int32),
+           "CtxLen": jnp.full((1,), 128, jnp.int32)}
+    return [_op_fn("fused_attention", ins,
+                   {"n_head": 2, "_cached": True, "is_test": True},
+                   is_test=True)]
+
+
+def _grouped_ffn():
+    n, d, f, e, k = 256, 128, 128, 2, 2
+    ins = {"X": _f32(n, d), "TopkWeight": _f32(n, k),
+           "TopkIndex": jnp.zeros((n, k), jnp.int32),
+           "WGate": _f32(e, d, f), "WUp": _f32(e, d, f),
+           "WDown": _f32(e, f, d)}
+    return [_op_fn("moe_grouped_ffn", ins, {})]
+
+
+def _norm(op, residual=False):
+    ins = {"X": _f32(16, 128), "Scale": _f32(128), "Bias": _f32(128)}
+    if residual:
+        ins["Residual"] = _f32(16, 128)
+    return [_op_fn(op, ins, {"begin_norm_axis": 1}, out="Y")]
+
+
+def _bias_gelu():
+    return [_op_fn("fused_elemwise_activation",
+                   {"X": _f32(16, 128), "Y": _f32(128)},
+                   {"functor_list": ["elementwise_add", "gelu"]})]
+
+
+def _multihead_matmul():
+    qkv = _f32(1, 2, 128, 64)
+    return [_op_fn("multihead_matmul", {"Q": qkv, "K": qkv, "V": qkv},
+                   {"is_test": True}, grad=False, is_test=True)]
+
+
+def _quant_collective(op, dtypes):
+    """One program a wire dtype: int8 round-to-nearest fuses the
+    requantisation into the receive kernel, int4 does not."""
+    from jax.sharding import PartitionSpec as P
+    return [_op_fn(op, {"X": _f32(8, 8 * 256)},
+                   {"_axis_name": "dp",
+                    "quant_spec": {"dtype": dt, "block_size": 256}},
+                   grad=False, mesh=_mesh(8, "dp"),
+                   specs={"X": P("dp"), "Out": P("dp")})
+            for dt in dtypes]
+
+
+#: (op, route) -> the smallest programs that hit it, between them holding
+#: every kernel the route names
+ROUTE_CASES = {
+    ("fused_attention", "attention_tile"): lambda: _attention(
+        {"n_head": 2, "dropout_rate": 0.1}, bias=True),
+    ("fused_attention", "flash_attention"): lambda: _attention(
+        {"n_head": 2, "causal": True}, s=256),
+    ("fused_attention", "flash_gqa_attention"): lambda: _attention(
+        {"n_head": 2, "num_kv_heads": 1, "window": 128, "causal": True},
+        s=256, hidden=256),
+    ("fused_attention", "ring_flash_attention"): _ring_attention,
+    ("fused_attention", "cached_flash_attention"): _cached_attention,
+    ("moe_grouped_ffn", "moe_grouped_matmul"): _grouped_ffn,
+    ("layer_norm", "fused_layer_norm"): lambda: _norm("layer_norm"),
+    ("fused_add_layernorm", "fused_add_layer_norm"): lambda: _norm(
+        "fused_add_layernorm", residual=True),
+    ("fused_elemwise_activation", "fused_bias_gelu"): _bias_gelu,
+    ("multihead_matmul", "flash_attention"): _multihead_matmul,
+    ("c_quant_allreduce_sum", "dequant_accumulate"):
+        lambda: _quant_collective("c_quant_allreduce_sum",
+                                  ("int8", "int4")),
+    ("c_fused_quant_allreduce_sum", "dequant_accumulate"):
+        lambda: _quant_collective("c_fused_quant_allreduce_sum",
+                                  ("int8", "int4")),
+    ("quant_reduce_scatter", "dequant_accumulate"):
+        lambda: _quant_collective("quant_reduce_scatter", ("int8",)),
+}
+
+
+def _table_pairs():
+    import paddle_tpu.fluid  # noqa: F401 - registers the spec library
+    from paddle_tpu.ops.registry import pallas_table
+    return {(op, r.kernel): r for op, routes in pallas_table().items()
+            for r in routes}
+
+
+def test_every_route_has_a_census_case():
+    """A route cannot land without its case below."""
+    assert set(_table_pairs()) == set(ROUTE_CASES)
+
+
+@pytest.mark.skipif(jax.device_count() < 8,
+                    reason="needs the 8-device virtual CPU mesh")
+@pytest.mark.parametrize("op,route", sorted(ROUTE_CASES),
+                         ids=lambda v: v)
+def test_route_kernels_in_tpu_module(op, route):
+    """The route table's ``kernels=`` field is the one statement of which
+    kernels a route puts in a TPU module; this is its one check."""
+    from paddle_tpu.observability import metrics
+    want = _table_pairs()[(op, route)].kernels
+    metrics.reset_metrics()
+    found = set()
+    for fn, args in ROUTE_CASES[(op, route)]():
+        found |= _tpu_kernel_names(fn, *args)
+    assert metrics.counter("pallas_routes", op=op, kernel=route,
+                           outcome="hit", reason="supported").get() > 0
+    assert set(want) <= found, (sorted(want), sorted(found))

@@ -1,8 +1,8 @@
 """Executor fast-path tests: feed device cache, async fetch pipelining,
 DataLoader device prefetch (the r4 perf work — VERDICT r3 #1).
 
-These validate semantics on CPU; the throughput effect is measured on
-hardware by tools/perf_probe.py.
+These validate semantics on CPU; the throughput effect is the
+benchmark's to measure on the chip (``feed_wait_ms.train``).
 """
 
 import numpy as np

@@ -17,8 +17,7 @@ restore (framework/reshard.py, io.py checkpoint format v2).
   retention pruning keeps the newest ``max_checkpoints``; cold-start
   restore on an empty dir is clean;
 * a layout mismatch raises an anchored InvalidArgumentError naming
-  BOTH layouts (never a shape error deep in the executor);
-* the RESHARD_r16.json artifact contract (tools/reshard_probe.py).
+  BOTH layouts (never a shape error deep in the executor).
 """
 
 import json
@@ -45,7 +44,6 @@ from paddle_tpu.distributed.fleet import (fleet, DistributedStrategy,
                                           UserDefinedRoleMaker)
 from paddle_tpu.monitor import stat
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 # ---------------------------------------------------------------------------
@@ -595,32 +593,6 @@ def test_retry_exhaustion_propagates(tmp_path, monkeypatch):
         exe.run(startup)
         with pytest.raises(OSError):
             _tiny_ckpt(exe, str(tmp_path), 0, main)
-
-
-# ---------------------------------------------------------------------------
-# RESHARD_r16.json artifact contract (tools/reshard_probe.py)
-# ---------------------------------------------------------------------------
-
-
-def test_reshard_artifact_contract():
-    path = os.path.join(REPO, "RESHARD_r16.json")
-    assert os.path.exists(path), \
-        "run: python tools/reshard_probe.py --selftest"
-    with open(path) as f:
-        art = json.load(f)
-    assert art["artifact"] == "RESHARD"
-    legs = {l["name"]: l for l in art["legs"]}
-    for want in ("dp8_to_dp8", "dp8_to_dp4", "dp8_to_dp16", "tp2_to_tp1"):
-        assert want in legs, f"missing leg {want}"
-    assert legs["dp8_to_dp8"]["bit_exact"] is True
-    for name, leg in legs.items():
-        assert leg["max_loss_delta"] <= 1e-6, (name, leg)
-        assert leg["executed_wire_bytes"] == leg["planned_wire_bytes"]
-        assert leg["compiles_on_rejected"] == 0
-    assert legs["dp8_to_dp16"]["planned_wire_bytes"] == 0   # pure slice
-    assert legs["dp8_to_dp4"]["planned_wire_bytes"] > 0
-    assert art["compiles_on_rejected_total"] == 0
-    assert art["candidates_rejected_total"] >= 1
 
 
 # ---------------------------------------------------------------------------

@@ -4,9 +4,8 @@ cache across a simulated process restart (fresh Executor, same cache
 dir; corrupt-entry fallback), continuous-batching lifecycle races, the
 queue-discipline fixes (head-of-line packing, whole-queue deadline
 sweep, notify-driven idle wait), ServingFleet HBM admission with
-eviction-under-budget, and the SERVE_BENCH_r11 artifact contract."""
+eviction-under-budget."""
 
-import json
 import os
 import threading
 import time
@@ -23,7 +22,6 @@ from paddle_tpu.inference import AnalysisConfig, create_paddle_predictor
 from paddle_tpu.serving import (ServingConfig, ServingEngine, ServingFleet,
                                 pack_requests)
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 SEQ_FEEDS = ("src_ids", "pos_ids", "sent_ids", "input_mask")
 
@@ -138,7 +136,10 @@ class TestRaggedPacking:
         stats = engine.stats()
         assert stats["packing"] is True
         assert stats["batches"] == 1
-        # packing occupancy beats one-row-per-request padding by design
+        # packing occupancy beats one-row-per-request padding of the same
+        # requests (each row at its seq bucket, rows at the batch bucket)
+        one_row = 8 * 32        # 6 requests -> batch bucket 4 + 2 -> 8 rows
+        assert stats["padding_waste"] < 1.0 - sum(lengths) / one_row
         assert stats["padding_waste"] < 0.5
         engine.shutdown()
 
@@ -347,8 +348,10 @@ class TestContinuousLifecycle:
             t.join(120)
         assert not errors
         for (tid, i), (x, out) in results.items():
+            # a batched row differs from a lone run by float noise; a
+            # misrouted one by O(1)
             ref, = baseline.run([x])
-            np.testing.assert_array_equal(out, ref)
+            np.testing.assert_allclose(out, ref, rtol=2e-5, atol=2e-6)
         engine.shutdown()
 
 
@@ -538,46 +541,3 @@ class TestServingFleet:
                                        donate_state=False)
         assert est.peak_bytes > est.state_bytes > 0
         assert est.as_dict()["peak_bytes"] == est.peak_bytes
-
-
-# ---------------------------------------------------------------------------
-# SERVE_BENCH_r11 artifact contract (emitted by tools/serve_bench.py)
-# ---------------------------------------------------------------------------
-
-
-def test_serve_bench_r11_artifact_contract():
-    """The committed Serving-v2 artifact parses and documents the
-    acceptance bounds: ragged steady-state >= 1.0x the naive loop with
-    <= 15 % packing waste (was 0.81x / 44.7 %); the warm restart
-    performs 0 fresh compiles, hits the cache for every bucket, warms
-    >= 5x faster than cold, bit-identical; the over-budget tenant is
-    rejected pre-compile by name and admits after one eviction."""
-    path = os.path.join(REPO, "SERVE_BENCH_r11.json")
-    with open(path) as fh:
-        art = json.load(fh)
-    assert art["metric"] == "serving_v2"
-
-    ragged = art["ragged"]
-    assert ragged["requests"] > 0
-    assert ragged["distinct_request_shapes"] >= 12
-    assert ragged["steady_state_ratio"] >= 1.0, ragged
-    assert ragged["padding_waste"] <= 0.15, ragged
-    assert ragged["padding_waste"] < ragged["padding_waste_padded"]
-    assert ragged["parity_max_abs_diff"] <= 2e-5
-    assert 0 < ragged["compiles"] <= ragged["bucket_capacity"]
-
-    aot = art["aot_cache"]
-    assert aot["combos"] > 0
-    assert aot["cold_fresh_compiles"] == aot["combos"]
-    assert aot["warm_fresh_compiles"] == 0, aot
-    assert aot["warm_hits"] >= aot["combos"]
-    assert aot["warmup_speedup"] >= 5.0, aot
-    assert aot["bit_identical"] is True
-
-    mt = art["multi_tenant"]
-    assert mt["rejected_model"] == "model_b"
-    assert mt["rejection_names_model"] is True
-    assert mt["compiles_at_reject"] == 0
-    assert mt["evicted_variant"]
-    assert mt["admitted_after_evict"] == ["model_a", "model_b"]
-    assert mt["served_after_admit"] is True

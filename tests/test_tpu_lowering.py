@@ -1,11 +1,10 @@
 """Chip-free lowering verification.
 
-Cross-lowers the bench-shape BERT training step for ``platforms=("tpu",)``
+Cross-lowers the ``s128`` cell's BERT training step for ``platforms=("tpu",)``
 on the CPU host via jax.export and asserts, from the StableHLO text alone:
 
-  * the Pallas flash-attention kernels (fwd + both bwd) are present as
-    ``tpu_custom_call``s,
-  * the fused LayerNorm and Adam Pallas kernels are present,
+  * the kernels of the routes the static report says the program takes
+    are present as ``tpu_custom_call``s, and no others,
   * every state buffer is donated (``tf.aliasing_output``),
   * the step is ONE executable and same-shape fresh batches do not
     recompile.
@@ -37,10 +36,11 @@ def _build_pretrain(cfg):
 
 
 @pytest.fixture(scope="module")
-def lowered_bench_step():
-    """The exact bench.py model/optimizer config, cross-lowered for TPU.
+def bench_step():
+    """The ``bert_pretrain.s128`` cell's model and optimizer, cross-lowered
+    for TPU: ``(exported, program, feed)``.
 
-    Bench shapes (batch 96, seq 128) with a 2-layer config: layers share
+    Cell shapes (batch 96, seq 128) with a 2-layer config: layers share
     shapes, so kernel presence/donation are identical to the 12-layer
     module while tracing stays fast on the CPU CI host."""
     cfg = bert.BertConfig.base()
@@ -55,27 +55,33 @@ def lowered_bench_step():
                                     num_masks=20)
         exported = lower_train_step_for_tpu(main_prog, data, [total],
                                             scope=scope)
-    return exported
+    return exported, main_prog, data
+
+
+@pytest.fixture(scope="module")
+def lowered_bench_step(bench_step):
+    return bench_step[0]
 
 
 def test_platform_is_tpu(lowered_bench_step):
     assert tuple(lowered_bench_step.platforms) == ("tpu",)
 
 
-def test_pallas_kernels_present(lowered_bench_step):
-    txt = lowered_bench_step.mlir_module()
+def test_pallas_kernels_present(bench_step):
+    """The module holds exactly the kernels of the routes the static
+    report says the program takes (the route table's ``kernels=``): at
+    seq 128 the one-tile attention pair and fused LayerNorm, none of the
+    blockwise flash kernels, no kernel for the Adam update."""
+    from paddle_tpu.framework.analysis import kernel_routing_report
+    exported, main_prog, data = bench_step
+    txt = exported.mlir_module()
     names = set(re.findall(r'kernel_name = "(\w+)"', txt))
-    assert txt.count("tpu_custom_call") > 0, "no Mosaic custom calls at all"
-    # attention at seq 128 is one tile: the one-tile forward and the ONE
-    # fused backward kernel — and none of the blockwise flash kernels
-    assert "attn_tile_fwd" in names, f"attn tile fwd missing; found {names}"
-    assert "attn_tile_bwd" in names, f"attn tile bwd missing; found {names}"
-    assert not {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"} & names, names
-    # fused LayerNorm fwd+bwd
-    assert "fused_layer_norm_fwd" in names, f"fused LN fwd missing; found {names}"
-    assert "fused_layer_norm_bwd" in names, f"fused LN bwd missing; found {names}"
-    # the Adam update is XLA's own fusion, no kernel
-    assert "fused_adam" not in names, names
+    report = kernel_routing_report(
+        main_prog, feed_shapes={k: np.asarray(v) for k, v in data.items()},
+        backend="tpu")
+    hit = {r["kernel"] for r in report["rows"] if r["route"] == "pallas"}
+    assert hit == {"attention_tile", "fused_layer_norm"}
+    assert names == {k for r in report["rows"] for k in r["kernels"]}
 
 
 def test_fluid_op_scopes_and_kernel_names_in_op_metadata(lowered_bench_step):
@@ -178,11 +184,11 @@ def test_single_executable_no_per_step_recompile():
 
 def test_flops_denominator_sane():
     """XLA's counted FLOPs for the compiled step must bracket the
-    analytic GEMM model bench.py divides by — a wrong denominator would
-    silently misreport MFU (tiny config; the full-scale audit artifact
-    is FLOPS_AUDIT_r05.json via tools/flops_audit.py)."""
+    analytic GEMM count ``train_mfu_pct`` divides by
+    (``benchmark/flops.py``) — a wrong denominator would silently
+    misreport MFU (tiny config)."""
     import jax
-    from bench import bert_flops_per_step
+    from benchmark.flops import bert_flops_per_step
     cfg = bert.BertConfig.tiny()
     batch, seq, masks = 8, 64, 4
     main_prog, startup, total = _build_pretrain(cfg)
@@ -203,7 +209,7 @@ def test_flops_denominator_sane():
     ca = compiled.cost_analysis()
     ca = ca[0] if isinstance(ca, list) else ca
     xla = float(ca.get("flops", 0.0))
-    analytic = float(bert_flops_per_step(cfg, batch, seq, masks))
+    analytic = float(bert_flops_per_step(vars(cfg), batch, seq, masks))
     ratio = xla / analytic
     # tiny models carry relatively more non-GEMM work, so the band is
     # loose; at bench scale the tool reports ~1.0-1.3

@@ -3,7 +3,8 @@
 
 Runs seven deterministic fault drills — all injected through
 ``paddle_tpu.testing.faultline`` seams, never by monkeypatching — and
-emits ``CHAOS_r18.json`` with the results + recovery accounting:
+prints the results + recovery accounting (each drill is also a live
+test in tests/test_guardrails.py and tests/test_launch_audit.py):
 
 1. **nan_skip** — NaN injected into a gradient at device step k: the
    step is SKIPPED with params + optimizer state bitwise equal to step
@@ -36,13 +37,11 @@ emits ``CHAOS_r18.json`` with the results + recovery accounting:
 
 Usage::
 
-    python tools/chaos_probe.py              # writes CHAOS_r18.json
-    python tools/chaos_probe.py --selftest   # tmp artifact + assertions
+    python tools/chaos_probe.py
 """
 
 from __future__ import annotations
 
-import argparse
 import json
 import os
 import sys
@@ -55,7 +54,6 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
-ARTIFACT = "CHAOS_r18.json"
 SCHEMA = "paddle_tpu.chaos/1"
 
 #: the documented injection-seam list (MIGRATION.md "Fault tolerance
@@ -426,7 +424,7 @@ def drill_rank_divergence(work_dir):
     }
 
 
-def run(artifact_path):
+def run():
     from paddle_tpu.flags import get_flags, set_flags
     from paddle_tpu.testing import faultline
     work_dir = tempfile.mkdtemp(prefix="chaos_probe_")
@@ -468,14 +466,11 @@ def run(artifact_path):
                 "aborted_at_rendezvous"] else 1,
         },
     }
-    with open(artifact_path, "w") as f:
-        json.dump(art, f, indent=1)
     return art
 
 
 def check(art):
-    """The selftest assertions — the same contract the tier-1 artifact
-    test (tests/test_guardrails.py) applies to the committed file."""
+    """What a run has to show."""
     assert art["metric"] == "chaos_drills"
     assert art["schema"] == SCHEMA
     assert art["seams"] == list(DOCUMENTED_SEAMS), art["seams"]
@@ -513,20 +508,10 @@ def check(art):
 
 
 def main():
-    ap = argparse.ArgumentParser()
-    ap.add_argument("--selftest", action="store_true",
-                    help="tmp artifact + assertions (preflight gate)")
-    ap.add_argument("--out", type=str, default=None)
-    args = ap.parse_args()
-    if args.selftest:
-        out = os.path.join(tempfile.mkdtemp(prefix="chaos_probe_"),
-                           ARTIFACT)
-    else:
-        out = args.out or os.path.join(REPO, ARTIFACT)
-    art = run(out)
+    art = run()
     check(art)
     print(json.dumps(art["recovery_accounting"]))
-    print(f"chaos_probe OK -> {out}")
+    print("chaos_probe OK")
 
 
 if __name__ == "__main__":

@@ -12,7 +12,8 @@ by the executor compile counter — then runs the one drill that must be
 dynamic: a real two-process rendezvous where rank 1 arms the
 ``rank_divergence`` faultline seam (a divergent bucket reorder) and
 both ranks must ABORT with exit code 43 naming the op, instead of
-hanging.  Results land in ``LAUNCH_AUDIT_r24.json``:
+hanging.  The classes (each also a live test in
+tests/test_launch_audit.py):
 
 1. **control_flow_collective** — a collective under a data-dependent
    branch: ranks taking different arms deadlock
@@ -40,13 +41,12 @@ hanging.  Results land in ``LAUNCH_AUDIT_r24.json``:
 
 Usage::
 
-    python tools/launch_probe.py              # writes LAUNCH_AUDIT_r24.json
+    python tools/launch_probe.py
     python tools/launch_probe.py --selftest   # tmp artifact + assertions
 """
 
 from __future__ import annotations
 
-import argparse
 import json
 import os
 import subprocess
@@ -57,7 +57,6 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
-ARTIFACT = "LAUNCH_AUDIT_r24.json"
 SCHEMA = "paddle_tpu.launch_audit/1"
 
 #: every statically seeded class and the launch-* code that must catch it
@@ -257,7 +256,7 @@ def _rendezvous_drill(timeout=120):
     }
 
 
-def run(out_path: str):
+def run():
     from paddle_tpu.monitor import stat
     compiles_before = stat("executor_compile_count").get()
 
@@ -310,15 +309,11 @@ def run(out_path: str):
             "exit_code_launch_divergence": la.EXIT_LAUNCH_DIVERGENCE,
         },
     }
-    with open(out_path, "w") as f:
-        json.dump(art, f, indent=1, sort_keys=True)
-        f.write("\n")
     return art
 
 
 def check(art):
-    """The artifact contract — the same assertions the tier-1 test
-    (tests/test_launch_audit.py) applies to the committed file."""
+    """What a run has to show."""
     assert art["metric"] == "launch_audit"
     assert art["schema"] == SCHEMA
     assert set(art["classes"]) == set(STATIC_CLASSES)
@@ -340,20 +335,10 @@ def check(art):
 
 
 def main():
-    ap = argparse.ArgumentParser()
-    ap.add_argument("--selftest", action="store_true",
-                    help="tmp artifact + assertions (preflight gate)")
-    ap.add_argument("--out", type=str, default=None)
-    args = ap.parse_args()
-    if args.selftest:
-        out = os.path.join(tempfile.mkdtemp(prefix="launch_probe_"),
-                           ARTIFACT)
-    else:
-        out = args.out or os.path.join(REPO, ARTIFACT)
-    art = run(out)
+    art = run()
     check(art)
     print(json.dumps(art["accounting"]))
-    print(f"launch_probe OK -> {out}")
+    print("launch_probe OK")
 
 
 if __name__ == "__main__":

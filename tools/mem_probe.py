@@ -8,8 +8,8 @@ REAL step and reads XLA's ground truth via
 error of ``estimate.peak_bytes`` against XLA's
 ``argument_size_in_bytes + temp_size_in_bytes`` (donated outputs alias
 their arguments, so args+temp IS the per-device live peak) must sit
-inside the tolerance band asserted by tier-1
-(tests/test_memory_analysis.py over the committed artifact).
+inside the tolerance band (tests/test_memory_analysis.py asserts it
+live on the smallest rung and the two mesh legs).
 
 Legs:
   * the transformer-bench ladder (TransformerConfig.tiny at the
@@ -23,11 +23,10 @@ Legs:
     output-shard accounting.
 
 Usage:
-  python tools/mem_probe.py [out.json]          # all legs, write artifact
+  python tools/mem_probe.py                     # all legs
   MP_LADDER=8x4,16x4 python tools/mem_probe.py  # subset of rungs
 """
 
-import json
 import os
 import sys
 
@@ -188,12 +187,6 @@ def main():
               f'rel={leg["rel_err"]:+.3f}')
     print(f'worst |rel_err| = {art["worst_abs_rel_err"]:.3f} '
           f'(tolerance ±{TOLERANCE})')
-    out = sys.argv[1] if len(sys.argv) > 1 else os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        "MEM_ESTIMATE_r09.json")
-    with open(out, "w") as f:
-        json.dump(art, f, indent=1)
-    print(f"wrote {out}")
     return 0 if art["all_within_tolerance"] else 1
 
 

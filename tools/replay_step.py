@@ -21,7 +21,7 @@ Usage::
     python tools/replay_step.py <flight_bundle.json> --checkpoint <dir>
 
 Exit code 0 iff the anomaly reproduced.  ``replay()`` is importable —
-tools/chaos_probe.py runs it in-process for the CHAOS_r18 drill.
+tools/chaos_probe.py runs it in-process for its replay drill.
 """
 
 from __future__ import annotations

@@ -31,16 +31,13 @@ Legs:
     lower), flops/mem skipped (unbalanced stages break the ideal
     SPMD divisor).
 
-The committed artifact (SPEC_AUDIT_r22.json) records the per-channel
-tolerance bands, the spec-coverage census (the ratchet tier-1 asserts
-against the live registry) and every leg's reconciliation rows.
+tests/test_spec_audit.py runs the legs live.
 
 Usage:
-  python tools/spec_audit_probe.py [out.json]   # all legs, write artifact
+  python tools/spec_audit_probe.py              # all legs
   python tools/spec_audit_probe.py --selftest   # fast subset + seeded drift
 """
 
-import json
 import os
 import sys
 
@@ -283,7 +280,7 @@ def run_probe():
 
 
 def selftest():
-    """Fast preflight tier: one single-device leg with all compiled
+    """Fast tier: one single-device leg with all compiled
     channels, the dp8 wire leg, and a seeded-drift smoke proving the
     auditor actually fires (corrupt one infer spec, expect exactly that
     op anchored under spec-drift-shape)."""
@@ -365,12 +362,6 @@ def main():
         print(f'{mark} {leg["leg"]:28s} ' + " ".join(rows))
     print(f'worst |rel_err| = {art["worst_abs_rel_err"]} '
           f'(bands {art["tolerances"]})')
-    out = sys.argv[1] if len(sys.argv) > 1 else os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        "SPEC_AUDIT_r22.json")
-    with open(out, "w") as f:
-        json.dump(art, f, indent=1)
-    print(f"wrote {out}")
     return 0 if art["all_within_tolerance"] and not art["shape_drift_total"] \
         else 1
 

@@ -1,32 +1,27 @@
-"""Multi-chip perf verification without hardware (companion to
-tools/verify_lowering.py): cross-lower the dp2/tp2/sp2 BERT TRAINING
-step for platforms=("tpu",) on the 8-device virtual CPU mesh and report
-the XLA collectives in the compiled TPU module — the sharded path's
-grad all-reduces, Megatron f/g pair, and ring-attention permutes are
-checked invariants, not claims.
+"""Multi-chip lowering verification without hardware: cross-lower the
+dp2/tp2/sp2 BERT TRAINING step for platforms=("tpu",) on the 8-device
+virtual CPU mesh and report the XLA collectives in the TPU module — the
+sharded path's grad all-reduces, Megatron f/g pair, and ring-attention
+permutes are checked invariants, not claims.
 
-Since the grad-comm PR the report is a per-collective CENSUS (op kind,
-count, total payload bytes) emitted as a JSON artifact next to the text
-report, and ``collective_census``/``donation_ratio`` are importable by
-the tier-1 tests that assert the bucketed-collective bound
-(tests/test_tpu_lowering.py).
+The report is a per-collective CENSUS (op kind, count, total payload
+bytes); ``collective_census`` / ``ordering_census`` / ``donation_ratio``
+/ ``lower_dp8_bert_census`` / ``fsdp_zero3_section`` are what the tier-1
+tests import (tests/test_tpu_lowering.py, test_grad_comm.py,
+test_overlap.py, test_shard_planner.py).
 
-Since the wire-compression PR each census row also carries true WIRE
-accounting (ring cost model over the op's replica-group size):
-``wire_bytes`` (what the schedule actually moves over ICI),
-``logical_bytes`` (the same payload priced at ≥fp32 master width) and
-``compression_ratio`` = logical/wire — 1.0 for full-precision rows (the
-back-compat default r06/r07 readers assume), ≈4 for int8 payloads, and
-a ``by_dtype`` byte breakdown that the zero-full-precision-collectives
-test asserts on.  The artifact gains a ``quant_dp8`` section comparing
-the dp8 BERT bucketed grad sync across the fp32/bf16/int8/int4 tiers
-(``MULTICHIP_CENSUS_r10.json``, ratio floors asserted in tier-1).
+Each census row also carries true WIRE accounting (ring cost model over
+the op's replica-group size): ``wire_bytes`` (what the schedule actually
+moves over ICI), ``logical_bytes`` (the same payload priced at ≥fp32
+master width) and ``compression_ratio`` = logical/wire — 1.0 for
+full-precision rows, ≈4 for int8 payloads — and a ``by_dtype`` byte
+breakdown that the zero-full-precision-collectives test asserts on.
 
 Usage:
-    PYTHONPATH=/root/repo python tools/verify_multichip_lowering.py \
-        [out.txt [census.json]]
-    PYTHONPATH=/root/repo python tools/verify_multichip_lowering.py \
-        --selftest        # dp8 quant census only, asserts ratio floors
+    python tools/verify_multichip_lowering.py             # dp2xtp2xsp2 report
+    python tools/verify_multichip_lowering.py --selftest  # dp8 wire tiers
+    python tools/verify_multichip_lowering.py --overlap   # ready-order census
+    python tools/verify_multichip_lowering.py --fsdp      # ZeRO-3 census
 """
 
 import json
@@ -41,11 +36,6 @@ _DTYPE_BYTES = {"f64": 8, "i64": 8, "u64": 8, "f32": 4, "i32": 4, "u32": 4,
                 "bf16": 2, "f16": 2, "i16": 2, "u16": 2, "i8": 1, "u8": 1,
                 "i1": 1}
 
-#: dp8 end-to-end parity bounds per wire dtype tier, as asserted by the
-#: tests/test_grad_comm.py legs (loss-trajectory rtol vs the fp32 dp8
-#: baseline over 4 Adam steps) — recorded in the census artifact so the
-#: byte numbers always travel with their accuracy contract
-PARITY_BOUNDS = {"bf16": 5e-2, "int8": 5e-2, "int4": 2.5e-1}
 
 
 def _tensor_elems_dtype(ty):
@@ -95,16 +85,14 @@ def collective_census(mlir_txt):
     bytes, by_dtype, wire_bytes, logical_bytes, compression_ratio}.
 
     ``bytes`` is the summed payload (result tensors) of that collective
-    kind — the r06/r07 field, unchanged.  ``wire_bytes`` applies the
+    kind.  ``wire_bytes`` applies the
     ring cost model (see :func:`_wire_bytes`) at the payload's actual
     element width; ``logical_bytes`` prices the same elements at master
     width (≥4 bytes — a bf16/int8 payload is a compressed view of fp32
     values; int4 payloads are packed 2-per-byte int8 carriers, so their
     census ratio understates the true 8× which the cross-tier
-    ``quant_dp8`` artifact section measures directly).
-    ``compression_ratio`` = logical/wire, 1.0 when unknown (the
-    back-compat default old artifact readers assume for rows without
-    the field).
+    :func:`quant_dp8_section` measures directly).
+    ``compression_ratio`` = logical/wire, 1.0 when unknown.
 
     Region-carrying ops (all_reduce, reduce_scatter) print their type on
     the closing ``}) : ... ->`` line; region-free ops carry it inline."""
@@ -312,7 +300,7 @@ def _dp8_run_and_lower(main_p, startup, mesh, total, steps=2):
 
 
 def overlap_dp8_section(min_buckets=8):
-    """The overlap-scheduling proof the r14 artifact carries: the dp8
+    """The overlap-scheduling proof: the dp8
     BERT-tiny grad sync, tail-fused vs ready-order overlapped —
 
     * ordering census of both lowered modules: tail mode's grad-sync
@@ -380,9 +368,8 @@ def overlap_dp8_section(min_buckets=8):
 
 
 def overlap_main(argv):
-    """``--overlap [out.json]``: run the overlap-scheduling census and
-    write the r14 artifact (ordering census + bit-parity; asserted in
-    tier-1 by tests/test_overlap.py)."""
+    """``--overlap``: run the overlap-scheduling census (ordering census
+    + bit-parity; tests/test_overlap.py asserts both live)."""
     _env8()
     section = overlap_dp8_section()
     ov, tail = section["overlapped"], section["tail_fused"]
@@ -392,28 +379,20 @@ def overlap_main(argv):
           tail["grad_sync_collectives"]
           and section["loss_bit_parity_vs_tail_fused"]
           and section["loss_bit_parity_vs_tail_sunk_control"])
-    out = {"artifact": "OVERLAP_CENSUS",
-           "revision": "r14",
-           "overlap_dp8": section,
-           "ok": bool(ok)}
-    path = next((a for a in argv if not a.startswith("--")),
-                "OVERLAP_CENSUS_r14.json")
-    with open(path, "w") as f:
-        json.dump(out, f, indent=1)
     print(f"overlap census {'OK' if ok else 'FAILED'}: "
           f"{ov['interleaved']}/{ov['grad_sync_collectives']} "
           f"interleaved grad-sync collectives (tail mode: "
           f"{tail['interleaved']}/{tail['grad_sync_collectives']}), "
           f"bit parity vs tail-fused="
-          f"{section['loss_bit_parity_vs_tail_fused']} — wrote {path}")
+          f"{section['loss_bit_parity_vs_tail_fused']}")
     return 0 if ok else 1
 
 
 def quant_dp8_section():
-    """The wire-compression comparison the r10 artifact carries: total
-    ring-model wire bytes of the dp8 BERT bucketed grad sync per dtype
-    tier, and the headline compression ratios (asserted ≥3.5×
-    int8-vs-fp32 / ≥1.9× int8-vs-bf16 in tier-1)."""
+    """The wire-compression comparison: total ring-model wire bytes of
+    the dp8 BERT bucketed grad sync per dtype tier, and the headline
+    compression ratios (tests/test_grad_comm.py asserts ≥3.5×
+    int8-vs-fp32 / ≥1.9× int8-vs-bf16 live)."""
     modes = {}
     for mode in ("fp32", "bf16", "int8", "int4"):
         census = lower_dp8_bert_census(mode)
@@ -432,13 +411,12 @@ def quant_dp8_section():
         "int4_vs_fp32": round(w["fp32"] / w["int4"], 3),
     }
     return {"module": "dp8_bert_tiny_train_bucketed",
-            "modes": modes, "ratios": ratios,
-            "parity_bounds": PARITY_BOUNDS}
+            "modes": modes, "ratios": ratios}
 
 
 def fsdp_zero3_section(fsdp=8):
-    """ZeRO-3 census on the fsdp8 BERT-tiny train step (the r12
-    artifact's ``fsdp_zero3`` section): prove the lowering keeps NO
+    """ZeRO-3 census on the fsdp8 BERT-tiny train step: prove the
+    lowering keeps NO
     full-parameter resident copies (per-device resident parameter bytes
     = full ÷ fsdp, measured on the LIVE sharded state arrays after a
     real step) and gathers parameters only in per-layer windows (one
@@ -533,8 +511,7 @@ def fsdp_zero3_section(fsdp=8):
 
 
 def selftest():
-    """Preflight gate: the quant census ratios must clear the floors the
-    artifact (and tier-1) promise."""
+    """The quant census ratios must clear their floors."""
     _env8()
     section = quant_dp8_section()
     r = section["ratios"]
@@ -598,7 +575,7 @@ def main():
     counts = {k: v["count"] for k, v in census.items()}
     # static collective/donation soundness over the SAME program the
     # census lowers (framework/analysis.py): a silently-dropped donation
-    # or divergent collective schedule fails the artifact, not just the
+    # or divergent collective schedule fails the run, not just the
     # numbers (regression gate for the PR 2 silent-donation-drop class)
     from paddle_tpu.framework.analysis import (check_collective_consistency,
                                                verify_program)
@@ -617,50 +594,23 @@ def main():
         f"({len(soundness_errs)} error(s))",
         f"verdict: {'OK' if counts.get('all_reduce', 0) >= 10 and counts.get('collective_permute', 0) >= 3 and not soundness_errs else 'MISSING COLLECTIVES OR UNSOUND'}",
     ]
-    # dp8 wire-compression comparison across dtype tiers (the r10
-    # headline: int8 buckets ≥3.5× fewer wire bytes than fp32)
+    # dp8 wire-compression comparison across dtype tiers (int8 buckets
+    # ≥3.5× fewer wire bytes than fp32)
     quant = quant_dp8_section()
     lines.append("dp8 quant wire ratios: " + json.dumps(quant["ratios"]))
     out = "\n".join(lines + soundness_errs)
     print(out)
-    if len(sys.argv) > 1:
-        with open(sys.argv[1], "w") as f:
-            f.write(out + "\n")
-    census_path = sys.argv[2] if len(sys.argv) > 2 else (
-        os.path.splitext(sys.argv[1])[0] + "_census.json"
-        if len(sys.argv) > 1 else None)
-    if census_path:
-        with open(census_path, "w") as f:
-            json.dump({"module": "dp2xtp2xsp2_bert_tiny_train",
-                       "census": census,
-                       "arg_donation": [donated, total],
-                       "static_soundness_errors": soundness_errs,
-                       "quant_dp8": quant}, f,
-                      indent=1)
 
 
 def fsdp_main(argv):
-    """``--fsdp [out.json]``: run the ZeRO-3 census and write the r12
-    artifact (fsdp section + a pointer to the r10 quant census, whose
-    numbers are unchanged by this PR)."""
+    """``--fsdp``: run the ZeRO-3 census."""
     _env8()
     section = fsdp_zero3_section()
-    out = {"artifact": "MULTICHIP_CENSUS",
-           "revision": "r12",
-           "fsdp_zero3": section,
-           "quant_dp8": {"see": "MULTICHIP_CENSUS_r10.json",
-                         "note": "wire-compression tiers unchanged; the "
-                                 "ZeRO-3 grad sync composes with them "
-                                 "through insert_grad_sync"}}
-    path = next((a for a in argv if not a.startswith("--")),
-                "MULTICHIP_CENSUS_r12.json")
-    with open(path, "w") as f:
-        json.dump(out, f, indent=1)
     print(f"fsdp census OK: {section['sharded_params']} sharded params, "
           f"resident ratio {section['resident_ratio']}x, "
           f"{section['module_all_gather_count']} all_gather / "
           f"{section['module_reduce_scatter_count']} reduce_scatter in "
-          f"module — wrote {path}")
+          f"module")
     return 0
 
 
