@@ -32,6 +32,17 @@ checks what comes out by the repo's own means:
   with an empty expert, then one period of the model (3 sliding layers +
   1 full, experts 0-15 of 64, a 24 576-row vocabulary slice, 8 192
   tokens) through ``exe.prepare(...).run`` under pure-bf16 Adam;
+* **leg L** — the served latent-attention decoder
+  (models/latent_decoder.py) at DeepSeek-V3's widths: the paged absorbed
+  decode kernel against the EXPANDED attention at the serving cell's
+  shapes (128 rows of 128 heads, contexts 1 / 16 / 17 / 4 095 / 6 144,
+  NaN in every slot no context owns), the sigmoid group-limited router
+  against the plain reference (sets equal, the bias only selects), the
+  grouped products at 0-8 tokens an expert with empty experts, then the
+  ``deepseek_v3_decode.reason_closed`` cell's own engine — its pool, its
+  chunk program and its 128-row chains under a watchdog — serving four
+  prompts of 1 024-4 096 tokens whose logits are held to the reference's
+  full forward pass;
 * **leg C** — four chips (run when >= 4 devices are visible): Fleet dp4
   with the bucketed grad all-reduce at per-chip batch 96, dp4-vs-one-chip
   loss parity, one dp2 x tp2 step, one ZeRO-1 flat-shard-Adam step.
@@ -58,7 +69,7 @@ import re
 import sys
 import time
 
-LEGS = ("K", "A", "B", "M", "C")
+LEGS = ("K", "A", "B", "M", "L", "C")
 
 
 def _say(msg):
@@ -916,6 +927,203 @@ def leg_server(S: Sizes, platform: str):
 
 
 # ---------------------------------------------------------------------------
+# leg L — the served latent-attention decoder
+# ---------------------------------------------------------------------------
+
+def _expanded_row(q, lat, wkvb, h, dn, dr, scale):
+    """One row's decode attention in the EXPANDED form, f32: q [H, dn +
+    dr], lat [T, dc + dr] -> [H * dv]."""
+    import jax
+    import jax.numpy as jnp
+    dc = wkvb.shape[0]
+    kv = (lat[:, :dc] @ wkvb).reshape(lat.shape[0], h, -1)
+    sc = jnp.einsum("hd,thd->ht", q[:, :dn], kv[..., :dn]) \
+        + jnp.einsum("hd,td->ht", q[:, dn:], lat[:, dc:dc + dr])
+    p = jax.nn.softmax(sc * scale, axis=-1)
+    return jnp.einsum("ht,thd->hd", p, kv[..., dn:]).reshape(-1)
+
+
+def leg_latent(S: Sizes, platform: str):
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from benchmark.builders import serve_lm
+    from benchmark.reference import deepseek_v3_jnp as ref
+    from benchmark.run import apply_rehearsal, load_manifest, resolve_cell
+    from paddle_tpu.ops.pallas.grouped_matmul import row_tile
+    from paddle_tpu.ops import mla_ops
+    from paddle_tpu.ops.decoder_lm_ops import (_sigmoid_group_topk,
+                                               grouped_ffn)
+    from paddle_tpu.ops.pallas import mla_paged
+
+    resolved = resolve_cell(load_manifest(),
+                            "deepseek_v3_decode.reason_closed")
+    config = resolved["config"]
+    if S.dry:
+        apply_rehearsal(config, resolved["traffic"])
+    cfg = serve_lm.decoder_config(config)
+    rng = np.random.RandomState(0)
+
+    # -- the paged absorbed kernel against the expanded form -------------
+    h, dn, dr, dv, dc = cfg.num_attention_heads, cfg.qk_nope_head_dim, \
+        cfg.qk_rope_head_dim, cfg.v_head_dim, cfg.kv_lora_rank
+    if S.dry:
+        h, dn, dr, dv, dc, width, bs = 8, 16, 128, 16, 128, 256, 16
+        rows, ctxs, pages = 8, (1, 16, 17, 47, 64), 4
+    else:
+        width, bs = cfg.latent_width, 16
+        rows, ctxs, pages = 128, (1, 16, 17, 4095, 6144), 512
+    attrs = {"n_head": h, "nope_dim": dn, "rope_dim": dr, "v_dim": dv,
+             "scale": 0.1}
+    ctx = np.array([ctxs[i % len(ctxs)] for i in range(rows)], np.int32)
+    need = -(-ctx // bs)
+    nb = int(need.sum()) + 1
+    table = np.zeros((rows, pages), np.int32)
+    order = rng.permutation(nb - 1) + 1
+    at = 0
+    for i, n in enumerate(need):
+        table[i, :n] = order[at:at + n]
+        at += n
+    pool = rng.randn(nb, bs, width).astype(np.float32)
+    pool[..., dc + dr:] = 0
+    owned = np.zeros((nb, bs), bool)
+    for i in range(rows):
+        flat = table[i, :need[i]][:, None] * bs + np.arange(bs)[None]
+        owned.reshape(-1)[flat.reshape(-1)[:ctx[i]]] = True
+    pool[~owned] = np.nan
+    pool = jnp.asarray(pool, jnp.bfloat16)
+    q = jnp.asarray(rng.randn(rows, 1, h * (dn + dr)) * 0.5, jnp.bfloat16)
+    wkvb = jnp.asarray(rng.randn(dc, h * (dn + dv)) * 0.05, jnp.bfloat16)
+    tbl, ctx_d = jnp.asarray(table), jnp.asarray(ctx)
+
+    @jax.jit
+    def kernel(q, pool, tbl, ctx_d, wkvb):
+        o = mla_paged.mla_paged_decode(
+            mla_ops.absorb_query(q, wkvb, attrs, width), pool, tbl, ctx_d,
+            latent_dim=dc, scale=attrs["scale"],
+            interpret=mla_paged.pltpu.InterpretParams() if S.dry else False)
+        return mla_ops.project_value(o, wkvb, attrs, jnp.float32)
+    got = np.asarray(kernel(q, pool, tbl, ctx_d, wkvb))[:, 0]
+    assert np.isfinite(got).all()
+    worst = 0.0
+    with jax.default_matmul_precision("highest"):
+        expanded = jax.jit(_expanded_row, static_argnums=(3, 4, 5, 6))
+        for i in range(len(ctxs)):            # one row of each context
+            flat = (table[i, :need[i]][:, None] * bs
+                    + np.arange(bs)[None]).reshape(-1)[:ctx[i]]
+            lat = pool.reshape(-1, width)[flat].astype(jnp.float32)
+            want = np.asarray(expanded(
+                q[i, 0].astype(jnp.float32).reshape(h, dn + dr), lat,
+                wkvb.astype(jnp.float32), h, dn, dr, attrs["scale"]))
+            worst = max(worst, _rel(got[i], want))
+    _say(f"  mla_paged_decode {rows} rows x {h} heads over {nb} blocks of "
+         f"{bs} x {width} bf16, contexts {ctxs}, NaN outside them: rel err "
+         f"{worst:.2e} against the expanded form")
+    assert worst < 2e-2, worst
+
+    # -- the router against the plain reference --------------------------
+    m = serve_lm.reference_model(config)
+    e = m["n_routed_experts"]
+    n = 64 if S.dry else 1024
+    logits = jnp.asarray(rng.randn(n, e).astype(np.float32))
+    bias = jnp.asarray(rng.randn(e).astype(np.float32) * 0.05)
+    rattrs = {"top_k": m["num_experts_per_tok"], "n_group": m["n_group"],
+              "topk_group": m["topk_group"],
+              "routed_scale": m["routed_scaling_factor"]}
+    vals, idx = jax.jit(lambda l, b: _sigmoid_group_topk(l, b, rattrs))(
+        logits, bias)
+    wts, ridx = ref.route(jax.nn.sigmoid(logits), bias, m)
+    same = (np.sort(idx, -1) == np.sort(ridx, -1)).all(-1)
+    np.testing.assert_allclose(np.sort(vals, -1)[same],
+                               np.sort(wts, -1)[same], rtol=1e-5)
+    s_chosen = np.take_along_axis(np.asarray(jax.nn.sigmoid(logits)),
+                                  np.asarray(idx), -1)
+    np.testing.assert_allclose(
+        vals, m["routed_scaling_factor"] * s_chosen
+        / s_chosen.sum(-1, keepdims=True), rtol=1e-5)
+    _say(f"  router: {int(same.sum())} of {n} tokens choose the "
+         f"reference's set of {rattrs['top_k']} of {e} (groups "
+         f"{rattrs['n_group']}, top {rattrs['topk_group']}); weights "
+         f"carry no bias")
+    assert same.mean() > 0.99, same.mean()
+
+    # -- the grouped products at decode sizes ----------------------------
+    d, f = cfg.hidden_size, cfg.moe_intermediate_size
+    lo, hi = cfg.held_experts
+    nt = 16 if S.dry else 128
+    xs = jnp.asarray(rng.randn(nt, d) * 0.5, jnp.bfloat16)
+    wg, wu = (jnp.asarray(rng.randn(hi - lo, d, f) * 0.02, jnp.bfloat16)
+              for _ in range(2))
+    wd = jnp.asarray(rng.randn(hi - lo, f, d) * 0.02, jnp.bfloat16)
+    tidx = jnp.asarray(np.stack([rng.permutation(e)[:rattrs["top_k"]]
+                                 for _ in range(nt)]).astype(np.int32))
+    tw = jnp.asarray(rng.rand(nt, rattrs["top_k"]).astype(np.float32))
+    outs = {}
+    for backend in ("xla", "pallas_interpret" if S.dry else "pallas"):
+        outs[backend], counts = jax.jit(
+            lambda *a, backend=backend: grouped_ffn(
+                *a, expert_offset=lo, backend=backend,
+                tile_m=8 if S.dry else row_tile(tidx.size, e)))(
+                    xs, tw, tidx, wg, wu, wd)
+    a, b = (np.asarray(v, np.float32) for v in outs.values())
+    counts = np.asarray(counts)
+    _say(f"  grouped products: {nt} tokens, {int(counts.sum())} "
+         f"assignments to {hi - lo} held experts of {e} (per expert "
+         f"{counts.min()}-{counts.max()}, {int((counts == 0).sum())} "
+         f"empty): rel err {_rel(b, a):.2e} against lax.ragged_dot")
+    assert _rel(b, a) < 3e-2
+
+    # -- the cell's engine -----------------------------------------------
+    routes0 = _route_counters()
+    t0 = time.perf_counter()
+    engine = serve_lm.build_engine(config, seed=7)
+    faulthandler.dump_traceback_later(1500, exit=True)
+    try:
+        assert engine._exe._device.platform == platform
+        n_warm = engine.warmup()
+        t_warm = time.perf_counter() - t0
+        assert n_warm == engine.config.executable_grid
+        flat_from = _compiles()
+        tr = resolved["traffic"]["prompt"]
+        plens = np.linspace(tr["min"], tr["max"], 4).astype(int)
+        prompts = [rng.randint(0, m["vocab_size"], (n,)).astype(np.int64)
+                   for n in plens]
+        max_new = 6 if S.dry else 128
+        engine.start()
+        t0 = time.perf_counter()
+        results = [f.result(timeout=900) for f in [
+            engine.generate({"src_ids": p}, max_new_tokens=max_new,
+                            return_logits=True) for p in prompts]]
+        t_gen = time.perf_counter() - t0
+        assert _compiles() == flat_from, "the engine compiled after warmup()"
+        st = engine.stats()
+        assert st["completed"] == 4 and not st["failed"], st
+        assert st["chunk_steps"] >= 4 and st["chain_hist"], st
+        assert st["moe_experts_hit"]["chain"] > 0, st
+        _say(f"  engine: {n_warm} executables warm in {t_warm:.1f} s, 4 "
+             f"prompts of {plens.tolist()} tokens x {max_new} new in "
+             f"{t_gen:.2f} s (smoke timings); chunk_steps "
+             f"{st['chunk_steps']}, chain_hist {st['chain_hist']}, experts "
+             f"hit {st['moe_experts_hit']}, assignments "
+             f"{st['moe_assignments_local']}")
+        weights = serve_lm.close_and_take_weights(engine)
+        readings = [serve_lm.compare(
+            config["reference"], m, cfg.held_experts, weights, p,
+            r.tokens, r.logits) for p, r in zip(prompts, results)]
+        verdict = serve_lm.judge(config["reference"], readings)
+        _say(f"  logits against the reference's full forward pass: "
+             f"{json.dumps(verdict)}")
+        assert verdict["ok"], readings
+    finally:
+        faulthandler.cancel_dump_traceback_later()
+        engine.close(timeout=5.0)
+    _check_routes(
+        _routes_since(routes0), S,
+        want_hits=("mla_paged_decode", "moe_grouped_matmul"),
+        allowed_fallbacks=())
+
+
+# ---------------------------------------------------------------------------
 # leg C — four chips
 # ---------------------------------------------------------------------------
 
@@ -1091,7 +1299,7 @@ def main(argv=None):
                     help="tiny width on the CPU backend, to debug this "
                          "script; proves nothing about the chip")
     ap.add_argument("--legs", default=",".join(LEGS),
-                    help="comma-separated subset of K,A,B,M,C")
+                    help="comma-separated subset of K,A,B,M,L,C")
     args = ap.parse_args(argv)
     legs = [x.strip().upper() for x in args.legs.split(",") if x.strip()]
     if not legs or set(legs) - set(LEGS):
@@ -1140,6 +1348,7 @@ def main(argv=None):
            "A": lambda: leg_trainer(S, device["platform"]),
            "B": lambda: leg_server(S, device["platform"]),
            "M": lambda: leg_decoder_lm(S, device["platform"]),
+           "L": lambda: leg_latent(S, device["platform"]),
            "C": lambda: leg_four_chips(S, device["platform"])}
     summary = []
     t_all = time.perf_counter()

@@ -245,6 +245,10 @@ def lower_decode_chain(ops, chain_idx, env, ctx, pool_names):
     slot_v, ctxl_v = in0("SlotIds"), in0("CtxLen")
     logits_v, tokens_v = in0("Logits"), in0("Tokens")
     out_v = chain_op.output("Out")[0]
+    # optional second output: every step's logits, stacked (a program
+    # whose marker declares LogitsOut; the host slices the rows of the
+    # requests that asked on the device and fetches only those)
+    logits_out = (chain_op.outputs.get("LogitsOut") or [None])[0]
     # native integer dtypes throughout (no forced int64 — x64 is
     # usually disabled and an explicit widening astype warns)
     table = env[in0("BlockTable")].astype(jnp.int32)
@@ -284,17 +288,21 @@ def lower_decode_chain(ops, chain_idx, env, ctx, pool_names):
         done2 = done | (left2 <= 0) | ((eos >= 0) & (nxt == eos))
         tok2 = jnp.where(done, tok, nxt)
         pos2 = jnp.where(done, pos, pos + 1)
+        ys = emitted if logits_out is None else (emitted, e[logits_v])
         return (tok2, pos2, left2, done2,
-                tuple(e[n] for n in pools)), emitted
+                tuple(e[n] for n in pools)), ys
 
     left0 = env[in0("StepsLeft")].astype(jnp.int32)
     carry0 = (env[tok_v].astype(jnp.int32), env[pos_v].astype(jnp.int32),
               left0, left0 <= 0, tuple(env[n] for n in pools))
-    carry, emitted = jax.lax.scan(one_step, carry0, None, length=length)
+    carry, ys = jax.lax.scan(one_step, carry0, None, length=length)
     out = dict(env)
     for n, v in zip(pools, carry[4]):
         out[n] = v
-    out[out_v] = emitted
+    if logits_out is None:
+        out[out_v] = ys
+    else:
+        out[out_v], out[logits_out] = ys
     return out
 
 

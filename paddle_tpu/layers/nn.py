@@ -240,19 +240,29 @@ def rms_norm(input, epsilon=1e-6, param_attr=None, name=None):
 
 
 def rotary_embedding(input, head_dim, rope_theta=10000.0,
-                     rope_type="default", name=None, **yarn):
+                     rope_type="default", name=None, pos=None,
+                     interleaved=False, rotary_dim=None, **yarn):
     """Rotary position embedding (rotate-half form) of every head of a
-    ``[B, S, heads * head_dim]`` projection at positions ``0..S-1``.
+    ``[B, S, heads * head_dim]`` projection at positions ``0..S-1``, or
+    at ``pos`` ``[B, S]`` when given (a served decoder's positions come
+    from its feeds).  ``interleaved`` rotates ADJACENT pairs together;
+    ``rotary_dim`` rotates only the last so many columns of every head.
     ``rope_type="yarn"`` takes ``factor``,
     ``original_max_position_embeddings``, ``beta_fast``, ``beta_slow``
     and ``attention_factor`` as the published configs name them."""
     helper = LayerHelper("rotary_embedding", name=name)
     out = helper.create_variable_for_type_inference(input.dtype, input.shape)
-    helper.append_op(type="rotary_embedding", inputs={"X": [input]},
-                     outputs={"Out": [out]},
-                     attrs=dict(yarn, head_dim=int(head_dim),
-                                rope_theta=float(rope_theta),
-                                rope_type=rope_type))
+    inputs = {"X": [input]}
+    attrs = dict(yarn, head_dim=int(head_dim), rope_theta=float(rope_theta),
+                 rope_type=rope_type)
+    if pos is not None:
+        inputs["Pos"] = [pos]
+    if interleaved:
+        attrs["interleaved"] = True
+    if rotary_dim and int(rotary_dim) != int(head_dim):
+        attrs["rotary_dim"] = int(rotary_dim)
+    helper.append_op(type="rotary_embedding", inputs=inputs,
+                     outputs={"Out": [out]}, attrs=attrs)
     return out
 
 

@@ -49,9 +49,10 @@ def flat_slots(kpool_shape):
 def _cache_write(ctx, ins, attrs):
     """Scatter per-token K/V rows into the paged pools.
 
-    Inputs: ``KPool``/``VPool`` ``[NB, BS, H]`` (persistable, updated in
-    place — under the donated prepared path the scatter aliases the pool
-    buffer), ``K``/``V`` ``[B, S, H]`` fresh projections, ``Slots``
+    With ``KPool``/``K`` alone the op writes ONE pool (a latent cache
+    keeps one tensor a layer).  Inputs: ``KPool``/``VPool``
+    ``[NB, BS, H]`` (persistable, updated in place — under the donated
+    prepared path the scatter aliases the pool buffer), ``K``/``V`` ``[B, S, H]`` fresh projections, ``Slots``
     ``[B, S]`` i32 flat slot ids (``block * BS + offset``; -1 = padding,
     dropped).  Outputs overwrite the pool vars.
 
@@ -70,6 +71,11 @@ def _cache_write(ctx, ins, attrs):
     # bounds instead so mode="drop" discards them
     idx = jnp.where(idx < 0, nslots, idx)
     flat_k = kpool.reshape(nslots, h)
+    if vpool is None:
+        # ONE pool a layer (a latent cache: ops/mla_ops.py)
+        return {"KPoolOut": flat_k.at[idx].set(
+            k.reshape(-1, h).astype(kpool.dtype), mode="drop")
+            .reshape(kpool.shape)}
     flat_v = vpool.reshape(nslots, h)
     new_k = flat_k.at[idx].set(k.reshape(-1, h).astype(kpool.dtype),
                                mode="drop")

@@ -21,6 +21,7 @@ experts as grouped matrix products).
   tests/test_decoder_lm.py).  Also returns the per-expert counts.
 * ``moe_load_stats``      folds those counts into a persistable counter
   on the device, read by ``PreparedStep.stats`` at a blocking point.
+* ``lm_head_logits``      the untied head alone, float32 logits (serving).
 * ``lm_head_loss``        the untied head and the per-token cross-entropy
   in one op, by blocks of rows: f32 logits exist one block at a time;
   what is kept for the backward pass is the logits in the compute dtype
@@ -98,18 +99,39 @@ def rope_inv_freq(head_dim: int, attrs) -> tuple:
 
 @register("rotary_embedding")
 def _rotary_embedding(ctx, ins, attrs):
+    """Every head of ``X`` [B, S, heads * head_dim] rotated at positions
+    ``Pos`` [B, S] when given (a served decoder: positions come from the
+    feed, a decode step's from its context length), else ``0..S-1``.
+    Rotate-half pairing, or under ``interleaved`` ADJACENT pairs
+    ``(x_2i, x_2i+1)`` together (the DeepSeek family's published form);
+    under ``rotary_dim`` only the LAST ``rotary_dim`` columns of every
+    head rotate (a head of ``[nope | rope]`` parts)."""
     xv = x(ins, "X")
     b, s, width = xv.shape
     d = int(attrs["head_dim"])
-    inv_freq, factor = rope_inv_freq(d, attrs)
-    # tables from TRACED shapes, so one program serves every length
-    freqs = jnp.arange(s, dtype=jnp.float32)[:, None] * inv_freq[None, :]
-    emb = jnp.concatenate([freqs, freqs], axis=-1)[None, :, None, :]
-    cos, sin = jnp.cos(emb) * factor, jnp.sin(emb) * factor
-    xh = xv.reshape(b, s, width // d, d).astype(jnp.float32)
-    rot = jnp.concatenate([-xh[..., d // 2:], xh[..., :d // 2]], axis=-1)
-    return {"Out": (xh * cos + rot * sin).astype(xv.dtype)
-            .reshape(b, s, width)}
+    r = int(attrs.get("rotary_dim") or d)
+    pos = x(ins, "Pos")
+    if pos is None:
+        # from TRACED shapes, so one program serves every length
+        pos = jnp.arange(s)[None, :]
+    inv_freq, factor = rope_inv_freq(r, attrs)
+    freqs = pos.astype(jnp.float32)[:, :, None, None] * inv_freq
+    heads = xv.reshape(b, s, width // d, d)
+    xh = heads[..., d - r:].astype(jnp.float32)
+    if attrs.get("interleaved"):
+        cos, sin = jnp.cos(freqs) * factor, jnp.sin(freqs) * factor
+        x1, x2 = xh[..., 0::2], xh[..., 1::2]
+        out = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                        axis=-1).reshape(xh.shape)
+    else:
+        emb = jnp.concatenate([freqs, freqs], axis=-1)
+        rot = jnp.concatenate([-xh[..., r // 2:], xh[..., :r // 2]],
+                              axis=-1)
+        out = xh * (jnp.cos(emb) * factor) + rot * (jnp.sin(emb) * factor)
+    out = out.astype(xv.dtype)
+    if r < d:
+        out = jnp.concatenate([heads[..., :d - r], out], axis=-1)
+    return {"Out": out.reshape(b, s, width)}
 
 
 # ---------------------------------------------------------------------------
@@ -124,11 +146,42 @@ def _moe_topk_router(ctx, ins, attrs):
     # highest precision (64 columns: 0.06 % of the layer's FLOPs)
     logits = jnp.matmul(xf, w.astype(jnp.float32),
                         precision=lax.Precision.HIGHEST)
+    if attrs.get("scoring", "softmax") == "sigmoid":
+        vals, idx = _sigmoid_group_topk(logits, x(ins, "Bias"), attrs)
+        return {"TopkWeight": vals, "TopkIndex": idx.astype(jnp.int32)}
     vals, idx = lax.top_k(jax.nn.softmax(logits, axis=-1),
                           int(attrs["top_k"]))
     if attrs.get("norm_topk_prob", True):
         vals = vals / jnp.sum(vals, axis=-1, keepdims=True)
     return {"TopkWeight": vals, "TopkIndex": idx.astype(jnp.int32)}
+
+
+def _sigmoid_group_topk(logits, bias, attrs):
+    """``scoring="sigmoid"``: ``s = sigmoid(logits)``; ``Bias`` [E] is
+    added for SELECTION only (it chooses, it never weighs); the experts
+    form ``n_group`` groups scored by the sum of their two largest
+    ``s + b``, the ``topk_group`` best groups stay, and the ``top_k``
+    largest ``s + b`` among them are chosen.  Weights ``s_k / sum s``
+    (``norm_topk_prob``) times ``routed_scale``."""
+    n, e = logits.shape
+    k = int(attrs["top_k"])
+    ng, kg = int(attrs.get("n_group", 1)), int(attrs.get("topk_group", 1))
+    s = jax.nn.sigmoid(logits)
+    pick = s if bias is None else s + bias.astype(jnp.float32)[None, :]
+    if ng > 1:
+        gscore = jnp.sum(lax.top_k(pick.reshape(n, ng, e // ng), 2)[0],
+                         axis=-1)
+        # a group stays iff fewer than topk_group groups beat it (ties
+        # to the lower index, as top_k breaks them)
+        _, gidx = lax.top_k(gscore, kg)
+        keep = jnp.zeros((n, ng), jnp.bool_).at[
+            jnp.arange(n)[:, None], gidx].set(True)
+        pick = jnp.where(jnp.repeat(keep, e // ng, axis=1), pick, -jnp.inf)
+    _, idx = lax.top_k(pick, k)
+    vals = jnp.take_along_axis(s, idx, axis=-1)
+    if attrs.get("norm_topk_prob", True):
+        vals = vals / (jnp.sum(vals, axis=-1, keepdims=True) + 1e-20)
+    return vals * float(attrs.get("routed_scale", 1.0)), idx
 
 
 def routing_layout(idx, expert_offset: int, e_local: int, tile_m: int):
@@ -286,11 +339,14 @@ def _moe_grouped_ffn(ctx, ins, attrs):
     xv, w, idx = x(ins, "X"), x(ins, "TopkWeight"), x(ins, "TopkIndex")
     wg, wu, wd = x(ins, "WGate"), x(ins, "WUp"), x(ins, "WDown")
     route, _ = pallas_route("moe_grouped_ffn", ins, attrs)
+    from .pallas.grouped_matmul import row_tile
     out, counts = grouped_ffn(
         xv.reshape(-1, xv.shape[-1]), w, idx, wg, wu, wd,
         expert_offset=int(attrs.get("expert_offset", 0)),
         backend="pallas" if route is not None else "xla",
-        tile_m=attrs.get("tile_m"))
+        # from what the op sees: the assignments and the router's width
+        tile_m=attrs.get("tile_m") or row_tile(
+            idx.size, int(attrs.get("num_experts") or wg.shape[0])))
     return {"Out": out.reshape(xv.shape), "ExpertCount": counts}
 
 
@@ -302,9 +358,13 @@ LOAD_STATS_EXTRA = 2
 @register("moe_load_stats")
 def _moe_load_stats(ctx, ins, attrs):
     counts, acc = x(ins, "Count"), x(ins, "Acc")
-    add = jnp.concatenate([counts, jnp.max(counts, keepdims=True),
-                           jnp.ones((1,), counts.dtype)])
-    return {"AccOut": acc + add.astype(acc.dtype)}
+    parts = [counts, jnp.max(counts, keepdims=True),
+             jnp.ones((1,), counts.dtype)]
+    if attrs.get("count_hit"):
+        # one more slot: the held experts with at least one assignment
+        parts.append(jnp.sum(counts > 0, keepdims=True)
+                     .astype(counts.dtype))
+    return {"AccOut": acc + jnp.concatenate(parts).astype(acc.dtype)}
 
 
 # ---------------------------------------------------------------------------
@@ -371,3 +431,12 @@ def _lm_head_loss(ctx, ins, attrs):
     loss = head_loss(xv.reshape(-1, xv.shape[-1]), w,
                      label.reshape(-1).astype(jnp.int32))
     return {"Loss": loss.reshape(label.shape)}
+
+
+@register("lm_head_logits")
+def _lm_head_logits(ctx, ins, attrs):
+    """The untied head of a SERVED decoder: ``x W`` accumulated and
+    returned in float32 whatever the operands' dtype (bfloat16 logits
+    would round away the lead a greedy token is chosen by)."""
+    xv, w = x(ins, "X"), x(ins, "W")
+    return {"Out": jnp.dot(xv, w, preferred_element_type=jnp.float32)}

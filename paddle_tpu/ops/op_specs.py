@@ -557,8 +557,10 @@ def _infer_cache_write(ins, attrs):
                 f"cache_write: Slots covers {list(slots.shape)} tokens "
                 f"but K carries {list(k.shape[:-1])}", kind="shape")
     out = [VarSig(kpool.shape, kpool.dtype)]
-    vout = [VarSig(vpool.shape, vpool.dtype)] if vpool is not None and \
-        vpool.shape is not None else out
+    if vpool is None:           # one pool a layer (a latent cache)
+        return {"KPoolOut": out}
+    vout = [VarSig(vpool.shape, vpool.dtype)] if vpool.shape is not None \
+        else out
     return {"KPoolOut": out, "VPoolOut": vout}
 
 
@@ -582,7 +584,14 @@ def _infer_decode_chain(ins, attrs):
         raise SpecMismatch(
             f"decode_chain: StepsLeft rows {steps.shape[0]} != TokenIds "
             f"rows {b}", kind="shape")
-    return {"Out": [VarSig((length, b), "int64")]}
+    out = {"Out": [VarSig((length, b), "int64")]}
+    logits = _sig(ins, "Logits")
+    if logits is not None and logits.shape is not None:
+        # optional: every step's logits stacked (a request that asked
+        # for its logits; fetched lazily, sliced on the device)
+        out["LogitsOut"] = [VarSig((length,) + tuple(logits.shape),
+                                   logits.dtype)]
+    return out
 
 
 def _attention_probs_bytes(ins, outs, attrs):
@@ -986,6 +995,65 @@ def _infer_moe_topk_router(ins, attrs):
     k = int(attrs.get("top_k", 1))
     return {"TopkWeight": [VarSig((n, k), "float32")],
             "TopkIndex": [VarSig((n, k), "int32")]}
+
+
+def _infer_lm_head_logits(ins, attrs):
+    xv, w = _sig(ins, "X"), _sig(ins, "W")
+    if xv is None or xv.shape is None or w is None or w.shape is None:
+        return None
+    if len(w.shape) == 2 and xv.shape[-1] >= 0 and w.shape[0] >= 0 and \
+            xv.shape[-1] != w.shape[0]:
+        raise SpecMismatch(
+            f"lm_head_logits: W rows {w.shape[0]} != X width "
+            f"{xv.shape[-1]}", kind="shape")
+    return {"Out": [VarSig(tuple(xv.shape[:-1]) + (w.shape[-1],),
+                           "float32")]}
+
+
+def _infer_mla_attention(ins, attrs):
+    """Out [B, Sq, n_head * v_dim] in Q's dtype; Q and WKVB must hold
+    ``n_head`` heads of ``nope + rope`` and ``nope + v`` columns."""
+    q, w = _sig(ins, "Q"), _sig(ins, "WKVB")
+    if q is None or q.shape is None:
+        return None
+    h = int(attrs.get("n_head", 0))
+    dn, dr, dv = (int(attrs.get(k, 0)) for k in
+                  ("nope_dim", "rope_dim", "v_dim"))
+    if q.shape[-1] >= 0 and q.shape[-1] != h * (dn + dr):
+        raise SpecMismatch(
+            f"mla_attention: Q width {q.shape[-1]} != {h} heads of "
+            f"{dn}+{dr}", kind="shape")
+    if w is not None and w.shape is not None and len(w.shape) == 2 and \
+            w.shape[1] >= 0 and w.shape[1] != h * (dn + dv):
+        raise SpecMismatch(
+            f"mla_attention: WKVB columns {w.shape[1]} != {h} heads of "
+            f"{dn}+{dv}", kind="shape")
+    return {"Out": [VarSig(tuple(q.shape[:-1]) + (h * dv,), q.dtype)]}
+
+
+def _pl_mla_paged_supported(ins, attrs, axis_sizes=None):
+    """MLA paged decode route gate (ops/pallas/mla_paged.py): a cached
+    read with a one-token query and no ``QPos`` over a bfloat16 latent
+    pool in pages of a multiple of 16 tokens, the latent and the row in
+    whole 128-lane tiles."""
+    from .pallas.mla_paged import supported
+    q = _shape_of(_sig(ins, "Q"))
+    pool = _sig(ins, "Pool")
+    pshape = _shape_of(pool)
+    w = _shape_of(_sig(ins, "WKVB"))
+    if pool is None:
+        return False, "not-cached"
+    if q is None or len(q) != 3 or pshape is None or len(pshape) != 3 \
+            or w is None or min(q[1], pshape[1], pshape[2], w[0]) < 0:
+        return False, "shape-unknown"
+    return supported(q[1], int(attrs.get("n_head", 0)), w[0],
+                     int(attrs.get("rope_dim", 0)), pshape[1], pshape[2],
+                     pool.dtype, has_qpos=_sig(ins, "QPos") is not None)
+
+
+def _lower_mla_paged_decode(ctx, ins, attrs):
+    from .mla_ops import lower_mla_paged_decode
+    return lower_mla_paged_decode(ctx, ins, attrs)
 
 
 def _infer_moe_grouped_ffn(ins, attrs):
@@ -1577,6 +1645,13 @@ _PL_PAGED = PallasLowering(
     attr="use_flash", match=_PL_CACHED.match,
     supported=_pl_paged_supported, lower=_lower_paged_decode_attention,
     kernels=("paged_decode_attn",))
+# a decode step's read of the paged LATENT cache (mla_attention with a
+# Pool, one query token a row): the absorbed form on the MXU
+_PL_MLA_PAGED = PallasLowering(
+    "mla_paged_decode", flag="use_flash_attention", attr="use_flash",
+    match=lambda attrs, ax: bool(attrs.get("_cached")),
+    supported=_pl_mla_paged_supported, lower=_lower_mla_paged_decode,
+    kernels=("mla_paged_decode",))
 _PL_GMM = PallasLowering(
     "moe_grouped_matmul", flag="use_pallas_fused",
     supported=_pl_gmm_supported,
@@ -1784,6 +1859,9 @@ def register_default_specs():
     op_spec("rotary_embedding", infer=same_as_input(),
             flops=_flops_elemwise(3))
     op_spec("moe_topk_router", infer=_infer_moe_topk_router)
+    op_spec("lm_head_logits", infer=_infer_lm_head_logits)
+    op_spec("mla_attention", infer=_infer_mla_attention,
+            pallas=(_PL_MLA_PAGED,))
     op_spec("moe_grouped_ffn", infer=_infer_moe_grouped_ffn,
             flops=_flops_moe_grouped_ffn, pallas=(_PL_GMM,))
     op_spec("moe_load_stats", infer=same_as_input("Acc", "AccOut"))

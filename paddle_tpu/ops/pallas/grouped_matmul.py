@@ -44,6 +44,19 @@ TILE_M = 256
 VMEM_LIMIT = 64 * 1024 * 1024
 
 
+def row_tile(assignments: int, num_experts: int) -> int:
+    """The row tile for ``assignments`` spread over ``num_experts``: the
+    power of two from 16 (one bfloat16 sublane tile) up to TILE_M that
+    first reaches four times an expert's share under balance.  Every
+    group pads to a whole tile, so a decode step's few rows an expert
+    (128 x 8 over 256: 16) must not pad to a training step's tile
+    (8 192 x 8 over 64: 256)."""
+    tile = 16
+    while tile < TILE_M and tile * num_experts < 4 * assignments:
+        tile *= 2
+    return tile
+
+
 def padded_rows(assignments: int, groups: int, tile_m: int = TILE_M) -> int:
     """Rows of the worst-case buffer: every assignment here, every group
     rounded up to a whole tile."""
@@ -66,16 +79,33 @@ def _real_tile(t, na_ref):
     return jnp.minimum(t, jnp.maximum(na_ref[0] - 1, 0))
 
 
-def _gmm_kernel(tg_ref, na_ref, lhs_ref, rhs_ref, out_ref, *, transpose_rhs):
+def _gmm_kernel(tg_ref, na_ref, lhs_ref, rhs_ref, out_ref, *, transpose_rhs,
+                tile_axis=0):
     del tg_ref
 
-    @pl.when(pl.program_id(0) < na_ref[0])
+    @pl.when(pl.program_id(tile_axis) < na_ref[0])
     def _body():
         dims = (((1,), (1,)), ((), ())) if transpose_rhs \
             else (((1,), (0,)), ((), ()))
         out_ref[...] = lax.dot_general(
             lhs_ref[...], rhs_ref[0], dims,
             preferred_element_type=jnp.float32).astype(out_ref.dtype)
+
+
+#: the largest weight block ``gmm`` keeps whole (double-buffered in
+#: VMEM); a wider expert is cut into column blocks
+WEIGHT_BLOCK_BYTES = 8 * 1024 * 1024
+
+
+def column_block(k: int, n: int, itemsize: int) -> int:
+    """Columns of one weight block: all ``n`` where ``[k, n]`` fits
+    WEIGHT_BLOCK_BYTES, else the largest lane-aligned divisor of ``n``
+    that does."""
+    if k * n * itemsize <= WEIGHT_BLOCK_BYTES or n % 128:
+        return n
+    fits = [c for c in range(128, n, 128)
+            if n % c == 0 and k * c * itemsize <= WEIGHT_BLOCK_BYTES]
+    return max(fits) if fits else 128
 
 
 @functools.partial(jax.jit, static_argnames=("transpose_rhs", "tile_m",
@@ -85,6 +115,10 @@ def gmm(lhs, rhs, tile_group, num_active, *, transpose_rhs=False,
     m, k = lhs.shape
     n = rhs.shape[1] if transpose_rhs else rhs.shape[2]
     num_tiles = m // tile_m
+    tile_n = column_block(k, n, rhs.dtype.itemsize)
+    if tile_n < n:
+        return _gmm_by_columns(lhs, rhs, tile_group, num_active,
+                               transpose_rhs, tile_m, tile_n, interpret)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2, grid=(num_tiles,),
         in_specs=[
@@ -101,6 +135,48 @@ def gmm(lhs, rhs, tile_group, num_active, *, transpose_rhs=False,
         out_shape=jax.ShapeDtypeStruct((m, n), lhs.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=VMEM_LIMIT),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * m * k * n, transcendentals=0,
+            bytes_accessed=(m * (k + n) + rhs.size) * lhs.dtype.itemsize),
+        interpret=interpret,
+        name="moe_gmm",
+    )(tile_group, num_active, lhs, rhs)
+
+
+def _gmm_by_columns(lhs, rhs, tile_group, num_active, transpose_rhs,
+                    tile_m, tile_n, interpret):
+    """``gmm`` for experts too wide for one VMEM block (7168 x 2048 in
+    bf16 is 29 MB, twice that double buffered): the weight is cut into
+    column blocks of ``tile_n``, the OUTER grid axis, and the row tiles
+    stream past each column block, so a group's block is fetched once
+    while its consecutive tiles use it and every hit expert's matrix is
+    read exactly once — what a decode step's few rows an expert are
+    bound by.  The row tiles are read once a column block: ``n /
+    tile_n`` times, little beside the weights at any group size."""
+    m, k = lhs.shape
+    n = rhs.shape[1] if transpose_rhs else rhs.shape[2]
+    rhs_block = (1, tile_n, k) if transpose_rhs else (1, k, tile_n)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2, grid=(n // tile_n, m // tile_m),
+        in_specs=[
+            pl.BlockSpec((tile_m, k),
+                         lambda j, t, tg, na: (_real_tile(t, na), 0)),
+            pl.BlockSpec(
+                rhs_block,
+                (lambda j, t, tg, na: (tg[_real_tile(t, na)], j, 0))
+                if transpose_rhs else
+                (lambda j, t, tg, na: (tg[_real_tile(t, na)], 0, j))),
+        ],
+        out_specs=pl.BlockSpec((tile_m, tile_n),
+                               lambda j, t, tg, na: (_real_tile(t, na), j)))
+    return pl.pallas_call(
+        functools.partial(_gmm_kernel, transpose_rhs=transpose_rhs,
+                          tile_axis=1),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((m, n), lhs.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=VMEM_LIMIT),
         cost_estimate=pl.CostEstimate(
             flops=2 * m * k * n, transcendentals=0,

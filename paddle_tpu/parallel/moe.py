@@ -165,7 +165,10 @@ def moe_ffn(x: Variable, num_experts: int, ffn_hidden: int,
 def moe_dropless_ffn(x: Variable, num_experts: int, ffn_hidden: int,
                      top_k: int, held_experts: Optional[Tuple[int, int]]
                      = None, norm_topk_prob: bool = True, param_attr=None,
-                     name: Optional[str] = None) -> Variable:
+                     name: Optional[str] = None, scoring: str = "softmax",
+                     n_group: int = 1, topk_group: int = 1,
+                     routed_scale: float = 1.0, shared_hidden: int = 0,
+                     bias_attr=None, counter_tag=None) -> Variable:
     """Dropless SiLU-gated expert block: ``softmax(x W_r)`` over ALL
     ``num_experts`` without a capacity, the ``top_k`` largest
     (renormalised under ``norm_topk_prob``), and
@@ -175,7 +178,21 @@ def moe_dropless_ffn(x: Variable, num_experts: int, ffn_hidden: int,
     them all.  Assignments to experts held elsewhere are skipped; none to
     a held expert is ever dropped.  Per-expert counts accumulate on the
     device in a persistable counter that ``PreparedStep.stats`` reads
-    (``moe_assignments_local``, ``moe_expert_load_max|mean``)."""
+    (``moe_assignments_local``, ``moe_expert_load_max|mean``).
+
+    ``scoring="sigmoid"`` routes as the DeepSeek-V3 family does: sigmoid
+    scores, a selection-only bias ``b`` (``bias_attr``; it chooses, it
+    never weighs), ``n_group`` groups of which the ``topk_group`` best
+    stay, weights times ``routed_scale`` (ops/decoder_lm_ops.py).
+    ``shared_hidden`` adds a SHARED expert of that width which every
+    token visits: computed once on the whole input, not through the
+    sort, and held by every chip alike (so over the shares of an
+    expert-parallel layer it counts once).  ``counter_tag`` names the
+    load counter of THIS program (programs that share a scope — a decode
+    engine's prefill, chunk and chain — count apart), which then also
+    counts the held experts with at least one assignment a step;
+    ``False`` builds no counter (a served decoder's score program runs
+    in the reference scope, which holds weights and no engine state)."""
     from ..framework.initializer import ConstantInitializer
     from ..ops.decoder_lm_ops import LOAD_STATS_EXTRA
     lo, hi = held_experts if held_experts is not None else (0, num_experts)
@@ -197,10 +214,20 @@ def moe_dropless_ffn(x: Variable, num_experts: int, ffn_hidden: int,
                                                        (-1, top_k))
     index = helper.create_variable_for_type_inference(
         "int32", (-1, top_k), stop_gradient=True)
+    router_in = {"X": [x], "W": [router_w]}
+    router_attrs = {"top_k": top_k, "norm_topk_prob": norm_topk_prob}
+    if scoring != "softmax":
+        router_in["Bias"] = [helper.create_parameter(
+            _suffixed(bias_attr if bias_attr is not None else param_attr,
+                      "router_bias"), [num_experts], "float32",
+            default_initializer=ConstantInitializer(0.0))]
+        router_attrs.update(scoring=scoring, n_group=n_group,
+                            topk_group=topk_group,
+                            routed_scale=float(routed_scale))
     helper.append_op(
-        type="moe_topk_router", inputs={"X": [x], "W": [router_w]},
+        type="moe_topk_router", inputs=router_in,
         outputs={"TopkWeight": [weight], "TopkIndex": [index]},
-        attrs={"top_k": top_k, "norm_topk_prob": norm_topk_prob})
+        attrs=router_attrs)
     out = helper.create_variable_for_type_inference(x.dtype, x.shape)
     count = helper.create_variable_for_type_inference(
         "int32", (e_local,), stop_gradient=True)
@@ -210,9 +237,25 @@ def moe_dropless_ffn(x: Variable, num_experts: int, ffn_hidden: int,
                 "WGate": [wg], "WUp": [wu], "WDown": [wd]},
         outputs={"Out": [out], "ExpertCount": [count]},
         attrs={"expert_offset": lo, "num_experts": num_experts})
+    if shared_hidden:
+        from .. import layers
+
+        def shared(suffix, size, inp):
+            return layers.fc(inp, size, num_flatten_dims=len(x.shape) - 1,
+                             param_attr=_suffixed(param_attr, suffix),
+                             bias_attr=False)
+
+        hid = layers.elementwise_mul(
+            layers.swish(shared("shared_gate_w", shared_hidden, x)),
+            shared("shared_up_w", shared_hidden, x))
+        out = layers.elementwise_add(
+            out, shared("shared_down_w", d, hid))
+    if counter_tag is False:
+        return out
     # the load counter: a persistable the step carries on the device
-    acc_name = f"{helper.name}.load_stats"
-    shape = [e_local + LOAD_STATS_EXTRA]
+    acc_name = f"{helper.name}.load_stats" \
+        + (f".{counter_tag}" if counter_tag else "")
+    shape = [e_local + LOAD_STATS_EXTRA + (1 if counter_tag else 0)]
     acc = helper.main_program.global_block().create_var(
         name=acc_name, shape=shape, dtype="int32", persistable=True,
         stop_gradient=True)
@@ -221,14 +264,18 @@ def moe_dropless_ffn(x: Variable, num_experts: int, ffn_hidden: int,
         name=acc_name, shape=shape, dtype="int32", persistable=True), sb)
     helper.append_op(type="moe_load_stats",
                      inputs={"Count": [count], "Acc": [acc]},
-                     outputs={"AccOut": [acc]}, attrs={})
+                     outputs={"AccOut": [acc]},
+                     attrs={"count_hit": True} if counter_tag else {})
     # what PreparedStep.wait() adds to its stats from the counter's gain
-    helper.main_program.__dict__.setdefault("_device_counters", {})[
-        acc_name] = (
+    pairs = (
         ("moe_assignments_local", lambda g: int(g[:e_local].sum())),
         ("moe_expert_load_max", lambda g: int(g[e_local])),
         ("moe_expert_load_mean", lambda g: float(g[:e_local].sum())
          / e_local))
+    if counter_tag:
+        pairs += (("moe_experts_hit", lambda g: int(g[e_local + 2])),)
+    helper.main_program.__dict__.setdefault("_device_counters", {})[
+        acc_name] = pairs
     return out
 
 

@@ -88,7 +88,10 @@ parent is the launch's ``decode::prefill|chunk|chain`` span.
 executable kind; ``["kv_pages_read"]`` over ``["kv_pages_spanned"]`` is
 the share of the block tables the decode steps' cache reads touch (per
 step and row ``ceil(ctx / block_size)`` pages of ``max_blocks_per_seq``,
-counted on the host from the rows' positions); and every request
+counted on the host from the rows' positions); counters a model's
+programs keep on the device (``program._device_counters``, e.g. a
+sparse decoder's ``moe_assignments_local`` / ``moe_experts_hit``) appear
+as ``stats()[key][kind]`` per launch kind; and every request
 carries ``rid`` and its submit/admit/first-token/done stamps
 (``GenerationResult.timing``,
 ``stats()["queue_wait_ns"]``/``["first_token_ns"]``).
@@ -243,14 +246,28 @@ class DecodeConfig:
         return n
 
 
+_ROW_OF = None
+
+
+def _logits_row(logits, i: int):
+    """Row ``i`` of ``[..., B, vocab]`` logits as a device array: ONE
+    compiled slice whatever ``i`` is, dispatched and not waited for."""
+    global _ROW_OF
+    if _ROW_OF is None:
+        import jax
+        _ROW_OF = jax.jit(lambda x, i: jax.lax.dynamic_index_in_dim(
+            x, i, x.ndim - 2, keepdims=False))
+    return _ROW_OF(logits, np.int32(i))
+
+
 class GenerationResult:
     """What a generation future resolves to."""
 
     __slots__ = ("tokens", "prompt_len", "finish_reason", "steps",
-                 "timing")
+                 "timing", "_logit_parts", "_logits")
 
     def __init__(self, tokens, prompt_len, finish_reason, steps,
-                 timing=None):
+                 timing=None, logit_parts=None):
         self.tokens = np.asarray(tokens, dtype=np.int64)
         self.prompt_len = int(prompt_len)
         self.finish_reason = finish_reason      # "length" | "eos"
@@ -260,6 +277,24 @@ class GenerationResult:
         #: TTFT = (admit - submit) queue wait + (first_token - admit)
         #: prefill.  None from the reference loop.
         self.timing = timing
+        # (device rows [n, vocab] or [chain, vocab], tokens they cover)
+        # a launch; brought to the host when ``logits`` is first read
+        self._logit_parts = logit_parts
+        self._logits = None
+
+    @property
+    def logits(self):
+        """``[len(tokens), vocab]`` float32, row ``t`` the logits
+        ``tokens[t]`` was chosen from — only for a request that asked
+        (``generate(return_logits=True)``), else None.  The rows stay on
+        the device until the first read, which copies them in the
+        reader's thread: the worker only ever slices."""
+        if self._logits is None and self._logit_parts:
+            self._logits = np.concatenate([
+                np.asarray(rows, np.float32).reshape(-1, rows.shape[-1])[:n]
+                for rows, n in self._logit_parts])
+            self._logit_parts = None
+        return self._logits
 
     def __repr__(self):
         return (f"GenerationResult(tokens={self.tokens.tolist()}, "
@@ -273,10 +308,11 @@ class _Seq:
                  "t_submit", "steps", "_gather_idx", "waited_rounds",
                  "temperature", "top_k", "top_p", "seed", "hit_blocks",
                  "_chunk_off", "rid", "t_submit_ns", "t_admit_ns",
-                 "t_first_token_ns")
+                 "t_first_token_ns", "logits")
 
     def __init__(self, prompt, max_new, eos, on_token,
-                 temperature=0.0, top_k=0, top_p=0.0, seed=0):
+                 temperature=0.0, top_k=0, top_p=0.0, seed=0,
+                 return_logits=False):
         self.prompt = prompt
         self.max_new = max_new
         self.eos = eos
@@ -301,6 +337,8 @@ class _Seq:
         self.seed = int(seed)
         self.hit_blocks = 0            # leading blocks shared by ref
         self._chunk_off = 0            # prompt tokens already in cache
+        # (device rows, tokens they cover) a launch (None: not asked for)
+        self.logits: Optional[List[tuple]] = [] if return_logits else None
 
 
 class _PrefixIndex:
@@ -495,10 +533,26 @@ class DecodeEngine:
         self._exe = Executor(place)
         self._exe.run(self._programs.startup, scope=self._scope)
         import jax.numpy as jnp
-        for name in self._programs.cache_vars:
-            v = self._programs.decode.global_block().var(name)
+        progs = self._programs
+        declaring = [progs.decode, progs.prefill, progs.chunk,
+                     *progs.chains.values()]
+        for name in progs.cache_vars:
+            # a pool is in every program; a device counter only in the
+            # programs of its launch kind
+            v = next(p.global_block().var(name) for p in declaring
+                     if p is not None and p.global_block().has_var(name))
             self._scope.set_var(name, jnp.zeros(
                 tuple(v.shape), dtype=np.dtype(v.dtype)))
+        # device counters by launch kind: {kind: {persistable: pairs}}
+        # (``program._device_counters``; chains of every length share
+        # theirs), read by stats()
+        self._counters = {
+            kind: dict(getattr(prog, "_device_counters", {}))
+            for kind, prog in (("prefill", progs.prefill),
+                               ("chunk", progs.chunk),
+                               ("chain", next(iter(progs.chains.values()),
+                                              None)))
+            if prog is not None and getattr(prog, "_device_counters", None)}
         if flag("verify_programs"):
             from ..framework.analysis import verify_decode
             to_verify = [(self._programs.prefill,
@@ -519,19 +573,17 @@ class DecodeEngine:
                     prog, feed_names=feeds,
                     fetch_names=fetches_v,
                     scope_names=self._scope.var_names(),
-                    cache_vars=self._programs.cache_vars
+                    # the pools are in every program; a device counter
+                    # only in the programs of its launch kind (each was
+                    # found in some program above, where it was zeroed)
+                    cache_vars=[n for n in self._programs.cache_vars
+                                if prog.global_block().has_var(n)]
                 ).raise_on_error()
 
-        # isolated weight snapshot for the reference loop — fresh device
-        # buffers (a host copy would re-cross the host link on every
-        # scored token), taken BEFORE the donated fast path can consume
-        # the scope's own buffers
-        self._ref_scope = Scope()
-        for name in self._scope.var_names():
-            if name in self._programs.cache_vars:
-                continue
-            self._ref_scope.set_var(
-                name, jnp.array(self._scope.find_var(name), copy=True))
+        # the reference loop's isolated weight snapshot is taken at its
+        # first use (_reference_scope): an engine that never scores a
+        # prefix holds its weights once
+        self._ref_scope: Optional[Scope] = None
 
         fetches = list(self._programs.fetch_names)
         self._prefill = self._exe.prepare(
@@ -576,9 +628,12 @@ class DecodeEngine:
         self._chunking: List[_Seq] = []
         self._cond = threading.Condition()
         self._run_lock = threading.Lock()   # device rounds vs warmup
+        self._lock_wanted = 0               # stats() readers waiting for it
         self._ref_lock = threading.Lock()
         self._thread: Optional[threading.Thread] = None
         self._stop = False
+        self._abort = False                 # close(): stop at a round's end
+        self._closed = False
         self._accepting = True
         self._unhealthy: Optional[BaseException] = None
 
@@ -725,7 +780,8 @@ class DecodeEngine:
                  on_token=None, temperature: Optional[float] = None,
                  top_k: Optional[int] = None,
                  top_p: Optional[float] = None,
-                 seed: Optional[int] = None) -> Future:
+                 seed: Optional[int] = None,
+                 return_logits: bool = False) -> Future:
         """Submit one prompt; returns a Future of
         :class:`GenerationResult`.  ``on_token(token_id)`` (optional)
         streams tokens from the worker thread as they decode.
@@ -735,7 +791,11 @@ class DecodeEngine:
         ``DecodeConfig(sampling=True)``); default/``temperature<=0``
         rows stay greedy and keep the bit-parity contract.  A fixed
         seed draws the same tokens no matter how the request is
-        co-batched or chain-scheduled.
+        co-batched or chain-scheduled.  ``return_logits`` also hands back
+        the float32 logits every token was chosen from
+        (``GenerationResult.logits``), sliced on the device for this
+        request alone; it needs a model whose chain programs return
+        them (``DecoderPrograms.chain_fetch_names``).
 
         Admission prices :func:`blocks_needed` HERE — a request that can
         never fit the pool (or the model's length budget) is rejected
@@ -746,6 +806,11 @@ class DecodeEngine:
             raise InvalidArgumentError(
                 "sampling parameters need DecodeConfig(sampling=True) — "
                 "this engine's chain executables were built greedy-only")
+        if return_logits and len(self._programs.chain_fetch_names) < 2:
+            raise InvalidArgumentError(
+                "return_logits needs chain programs that return every "
+                "step's logits; this model's return "
+                f"{self._programs.chain_fetch_names}")
         prompt = self._normalize_prompt(feed)
         plen = int(prompt.size)
         max_new = cfg.max_new_tokens if max_new_tokens is None \
@@ -779,7 +844,8 @@ class DecodeEngine:
                 f"request or grow the pool")
         seq = _Seq(prompt, max_new, eos, on_token,
                    temperature=temperature or 0.0, top_k=top_k or 0,
-                   top_p=top_p or 0.0, seed=seed or 0)
+                   top_p=top_p or 0.0, seed=seed or 0,
+                   return_logits=return_logits)
         with self._cond:
             if self._unhealthy is not None:
                 raise UnavailableError(
@@ -833,10 +899,21 @@ class DecodeEngine:
                 if self._stop and not self._pending \
                         and not self._active and not self._chunking:
                     return
+                if self._abort:
+                    failed = self._drop_all(UnavailableError(
+                        "decode engine shut down with the request in "
+                        "flight"))
+                    with self._stats_lock:
+                        self._failed += failed
+                    return
             if _FL_ARMED:
                 # drill seam: an uncaught decode-worker exception,
                 # outside any per-step recovery
                 _faultline.crossing("serving_decode")
+            while self._lock_wanted:
+                # a stats() reader waits for the gap between two rounds:
+                # do not take the lock back before it has had it
+                time.sleep(2e-4)
             with self._run_lock:
                 n_chunking = len(self._chunking)
                 with self._phase("admit") as span:
@@ -864,27 +941,30 @@ class DecodeEngine:
                      extra={"pending": len(self._pending),
                             "active": len(self._active),
                             "chunking": len(self._chunking)})
-        failed = 0
         with self._cond:
             self._unhealthy = exc
             self._accepting = False
             self._stop = True
-            victims = list(self._active) + list(self._chunking) \
-                + list(self._pending)
-            for seq in self._active + self._chunking:
-                self._release_blocks(seq)
-            self._active = []
-            self._chunking = []
-            self._pending = []
-            for seq in victims:
-                if not seq.future.done():
-                    seq.future.set_exception(UnavailableError(
-                        f"decode engine worker died: {exc!r} — "
-                        f"generation failed (flight bundle dumped)"))
-                    failed += 1
-            self._cond.notify_all()
+            failed = self._drop_all(UnavailableError(
+                f"decode engine worker died: {exc!r} — "
+                f"generation failed (flight bundle dumped)"))
         with self._stats_lock:
             self._failed += failed
+
+    def _drop_all(self, error: BaseException) -> int:
+        """Under ``_cond``: fail every queued and in-flight generation
+        with ``error`` and free their blocks; returns how many failed."""
+        victims = self._active + self._chunking + self._pending
+        for seq in self._active + self._chunking:
+            self._release_blocks(seq)
+        self._active, self._chunking, self._pending = [], [], []
+        failed = 0
+        for seq in victims:
+            if not seq.future.done():
+                seq.future.set_exception(error)
+                failed += 1
+        self._cond.notify_all()
+        return failed
 
     # -- scheduling -------------------------------------------------------
     def _availability(self) -> int:
@@ -1057,7 +1137,8 @@ class DecodeEngine:
         handle the host needs, its ``sync``, under the watchdog.
         Returns (the fetched host array or None, the two phases' ns —
         the worker is synchronous, so that is the executable's device
-        time plus launch latency)."""
+        time plus launch latency).  The launch's fetch handles stay in
+        ``self._handles`` for a request that asked for its logits."""
         # the worker is the phase clock's one writer, so its reads
         # outside the lock see its own last write
         ph = self._phase_ns
@@ -1067,7 +1148,7 @@ class DecodeEngine:
         try:
             with self._phase("dispatch"):
                 self._acquire(prepared)
-                handles = prepared.run(feed)
+                handles = self._handles = prepared.run(feed)
             if fetch is not None:
                 with self._phase("sync"):
                     out = handles[fetch].numpy()
@@ -1089,7 +1170,8 @@ class DecodeEngine:
             with self._phase("emit"):
                 self._first_tokens_out(
                     admitted, [int(tokens[seq._gather_idx])
-                               for seq in admitted])
+                               for seq in admitted],
+                    [seq._gather_idx for seq in admitted])
         self._active.extend(admitted)
         with self._stats_lock:
             self._prefill_batches += 1
@@ -1098,11 +1180,16 @@ class DecodeEngine:
             self._launch_ns["prefill"] += launch_ns
             self._t_last = now
 
-    def _first_tokens_out(self, seqs: List[_Seq], toks: List[int]):
+    def _first_tokens_out(self, seqs: List[_Seq], toks: List[int],
+                          rows: List[int]):
         """The prompt is in the cache and each sequence's first token is
-        on the host: stamp it, stream it, index the prompt's blocks."""
+        on the host: stamp it, stream it, index the prompt's blocks.
+        ``rows``: each sequence's row of the launch's ``next_logits``."""
         now = _now_ns()
-        for seq, tok in zip(seqs, toks):
+        for seq, tok, row in zip(seqs, toks, rows):
+            if seq.logits is not None:
+                seq.logits.append(
+                    (_logits_row(self._handles[0].value, row), 1))
             seq.pos = int(seq.prompt.size)
             seq.t_first_token_ns = now
             self._emit(seq, tok)
@@ -1169,7 +1256,7 @@ class DecodeEngine:
             seq._chunk_off = end
             if final:
                 with self._phase("emit"):
-                    self._first_tokens_out([seq], [int(toks[0])])
+                    self._first_tokens_out([seq], [int(toks[0])], [0])
         with self._stats_lock:
             self._chunk_steps += 1
             if final:
@@ -1286,6 +1373,12 @@ class DecodeEngine:
             now = time.monotonic()
             emitted = 0
             with self._phase("emit"):
+                for i, seq in enumerate(live):
+                    if seq.logits is not None:
+                        # [length, B, V] on the device: this row's steps
+                        seq.logits.append(
+                            (_logits_row(self._handles[1].value, i),
+                             int((tokens[:, i] >= 0).sum())))
                 for s in range(length):
                     for i, seq in enumerate(live):
                         tok = int(tokens[s, i])
@@ -1359,7 +1452,8 @@ class DecodeEngine:
                 timing={"rid": seq.rid, "submit": seq.t_submit_ns,
                         "admit": seq.t_admit_ns,
                         "first_token": seq.t_first_token_ns,
-                        "done": now}))
+                        "done": now},
+                logit_parts=seq.logits))
         with self._stats_lock:
             self._completed += len(finished)
 
@@ -1476,10 +1570,31 @@ class DecodeEngine:
                     self._programs.score,
                     feed_names=self._programs.score_feeds,
                     fetch_list=list(self._programs.fetch_names),
-                    scope=self._ref_scope, donate_state=False)
+                    scope=self._reference_scope(), donate_state=False)
             return self._score.run({
                 "src_ids": src, "pos_ids": pos, "input_mask": mask,
                 "last_pos": last})
+
+    def _reference_scope(self):
+        """The reference loop's weights: fresh device copies (a host copy
+        would re-cross the host link on every scored token) of what the
+        scope holds once the serving side's device-resident state has
+        flowed back into it, taken between two device rounds.  Weights
+        pass through the donated steps unchanged, so the copy is the
+        same whenever it is taken."""
+        if self._ref_scope is None:
+            import jax.numpy as jnp
+            from ..framework.executor import Scope
+            ref = Scope()
+            with self._run_lock:
+                if self._owner is not None:
+                    self._owner.sync_scope()
+                for name in self._scope.var_names():
+                    if name not in self._programs.cache_vars:
+                        ref.set_var(name, jnp.array(
+                            self._scope.find_var(name), copy=True))
+            self._ref_scope = ref
+        return self._ref_scope
 
     def reference_logits(self, tokens) -> np.ndarray:
         """Next-token logits ``[vocab]`` the parity oracle assigns after
@@ -1492,6 +1607,38 @@ class DecodeEngine:
                 f"prefix ({len(seq)} tokens) exceeds "
                 f"max_seq_len={self.config.max_seq_len}")
         return self._score_prefix(seq)[0].numpy()[0]
+
+    @property
+    def scope(self):
+        """The scope the served programs run in (weights, pools,
+        counters).  Read it only while no round runs: before ``start()``
+        or after ``shutdown()`` / ``close()`` has joined the worker."""
+        return self._scope
+
+    def close(self, timeout: float = 60.0) -> bool:
+        """Tear the engine down where it is: the worker stops at its next
+        round boundary, what is queued or in flight fails with
+        ``UnavailableError``, and the pools' and counters' device memory
+        goes back (the weights stay in ``scope``).  False if the worker
+        did not stop in ``timeout``; the engine cannot serve afterwards.
+        ``shutdown()`` is the graceful end: in-flight requests finish
+        and the pools stay."""
+        with self._cond:
+            self._abort = True
+        if not self.shutdown(drain=False, timeout=timeout):
+            return False
+        if self._closed:
+            return True
+        if self._owner is not None:
+            self._owner.sync_scope()
+        for prepared in (self._prefill, self._chunk, *self._chains.values()):
+            if prepared is not None:
+                prepared.close()
+                prepared._state = None
+        for name in self._programs.cache_vars:
+            self._scope.vars.pop(name, None)
+        self._closed = True
+        return True
 
     # -- observability ----------------------------------------------------
     @property
@@ -1506,6 +1653,31 @@ class DecodeEngine:
         return n
 
     def stats(self) -> Dict[str, Any]:
+        if not self._counters:
+            return self._stats()
+        # device counters are read between two rounds; the host's
+        # counters are read in the same gap, so the two agree
+        with self._stats_lock:
+            self._lock_wanted += 1
+        try:
+            with self._run_lock:
+                out = self._stats()
+                if self._owner is not None:
+                    self._owner.sync_scope()
+                for kind, counters in self._counters.items():
+                    for name, pairs in counters.items():
+                        total = np.asarray(self._scope.find_var(name)) \
+                            .astype(np.uint32).astype(np.int64)
+                        for key, reduce in pairs:
+                            by_kind = out.setdefault(key, {})
+                            by_kind[kind] = by_kind.get(kind, 0) \
+                                + reduce(total)
+        finally:
+            with self._stats_lock:
+                self._lock_wanted -= 1
+        return out
+
+    def _stats(self) -> Dict[str, Any]:
         with self._stats_lock:
             elapsed = None
             if self._t_first is not None and self._t_last is not None:

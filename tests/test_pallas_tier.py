@@ -599,6 +599,21 @@ def _paged_decode_attention():
                    grad=False, is_test=True)]
 
 
+def _mla_paged_decode():
+    """A decode step's read of the paged latent cache: one query token a
+    row, 8 heads of 128+128 / v 128 over 256-wide bfloat16 rows."""
+    bf = jnp.bfloat16
+    ins = {"Q": jnp.zeros((2, 1, 8 * 256), bf),
+           "WKVB": jnp.zeros((128, 8 * 256), bf),
+           "Pool": jnp.zeros((8, 16, 256), bf),
+           "BlockTable": jnp.zeros((2, 4), jnp.int32),
+           "CtxLen": jnp.full((2,), 17, jnp.int32)}
+    return [_op_fn("mla_attention", ins,
+                   {"n_head": 8, "nope_dim": 128, "rope_dim": 128,
+                    "v_dim": 128, "scale": 0.1, "_cached": True},
+                   grad=False, is_test=True)]
+
+
 def _grouped_ffn():
     n, d, f, e, k = 256, 128, 128, 2, 2
     ins = {"X": _f32(n, d), "TopkWeight": _f32(n, k),
@@ -652,6 +667,7 @@ ROUTE_CASES = {
     ("fused_attention", "ring_flash_attention"): _ring_attention,
     ("fused_attention", "cached_flash_attention"): _cached_attention,
     ("fused_attention", "paged_decode_attention"): _paged_decode_attention,
+    ("mla_attention", "mla_paged_decode"): _mla_paged_decode,
     ("moe_grouped_ffn", "moe_grouped_matmul"): _grouped_ffn,
     ("layer_norm", "fused_layer_norm"): lambda: _norm("layer_norm"),
     ("fused_add_layernorm", "fused_add_layer_norm"): lambda: _norm(

@@ -219,3 +219,62 @@ def test_paged_decode_attention_compiles_for_v5e(one_chip, no_compile_cache,
     assert f"f32[{batch},512,768]" not in txt
     moved = re.findall(r"= f32\[4096,16,768\]\S* copy\(", txt)
     assert not moved, moved
+
+
+@pytest.mark.parametrize("batch", [128, 32])
+def test_mla_paged_decode_compiles_for_v5e(one_chip, no_compile_cache,
+                                           batch):
+    """The latent serving cell's decode-step attention at its own shapes:
+    ``batch`` rows of 128 heads of 128+64 / v 128 over a pool of 34 688
+    blocks of 16 x 640 bfloat16 (512 + 64 live values a row) through a
+    512-page table, as ``mla_attention`` lowers it for a TPU — query
+    absorption, the paged kernel, the value up-projection.  The pool
+    reaches the kernel in place: no ``[B, T, W]`` gather of the cache
+    exists in the compiled module."""
+    from paddle_tpu.ops.pallas import lowering_target
+    from paddle_tpu.ops.registry import get_op
+
+    def sds(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    attrs = {"n_head": 128, "nope_dim": 128, "rope_dim": 64, "v_dim": 128,
+             "scale": 0.25, "_cached": True}
+
+    def step(q, wkvb, pool, table, ctx):
+        return get_op("mla_attention")(None, {
+            "Q": [q], "WKVB": [wkvb], "Pool": [pool], "BlockTable": [table],
+            "CtxLen": [ctx]}, attrs)["Out"]
+
+    with lowering_target("tpu"):
+        txt = jax.jit(step).lower(
+            sds((batch, 1, 128 * 192)), sds((512, 128 * 256)),
+            sds((34688, 16, 640)), sds((batch, 512), jnp.int32),
+            sds((batch,), jnp.int32)).compile().as_text()
+    assert all(k in txt for k in _route_kernels("mla_paged_decode"))
+    assert f"bf16[{batch},8192,640]" not in txt
+
+
+@pytest.mark.parametrize("tokens", [128, 1024],
+                         ids=["decode-128-rows", "chunk-1024-tokens"])
+def test_wide_expert_grouped_matmul_compiles_for_v5e(
+        one_chip, no_compile_cache, tokens):
+    """DeepSeek-V3's experts (7168 x 2048, 16 held of 256, top-8, bf16) at
+    the serving cell's two sizes: a weight block of 29 MB does not fit
+    VMEM twice, so the products run by column blocks."""
+    from paddle_tpu.ops.decoder_lm_ops import grouped_ffn
+    from paddle_tpu.ops.pallas.grouped_matmul import column_block, row_tile
+    assert column_block(7168, 2048, 2) < 2048
+    tile_m = row_tile(tokens * 8, 256)
+
+    def sds(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def step(x, w, idx, wg, wu, wd):
+        return grouped_ffn(x, w, idx, wg, wu, wd, backend="pallas",
+                           tile_m=tile_m)[0]
+
+    txt = jax.jit(step).lower(
+        sds((tokens, 7168)), sds((tokens, 8), jnp.float32),
+        sds((tokens, 8), jnp.int32), sds((16, 7168, 2048)),
+        sds((16, 7168, 2048)), sds((16, 2048, 7168))).compile().as_text()
+    assert "moe_gmm" in txt
