@@ -26,12 +26,14 @@ checks what comes out by the repo's own means:
   ``engine.greedy_reference``;
 * **leg M** — the pre-norm RoPE/GQA decoder with dropless experts
   (models/decoder_lm.py) at Mellum2-12B-A2.5B's widths: the grouped-head
-  windowed flash kernels against the materialised-scores reference at the
-  window's edge (1 023 / 1 024 / 1 025) and 32 query heads on 4, the
+  windowed flash kernels against the materialised-scores reference (a
+  query block at a time) at the window's edge (1 023 / 1 024 / 1 025),
+  32 query heads on 4 and the timed cell's own 8 192 tokens, the
   grouped matmul kernels against ``lax.ragged_dot`` over ragged groups
   with an empty expert, then one period of the model (3 sliding layers +
   1 full, experts 0-15 of 64, a 24 576-row vocabulary slice, 8 192
-  tokens) through ``exe.prepare(...).run`` under pure-bf16 Adam;
+  tokens: the ``mellum2_train.s8k`` cell's step) through
+  ``exe.prepare(...).run`` under pure-bf16 Adam and a watchdog;
 * **leg L** — the served latent-attention decoder
   (models/latent_decoder.py) at DeepSeek-V3's widths: the paged absorbed
   decode kernel against the EXPANDED attention at the serving cell's
@@ -652,6 +654,37 @@ MELLUM_ROPE = {
     "sliding_attention": {"rope_type": "default", "rope_theta": 500000}}
 
 
+def _gqa_reference_by_blocks(q, k, v, *, n_head, n_kv_head, window, blk):
+    """``flash_gqa.reference`` a query block at a time: the scores of
+    ``blk`` queries against every key, never ``[S, S]`` a head (8.6 GB
+    at the cell's shapes), recomputed in the backward pass."""
+    import jax
+    import jax.numpy as jnp
+    b, s, _ = q.shape
+    d, group = q.shape[-1] // n_head, n_head // n_kv_head
+    kh, vh = (t.reshape(b, s, n_kv_head, d) for t in (k, v))
+    cols = jnp.arange(s)[None, :]
+
+    @jax.checkpoint
+    def block(qb, row0):
+        rows = row0 + jnp.arange(blk)[:, None]
+        ok = cols <= rows
+        if window:
+            ok = ok & (rows - cols < window)
+        scores = jnp.einsum(
+            "bqkgd,btkd->bkgqt", qb.reshape(b, blk, n_kv_head, group, d),
+            kh, preferred_element_type=jnp.float32) / d ** 0.5
+        probs = jax.nn.softmax(jnp.where(ok, scores, -1e30), axis=-1)
+        return jnp.einsum("bkgqt,btkd->bqkgd", probs, vh).reshape(
+            b, blk, n_head * d)
+
+    out = jax.lax.map(
+        lambda a: block(*a),
+        (q.reshape(b, s // blk, blk, -1).swapaxes(0, 1),
+         jnp.arange(0, s, blk)))
+    return out.swapaxes(0, 1).reshape(q.shape)
+
+
 def leg_decoder_lm(S: Sizes, platform: str):
     import jax
     import jax.numpy as jnp
@@ -668,26 +701,41 @@ def leg_decoder_lm(S: Sizes, platform: str):
     def randn(*shape):
         return jnp.asarray(rng.randn(*shape).astype(np.float32))
 
-    # -- attention: the window's edge, then the model's head grouping ----
+    # -- attention: the window's edge, the model's head grouping, then
+    # the timed cell's own shapes (8 192 tokens, 32 heads on 4, bf16) ----
+    f32_bf16 = ((jnp.float32, 2e-2), (jnp.bfloat16, 4e-2))
+    bf16 = f32_bf16[1:]
     if S.dry:
-        seq, d, blk, cases = 32, 16, 8, ((7, 2, 1), (8, 2, 1), (9, 4, 2),
-                                         (None, 4, 2))
+        d, blk, sub = 16, 8, 4
+        cases = ((32, 7, 2, 1, f32_bf16), (32, 8, 2, 1, f32_bf16),
+                 (32, 9, 4, 2, f32_bf16), (32, None, 4, 2, f32_bf16),
+                 (64, 9, 8, 1, bf16), (64, None, 8, 1, bf16))
     else:
-        seq, d, blk, cases = 2048, 128, None, (
-            (1023, 8, 1), (1024, 8, 1), (1025, 8, 1), (1024, 32, 4),
-            (None, 32, 4))
-    for window, h, hkv in cases:
+        d, blk, sub = 128, None, None
+        cases = ((2048, 1023, 8, 1, f32_bf16), (2048, 1024, 8, 1, f32_bf16),
+                 (2048, 1025, 8, 1, f32_bf16), (2048, 1024, 32, 4, f32_bf16),
+                 (2048, None, 32, 4, f32_bf16),
+                 (8192, 1024, 32, 4, bf16), (8192, None, 32, 4, bf16))
+    for seq, window, h, hkv, dtypes in cases:
         args = (randn(1, seq, h * d), randn(1, seq, hkv * d),
                 randn(1, seq, hkv * d))
-        for dtype, tol in ((jnp.float32, 2e-2), (jnp.bfloat16, 4e-2)):
+        for dtype, tol in dtypes:
             kw = dict(n_head=h, n_kv_head=hkv, window=window)
             _check_kernel(
                 f"flash_gqa window={window} heads={h}/{hkv}",
-                lambda q, k, v: fg.flash_gqa_bsd(q, k, v, block=blk,
+                lambda q, k, v: fg.flash_gqa_bsd(q, k, v, block=blk, sub=sub,
                                                  interpret=interp, **kw),
-                lambda q, k, v: fg.reference(
-                    *(t.astype(jnp.float32) for t in (q, k, v)), **kw),
+                lambda q, k, v: _gqa_reference_by_blocks(
+                    *(t.astype(jnp.float32) for t in (q, k, v)),
+                    blk=min(seq, 512), **kw),
                 tuple(t.astype(dtype) for t in args), tol, tol)
+    # how often the sub-tiling engages in the cell's two kinds of layer
+    tile = fg.pick_sub(fg.pick_block(seq, blk), sub)
+    for _, window, *_ in cases[-2:]:
+        plain, masked, skipped = fg.subtile_counts(seq, tile.blk, sub, window)
+        _say(f"  flash_gqa window={window} seq={seq}: {tile.rows} x "
+             f"{tile.lanes} sub-tiles of {tile.blk}-tiles a kernel call and "
+             f"query head: {plain} plain, {masked} masked, {skipped} skipped")
 
     # -- grouped products: ragged groups, one expert empty ---------------
     if S.dry:
@@ -756,19 +804,30 @@ def leg_decoder_lm(S: Sizes, platform: str):
     batch = dl.make_fake_batch(rng, cfg, batch_size=1, seq_len=seq)
     routes0 = _route_counters()
     exe = fluid.Executor(fluid.TPUPlace(0))
-    with fluid.scope_guard(fluid.Scope()):
-        exe.run(startup)
-        prepared = exe.prepare(main, fetch_list=[loss])
-        losses, times = [], []
-        for _ in range(steps):
-            t0 = time.perf_counter()
-            losses.append(float(prepared.run(batch)[0]))
-            times.append(time.perf_counter() - t0)
-        prepared.wait()
-        stats = dict(prepared.stats)
-        mem = jax.devices()[0].memory_stats() or {}
-        prepared.sync_scope()
-        _assert_on_device(fluid.global_scope(), exe._device)
+    # kernels that pass alone can still hang inside the whole step
+    # (PERF.md question 27): the steps run under a watchdog that dumps
+    # every thread's stack and ends the process instead of the chip call
+    faulthandler.dump_traceback_later(600, exit=True)
+    try:
+        with fluid.scope_guard(fluid.Scope()):
+            exe.run(startup)
+            prepared = exe.prepare(main, fetch_list=[loss])
+            losses, times, flat_from = [], [], None
+            for _ in range(steps):
+                t0 = time.perf_counter()
+                losses.append(float(prepared.run(batch)[0]))
+                times.append(time.perf_counter() - t0)
+                if flat_from is None:
+                    flat_from = _compiles()
+            prepared.wait()
+            assert _compiles() == flat_from, \
+                "the decoder LM's step recompiled after its first step"
+            stats = dict(prepared.stats)
+            mem = jax.devices()[0].memory_stats() or {}
+            prepared.sync_scope()
+            _assert_on_device(fluid.global_scope(), exe._device)
+    finally:
+        faulthandler.cancel_dump_traceback_later()
     assert all(np.isfinite(losses)) and losses[-1] < losses[0], losses
     expect = steps * cfg.num_hidden_layers * seq \
         * cfg.num_experts_per_tok * (cfg.held_experts[1]

@@ -324,11 +324,33 @@ def test_grouped_kernels_match_ragged_dot_with_an_empty_expert(tile_m):
 # attention: window and grouped heads
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("seq,h,hkv,d,window,blk", [
-    (32, 4, 2, 16, 8, 8), (32, 4, 2, 16, None, 8), (32, 4, 4, 16, 8, 16),
-    (64, 8, 2, 8, 20, 8), (32, 4, 1, 16, 1, 8), (32, 2, 1, 16, 7, 8),
-    (32, 2, 1, 16, 9, 8)])
-def test_flash_gqa_kernels_in_interpret_mode(seq, h, hkv, d, window, blk):
+def _sub_tiled(window, sub=8, seq=64, h=8, hkv=1, blk=32):
+    """DMA tiles of 32 computed in ``sub`` sub-tiles (8 x 8; a pair:
+    rows x lanes), 8 query heads on one K/V head."""
+    return (seq, h, hkv, 8, window, blk, sub)
+
+
+@pytest.mark.parametrize("seq,h,hkv,d,window,blk,sub", [
+    (32, 4, 2, 16, 8, 8, None), (32, 4, 2, 16, None, 8, None),
+    (32, 4, 4, 16, 8, 16, None), (64, 8, 2, 8, 20, 8, None),
+    (32, 4, 1, 16, 1, 8, None), (32, 2, 1, 16, 7, 8, None),
+    (32, 2, 1, 16, 9, 8, None),
+    # the window's edge inside a sub-tile, on a sub-tile boundary and one
+    # either side of it; on the tile boundary and one either side
+    _sub_tiled(12), _sub_tiled(15), _sub_tiled(16), _sub_tiled(17),
+    _sub_tiled(31), _sub_tiled(32), _sub_tiled(33),
+    # one position, past the sequence, full causal
+    _sub_tiled(1), _sub_tiled(100), _sub_tiled(None),
+    # three tiles to a sequence: a plain tile between the two crossed
+    _sub_tiled(40, seq=96), _sub_tiled(None, seq=96),
+    # sub-tiles that are not square
+    _sub_tiled(17, sub=(16, 8)), _sub_tiled(23, sub=(8, 16)),
+    _sub_tiled(None, sub=(16, 8)),
+    # 1 and 2 query heads a K/V head; 16, which take two steps of 8
+    _sub_tiled(17, h=2, hkv=2), _sub_tiled(17, h=4, hkv=2),
+    _sub_tiled(9, h=16, hkv=1, seq=32, blk=16)])
+def test_flash_gqa_kernels_in_interpret_mode(seq, h, hkv, d, window, blk,
+                                             sub):
     from paddle_tpu.ops.pallas import flash_gqa as fg
     ks = jax.random.split(jax.random.PRNGKey(0), 4)
     q = jax.random.normal(ks[0], (2, seq, h * d))
@@ -341,7 +363,7 @@ def test_flash_gqa_kernels_in_interpret_mode(seq, h, hkv, d, window, blk):
         return jax.value_and_grad(
             lambda *a: jnp.sum(fn(*a, **kw, **extra) * g), (0, 1, 2))(
                 q, k, v)
-    l1, g1 = both(fg.flash_gqa_bsd, block=blk, interpret=True)
+    l1, g1 = both(fg.flash_gqa_bsd, block=blk, sub=sub, interpret=True)
     l2, g2 = both(fg.reference)
     assert abs(float(l1) - float(l2)) < 1e-3
     for a, b in zip(g1, g2):
@@ -355,6 +377,56 @@ def test_window_blocks_are_skipped_not_masked():
     assert fg.grid_steps(8192, 512, 1024) == 3
     assert fg.grid_steps(8192, 512, 0) == 16
     assert fg.grid_steps(32, 8, 9) == 2 and fg.grid_steps(32, 8, 10) == 3
+
+
+@pytest.mark.parametrize("seq,blk,sub,window", [
+    (8192, 512, 128, 1024), (8192, 512, 128, 0),
+    (8192, 512, None, 1024), (8192, 512, None, 0),
+    (64, 32, 8, 17), (96, 32, (16, 8), 40), (64, 16, 4, 100),
+    (32, 8, 8, 9)])
+def test_subtile_counts_match_the_mask(seq, blk, sub, window):
+    """``subtile_counts`` reads the plan the kernel bodies are unrolled
+    from; here every sub-tile of every tile is looked up in the mask
+    itself: nothing visible lies in a skipped one or outside the grid's
+    tiles, nothing hidden in a plain one."""
+    from paddle_tpu.ops.pallas import flash_gqa as fg
+    _, rows, lanes = fg.pick_sub(blk, sub)
+    i, j = np.arange(seq)[:, None], np.arange(seq)[None, :]
+    ok = (j <= i) & ((i - j < window) if window else True)
+    tiles = ok.reshape(seq // blk, blk, seq // blk, blk).swapaxes(1, 2)
+    want = dict(plain=0, masked=0, skipped=0)
+    steps = fg.grid_steps(seq, blk, window)
+    for qi in range(seq // blk):
+        for kj in range(seq // blk):
+            tile = tiles[qi, kj]
+            if not 0 <= qi - kj < steps:
+                assert not tile.any()
+                continue
+            subs = tile.reshape(blk // rows, rows, blk // lanes,
+                                lanes).swapaxes(1, 2).reshape(-1, rows * lanes)
+            full, some = subs.all(axis=1), subs.any(axis=1)
+            want["plain"] += int(full.sum())
+            want["masked"] += int((some & ~full).sum())
+            want["skipped"] += int((~some).sum())
+    assert fg.subtile_counts(seq, blk, sub, window) == tuple(want.values())
+
+
+def test_subtile_counts_at_the_cell_shapes():
+    """Mellum2's attention at the timed size, a kernel call and query
+    head.  In 128 x 128 sub-tiles a query block's 48 are 28 plain, 8
+    masked, 12 skipped under the window; in what ``pick_sub`` chooses,
+    256 x 128 (rows of 128 ran slower: PERF.md, PR 35), its 24 are 12,
+    8 and 4.  Whole tiles skip nothing."""
+    from paddle_tpu.ops.pallas import flash_gqa as fg
+    assert fg.pick_sub(fg.pick_block(8192)) == (512, 256, 128)
+    assert fg.pick_sub(fg.pick_block(384)) == (128, 128, 128)
+    assert fg.subtile_counts(8192, 512, 128, 1024) == (420, 120, 180)
+    assert fg.subtile_counts(8192, 512, 128, 0) == (2016, 64, 96)
+    assert fg.subtile_counts(8192, 512, None, 1024) == (180, 120, 60)
+    assert fg.subtile_counts(8192, 512, None, 0) == (992, 64, 32)
+    assert fg.subtile_counts(8192, 512, 512, 1024) == (15, 30, 0)
+    far = [fg.subtile_counts(512 * n, 512, 128, 1024) for n in (15, 16)]
+    assert tuple(b - a for a, b in zip(*far)) == (28, 8, 12)
 
 
 def test_fused_attention_takes_window_and_kv_heads_by_attrs():
