@@ -45,6 +45,20 @@ checks what comes out by the repo's own means:
   chunk program and its 128-row chains under a watchdog — serving four
   prompts of 1 024-4 096 tokens whose logits are held to the reference's
   full forward pass;
+* **leg H** — the served hybrid decoder (models/hybrid_decoder.py) at
+  Olmo-Hybrid-7B's widths: the paged decode attention over bfloat16 K/V
+  pools at 30 heads of 128 (64 rows, contexts 1 / 16 / 17 / 4 095 /
+  12 288, NaN in every slot no context owns) against the gathered rows;
+  the recurrent delta-rule kernel (``gdn_decode``, 16 rows) carried through
+  1 024 tokens inside one ``lax.scan`` against the float32 recurrence — the
+  state itself is compared, beside the same scan with the state rounded
+  to bfloat16 after every token —
+  and the chunked kernel (``gdn_chunk``) on a 1 024-token chunk against
+  the reference's token scan; then the ``olmo_hybrid_serve.doc_closed``
+  cell's own engine — its K/V pool, its state pool, its chunk program and
+  its 64-row chains with both kernels inside, under a watchdog — serving
+  four prompts of 1 024-12 288 tokens whose logits are held to the
+  reference's full forward pass on the committed limits;
 * **leg C** — four chips (run when >= 4 devices are visible): Fleet dp4
   with the bucketed grad all-reduce at per-chip batch 96, dp4-vs-one-chip
   loss parity, one dp2 x tp2 step, one ZeRO-1 flat-shard-Adam step.
@@ -71,7 +85,7 @@ import re
 import sys
 import time
 
-LEGS = ("K", "A", "B", "M", "L", "C")
+LEGS = ("K", "A", "B", "M", "L", "H", "C")
 
 
 def _say(msg):
@@ -1183,6 +1197,265 @@ def leg_latent(S: Sizes, platform: str):
 
 
 # ---------------------------------------------------------------------------
+# leg H — the served hybrid linear / full attention decoder
+# ---------------------------------------------------------------------------
+
+
+def _hybrid_kernels(S: Sizes, cfg, rng):
+    """Leg H's three kernels alone, at the cell's shapes."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from benchmark.reference import olmo_hybrid_jnp as ref
+    from paddle_tpu.ops import linear_attn_ops as la
+    from paddle_tpu.ops.pallas import gated_delta as gd
+    from paddle_tpu.ops.pallas import paged_attention as pa
+    interpret = gd.pltpu.InterpretParams() if S.dry else False
+
+    # -- the paged read of bfloat16 K/V pools ------------------------------
+    if S.dry:
+        rows, ctxs, pages, bs, n_head, hidden = 8, (1, 16, 17, 47, 64), 4, \
+            16, 2, 256
+    else:
+        rows, ctxs, pages, bs, n_head, hidden = 64, (1, 16, 17, 4095,
+                                                     12288), 1024, 16, \
+            cfg.num_attention_heads, cfg.hidden_size
+    ctx = np.array([ctxs[i % len(ctxs)] for i in range(rows)], np.int32)
+    need = -(-ctx // bs)
+    nb = int(need.sum()) + 1
+    table = np.zeros((rows, pages), np.int32)
+    order = rng.permutation(nb - 1) + 1
+    at = 0
+    for i, n in enumerate(need):
+        table[i, :n] = order[at:at + n]
+        at += n
+    owned = np.zeros((nb * bs,), bool)
+    for i in range(rows):
+        flat = (table[i, :need[i]][:, None] * bs
+                + np.arange(bs)[None]).reshape(-1)
+        owned[flat[:ctx[i]]] = True
+    pools = []
+    for _ in range(2):
+        pool = rng.randn(nb * bs, hidden).astype(np.float32)
+        pool[~owned] = np.nan
+        pools.append(jnp.asarray(pool.reshape(nb, bs, hidden), jnp.bfloat16))
+    q = jnp.asarray(rng.randn(rows, 1, hidden), jnp.bfloat16)
+    got = np.asarray(jax.jit(lambda *a: pa.paged_decode_attention(
+        *a, n_head=n_head, interpret=interpret))(
+            q, *pools, jnp.asarray(table), jnp.asarray(ctx)), np.float32)
+    assert np.isfinite(got).all()
+    worst = 0.0
+    d = hidden // n_head
+    with jax.default_matmul_precision("highest"):
+        for i in range(len(ctxs)):            # one row of each context
+            flat = (table[i, :need[i]][:, None] * bs
+                    + np.arange(bs)[None]).reshape(-1)[:ctx[i]]
+            k, v = (p.reshape(-1, hidden)[flat].astype(jnp.float32)
+                    .reshape(-1, n_head, d) for p in pools)
+            sc = jnp.einsum("hd,thd->ht", q[i, 0].astype(jnp.float32)
+                            .reshape(n_head, d), k) * d ** -0.5
+            want = jnp.einsum("ht,thd->hd", jax.nn.softmax(sc, -1), v)
+            worst = max(worst, _rel(got[i, 0], np.asarray(want).reshape(-1)))
+    _say(f"  paged_decode_attn {rows} rows x {n_head} heads of {d} over "
+         f"{nb} bf16 blocks of {bs} x {hidden}, contexts {ctxs}, NaN "
+         f"outside them: rel err {worst:.2e} against the gathered rows")
+    assert worst < 2e-2, worst
+
+    # -- the two delta-rule kernels alone ----------------------------------
+    h, dk, dv = cfg.linear_num_key_heads, cfg.linear_key_head_dim, \
+        cfg.linear_value_head_dim
+    b, steps = (2, 24) if S.dry else (16, 1024)
+
+    def f32(*shape):
+        return jnp.asarray(rng.randn(*shape), jnp.float32)
+
+    q, k = la.l2_normalize(f32(steps, b, h, dk)) * dk ** -0.5, \
+        la.l2_normalize(f32(steps, b, h, dk) + 0.3)
+    v = f32(steps, b, h, dv)
+    alpha = jnp.exp(-jnp.asarray(rng.uniform(1e-3, 1.6, (steps, b, h)),
+                                 jnp.float32))
+    beta = jnp.asarray(rng.uniform(0, 2, (steps, b, h)), jnp.float32)
+    slot = jnp.asarray(rng.permutation(b + 1)[:b], jnp.int32)
+    pool0 = jnp.zeros((b + 1, h, dk, dv), jnp.float32)
+
+    @jax.jit
+    def by_kernel(pool, xs):
+        def step(pool, xs):
+            o, pool = gd.gdn_decode(*xs, pool, slot, interpret=interpret)
+            return pool, o
+        return jax.lax.scan(step, pool, xs)
+
+    @jax.jit
+    def by_recurrence(state, xs):
+        with jax.default_matmul_precision("highest"):
+            return jax.lax.scan(
+                lambda s, xs: la.recurrent_step(s, *xs)[::-1], state, xs)
+    t0 = time.perf_counter()
+    pool, o_k = by_kernel(pool0, (q, k, v, alpha, beta))
+    jax.block_until_ready(pool)
+    t_k = time.perf_counter() - t0
+    zero = jnp.zeros((b, h, dk, dv), jnp.float32)
+    state, o_r = by_recurrence(zero, (q, k, v, alpha, beta))
+
+    @jax.jit
+    def coarse(state, xs):
+        """The control: the state rounded to bfloat16 after every token."""
+        def step(s, xs):
+            o, s = la.recurrent_step(s, *xs)
+            return ref.round_to_bfloat16(s), o
+        with jax.default_matmul_precision("highest"):
+            return jax.lax.scan(step, state, xs)[0]
+    err_o = _rel(np.asarray(o_k), np.asarray(o_r))
+    err_s = _rel(np.asarray(pool)[np.asarray(slot)], np.asarray(state))
+    err_bf = _rel(np.asarray(coarse(zero, (q, k, v, alpha, beta))),
+                  np.asarray(state))
+    _say(f"  gdn_decode {b} rows x {h} heads of {dk} x {dv}, {steps} "
+         f"tokens in one scan ({t_k:.2f} s with its compile): outputs rel "
+         f"err {err_o:.2e}, the STATE after {steps} tokens {err_s:.2e} "
+         f"against the float32 recurrence; a state rounded to bfloat16 "
+         f"after every token reads {err_bf:.2e}")
+    # the control is real only in integer arithmetic: on the chip XLA drops
+    # a float32 -> bfloat16 -> float32 pair of converts (excess precision)
+    assert err_o < 1e-4 and err_s < 1e-4 < err_bf / 4, (err_o, err_s, err_bf)
+
+    tokens = 100 if S.dry else 1024
+    ins = {"Q": f32(1, tokens, h * dk), "K": f32(1, tokens, h * dk) + 0.3,
+           "V": f32(1, tokens, h * dv), "A": f32(1, tokens, h),
+           "B": f32(1, tokens, h),
+           "ALog": jnp.log(jnp.asarray(rng.uniform(1, 16, h), jnp.float32)),
+           "DtBias": f32(h) - 3.0}
+    prep = la._prepare({n: [t] for n, t in ins.items()},
+                       {"n_head": h, "beta_scale": 2.0})
+    pad = -tokens % gd.SUB_CHUNK
+    parts = la.wy_transform(*(la._pad_time(t, pad) for t in prep))
+    o_c, pool_c = jax.jit(lambda *a: gd.gdn_chunk(*a, interpret=interpret))(
+        *parts, pool0[:2], jnp.array([1], jnp.int32),
+        jnp.array([1], jnp.int32))
+    qh, kh, vh, g, bt = prep
+    with jax.default_matmul_precision("highest"):
+        want = jax.jit(lambda *a: ref.delta_rule(*a, frozenset(), 1 << 30))(
+            qh[0].transpose(1, 0, 2), kh[0].transpose(1, 0, 2),
+            vh[0].transpose(1, 0, 2), jnp.exp(g[0]).T, bt[0].T)
+    got = np.asarray(o_c).reshape(h, tokens + pad, dv)[:, :tokens]
+    err_c = _rel(got, np.asarray(want).transpose(1, 0, 2))
+    _say(f"  gdn_chunk {tokens} tokens x {h} heads in sub-chunks of "
+         f"{gd.SUB_CHUNK}: rel err {err_c:.2e} against the token scan")
+    assert err_c < 1e-3, err_c
+
+
+def _stated_precision(ref_cfg, m, weights, prompt, result):
+    """Relative L2 over one request's served rows: the float32 reference
+    with its residual stream, then with every activation, kept in
+    bfloat16 (``olmo_hybrid_jnp.ROUNDINGS``) off the float32 reference,
+    and the served logits off each."""
+    import numpy as np
+    from benchmark.reference import olmo_hybrid_jnp as ref
+    plen, n = int(prompt.size), int(result.tokens.size)
+    pad_to = ref_cfg["pad_to"]
+    seq = np.zeros(-(-(plen + n) // pad_to) * pad_to, np.int64)
+    seq[:plen], seq[plen:plen + n] = prompt, result.tokens
+
+    def logits(*wrong):
+        return np.asarray(ref.logits(
+            weights, seq, m, layer_prefix=ref_cfg["layer_prefix"],
+            wrong=wrong, q_block=ref_cfg["q_block"], chunk=ref_cfg["chunk"],
+            rows=(plen - 1, plen - 1 + n)))
+
+    def off(a, b):
+        return float(np.linalg.norm(a - b) / np.linalg.norm(b))
+    exact = logits()
+    served = np.asarray(result.logits, np.float32)
+    out = {"prompt": plen, "tokens": n, "served_off_reference": off(served,
+                                                                    exact)}
+    for name in ref.ROUNDINGS:
+        rounded = logits(name)
+        out[name] = {"off_reference": off(rounded, exact),
+                     "served_off_it": off(served, rounded)}
+    return out
+
+
+def leg_hybrid(S: Sizes, platform: str):
+    import gc
+    import numpy as np
+    from benchmark.builders import serve_hybrid
+    from benchmark.run import apply_rehearsal, load_manifest, resolve_cell
+
+    resolved = resolve_cell(load_manifest(), "olmo_hybrid_serve.doc_closed")
+    config = resolved["config"]
+    if S.dry:
+        apply_rehearsal(config, resolved["traffic"])
+    cfg = serve_hybrid.decoder_config(config)
+    rng = np.random.RandomState(0)
+    # a function of their own: the kernels' operands (gigabytes) are gone
+    # when it returns, and the engine below fills the chip
+    _hybrid_kernels(S, cfg, rng)
+    gc.collect()
+
+    # -- the cell's engine ---------------------------------------------------
+    routes0 = _route_counters()
+    t0 = time.perf_counter()
+    engine = serve_hybrid.build_engine(config, seed=7)
+    t_build = time.perf_counter() - t0
+    faulthandler.dump_traceback_later(1800, exit=True)
+    try:
+        assert engine._exe._device.platform == platform
+        n_warm = engine.warmup()
+        t_warm = time.perf_counter() - t0
+        assert n_warm == engine.config.executable_grid
+        flat_from = _compiles()
+        tr = resolved["traffic"]["prompt"]
+        m = serve_hybrid.reference_model(config)
+        plens = np.linspace(tr["min"], tr["max"], 4).astype(int)
+        prompts = [rng.randint(0, m["vocab_size"], (n,)).astype(np.int64)
+                   for n in plens]
+        max_new = 6 if S.dry else 128
+        engine.start()
+        t0 = time.perf_counter()
+        results = [f.result(timeout=1500) for f in [
+            engine.generate({"src_ids": p}, max_new_tokens=max_new,
+                            return_logits=True) for p in prompts]]
+        t_gen = time.perf_counter() - t0
+        assert _compiles() == flat_from, "the engine compiled after warmup()"
+        st = engine.stats()
+        assert st["completed"] == 4 and not st["failed"], st
+        assert st["chunk_steps"] >= 4 and st["chain_hist"], st
+        assert st["state_slots_peak"] == 4 and not st["state_slots_in_use"]
+        _say(f"  engine: built in {t_build:.1f} s (programs, startup, "
+             f"pools), {n_warm} executables warm in {t_warm:.1f} s, 4 "
+             f"prompts of {plens.tolist()} tokens x {max_new} new in "
+             f"{t_gen:.2f} s (smoke timings); chunk_steps "
+             f"{st['chunk_steps']}, chain_hist {st['chain_hist']}, state "
+             f"rows launched / live {st['state_rows_launched']} / "
+             f"{st['state_rows_live']}")
+        weights = serve_hybrid.close_and_take_weights(engine)
+        readings = [serve_hybrid.compare(config["reference"], m, weights, p,
+                                         r.tokens, r.logits)
+                    for p, r in zip(prompts, results)]
+        verdict = serve_hybrid.judge(config["reference"], readings)
+        _say(f"  logits against the reference's full forward pass: "
+             f"{json.dumps(verdict)}")
+        assert verdict["ok"], readings
+        # where the distance comes from, at THESE widths: the f32 reference
+        # with the stated bfloat16 applied to its own activations, against
+        # itself and against the served logits of the shortest request
+        floor = _stated_precision(config["reference"], m, weights,
+                                  prompts[0], results[0])
+        _say(f"  the reference in the stated precision: {json.dumps(floor)}")
+        # a rounding is real (integer arithmetic: XLA drops a pair of
+        # converts on the chip) and of bfloat16's order
+        assert all(1e-3 < floor[r]["off_reference"] < 0.2
+                   for r in ("residual_bfloat16", "activations_bfloat16")), \
+            floor
+    finally:
+        faulthandler.cancel_dump_traceback_later()
+        engine.close(timeout=5.0)
+    _check_routes(
+        _routes_since(routes0), S,
+        want_hits=("paged_decode_attention", "gdn_decode", "gdn_chunk"),
+        allowed_fallbacks=())
+
+
+# ---------------------------------------------------------------------------
 # leg C — four chips
 # ---------------------------------------------------------------------------
 
@@ -1358,7 +1631,7 @@ def main(argv=None):
                     help="tiny width on the CPU backend, to debug this "
                          "script; proves nothing about the chip")
     ap.add_argument("--legs", default=",".join(LEGS),
-                    help="comma-separated subset of K,A,B,M,L,C")
+                    help="comma-separated subset of K,A,B,M,L,H,C")
     args = ap.parse_args(argv)
     legs = [x.strip().upper() for x in args.legs.split(",") if x.strip()]
     if not legs or set(legs) - set(LEGS):
@@ -1408,6 +1681,7 @@ def main(argv=None):
            "B": lambda: leg_server(S, device["platform"]),
            "M": lambda: leg_decoder_lm(S, device["platform"]),
            "L": lambda: leg_latent(S, device["platform"]),
+           "H": lambda: leg_hybrid(S, device["platform"]),
            "C": lambda: leg_four_chips(S, device["platform"])}
     summary = []
     t_all = time.perf_counter()

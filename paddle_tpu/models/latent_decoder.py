@@ -10,7 +10,9 @@ layer ``rms`` and an untied head with float32 logits.
 
 What the engine gets is the same :class:`models.decoder.DecoderPrograms`
 ``BertDecoder.build`` returns (prefill, decode, chains, chunk, score,
-startup), over one parameter set:
+startup), over one parameter set, built by the scaffold of
+models/decoder_programs.py from this file's layer stack, head and cache
+description:
 
 * the cache is ONE pool a layer: a block holds ``[block_size, W]``
   bfloat16 rows ``[c_kv after its norm | k_rope after its rotation |
@@ -42,11 +44,11 @@ from dataclasses import dataclass, field
 from typing import List, Optional, Tuple
 
 from .. import layers
-from ..framework.core import Program, program_guard
 from ..framework.initializer import (NormalInitializer,
                                      TruncatedNormalInitializer)
 from ..framework.layer_helper import LayerHelper, ParamAttr
-from .decoder import DecoderPrograms, _gather_last, _mask_bias
+from .decoder import DecoderPrograms
+from .decoder_programs import CacheFeeds, build_decoder_programs
 
 LANES = 128
 
@@ -143,15 +145,6 @@ class LatentDecoderConfig:
                 / self.mscale("mscale_all_dim")}
 
 
-class _Cache:
-    """Per-build cache wiring: the latent pools of the CURRENT program
-    plus the slot / table / length feeds the cache ops read."""
-
-    def __init__(self, pools, slots, table=None, ctx_len=None, q_pos=None):
-        self.pools, self.slots = pools, slots
-        self.table, self.ctx_len, self.q_pos = table, ctx_len, q_pos
-
-
 def _attr(name, cfg):
     return ParamAttr(name=name, initializer=TruncatedNormalInitializer(
         0.0, cfg.initializer_range))
@@ -170,7 +163,7 @@ def _swiglu(x, width, p, cfg):
 
 
 def _mla(x, pos, cfg: LatentDecoderConfig, p: str,
-         cache: Optional[_Cache], layer_idx: int, attn_bias):
+         cache: Optional[CacheFeeds], layer_idx: int, attn_bias):
     """Latent attention of one layer: the projections, the latent row
     (written to the cache when there is one) and ``mla_attention`` in the
     form the cache wiring selects."""
@@ -223,7 +216,7 @@ def _mla(x, pos, cfg: LatentDecoderConfig, p: str,
 
 
 def decoder_layer(x, pos, cfg: LatentDecoderConfig, p: str, index: int,
-                  cache: Optional[_Cache], attn_bias, counter_tag):
+                  cache: Optional[CacheFeeds], attn_bias, counter_tag):
     eps = cfg.rms_norm_eps
     x = x + _mla(layers.rms_norm(x, eps,
                                  ParamAttr(name=f"{p}_attn_norm_scale")),
@@ -313,20 +306,17 @@ class LatentDecoder:
                 f"held={cfg.held_experts}/V={cfg.vocab_size}"
                 f"/dtype={cfg.dtype}/bs={block_size}")
 
-    def _declare_pools(self, block, num_blocks, block_size):
+    # -- what models/decoder_programs.py builds from ----------------------
+    def declare_cache(self, block, num_blocks, block_size, state_slots=0):
         return [block.create_var(
             name=n, shape=(num_blocks, block_size, self.cfg.latent_width),
             dtype=self.cfg.dtype, persistable=True)
             for n in self.pool_var_names()]
 
-    # -- program builders -------------------------------------------------
-    def _program(self):
-        main = Program()
-        main.random_seed = self.seed
-        main._is_test = True
-        return main
+    def cache_vars(self, kinds) -> List[str]:
+        return self.pool_var_names() + self.counter_var_names(kinds)
 
-    def _body(self, ids, pos2d, cache, attn_bias, tag, lift_1d=False):
+    def body(self, ids, pos2d, cache, attn_bias, tag, lift_1d=False):
         cfg = self.cfg
         x = layers.embedding(ids, size=[cfg.vocab_size, cfg.hidden_size],
                              dtype=cfg.dtype,
@@ -338,161 +328,16 @@ class LatentDecoder:
                               cache, attn_bias, tag)
         return x
 
-    @staticmethod
-    def _data(name, shape, dtype):
-        return layers.data(name, shape=shape, dtype=dtype,
-                           append_batch_size=False)
-
-    def _build_prefill(self, startup, num_blocks, block_size,
-                       pack_max_segments, score_only=False):
-        main = self._program()
-        k = 1 if score_only else pack_max_segments
-        with program_guard(main, startup):
-            src = self._data("src_ids", [-1, -1], "int64")
-            pos = self._data("pos_ids", [-1, -1], "int64")
-            mask = self._data("input_mask", [-1, -1, k], "float32")
-            last_pos = self._data("last_pos", [-1, k], "int64")
-            cache = None
-            if not score_only:
-                slots = self._data("slot_ids", [-1, -1], "int32")
-                cache = _Cache(self._declare_pools(
-                    main.global_block(), num_blocks, block_size), slots)
-            x = self._body(src, pos, cache, _mask_bias(mask),
-                           False if score_only else "prefill")
-            _lm_head(_gather_last(x, last_pos, self.cfg), self.cfg)
-        feeds = ["src_ids", "pos_ids", "input_mask", "last_pos"]
-        return main, feeds + ([] if score_only else ["slot_ids"])
-
-    def _decode_feeds(self, main, num_blocks, block_size,
-                      max_blocks_per_seq):
-        tok = self._data("token_ids", [-1], "int64")
-        pos = self._data("pos_ids", [-1], "int64")
-        slots = self._data("slot_ids", [-1, 1], "int32")
-        table = self._data("block_table", [-1, max_blocks_per_seq], "int32")
-        ctx_len = self._data("ctx_len", [-1], "int32")
-        cache = _Cache(self._declare_pools(main.global_block(), num_blocks,
-                                           block_size), slots, table, ctx_len)
-        return tok, pos, slots, table, ctx_len, cache
-
-    def _decode_body(self, tok, pos, cache, tag):
-        x = self._body(tok, layers.unsqueeze(pos, axes=[1]), cache, None,
-                       tag, lift_1d=True)
-        return _lm_head(layers.reshape(x, [-1, self.cfg.hidden_size]),
-                        self.cfg)
-
-    def _build_decode(self, startup, num_blocks, block_size,
-                      max_blocks_per_seq):
-        main = self._program()
-        with program_guard(main, startup):
-            tok, pos, _, _, _, cache = self._decode_feeds(
-                main, num_blocks, block_size, max_blocks_per_seq)
-            self._decode_body(tok, pos, cache, "chain")
-        return main, ["token_ids", "pos_ids", "slot_ids", "block_table",
-                      "ctx_len"]
-
-    def _build_chain(self, startup, num_blocks, block_size,
-                     max_blocks_per_seq, chain_length, with_sampling):
-        """The decode-step network plus the trailing ``decode_chain``
-        marker (executor.lower_decode_chain), which here also stacks
-        every step's logits (``chain_logits`` [chain, B, V])."""
-        main = self._program()
-        with program_guard(main, startup):
-            tok, pos, slots, table, ctx_len, cache = self._decode_feeds(
-                main, num_blocks, block_size, max_blocks_per_seq)
-            steps_left = self._data("steps_left", [-1], "int32")
-            eos_ids = self._data("eos_ids", [-1], "int64")
-            sample = {}
-            if with_sampling:
-                sample = {"Temperature": self._data("temperature", [-1],
-                                                    "float32"),
-                          "TopK": self._data("top_k", [-1], "int32"),
-                          "TopP": self._data("top_p", [-1], "float32"),
-                          "Seeds": self._data("seeds", [-1], "int32")}
-            logits, tokens = self._decode_body(tok, pos, cache, "chain")
-            block = main.global_block()
-            out = block.create_var(name="chain_tokens",
-                                   shape=(chain_length, -1), dtype="int64")
-            out_logits = block.create_var(
-                name="chain_logits",
-                shape=(chain_length, -1, self.cfg.vocab_size),
-                dtype="float32")
-            inputs = {"TokenIds": [tok], "PosIds": [pos],
-                      "SlotIds": [slots], "BlockTable": [table],
-                      "CtxLen": [ctx_len], "StepsLeft": [steps_left],
-                      "EosIds": [eos_ids], "Logits": [logits],
-                      "Tokens": [tokens]}
-            inputs.update({k: [v] for k, v in sample.items()})
-            LayerHelper("decode_chain").append_op(
-                type="decode_chain", inputs=inputs,
-                outputs={"Out": [out], "LogitsOut": [out_logits]},
-                attrs={"chain_length": chain_length,
-                       "block_size": block_size,
-                       "with_sampling": bool(with_sampling)})
-        feeds = ["token_ids", "pos_ids", "slot_ids", "block_table",
-                 "ctx_len", "steps_left", "eos_ids"]
-        if with_sampling:
-            feeds += ["temperature", "top_k", "top_p", "seeds"]
-        return main, feeds
-
-    def _build_chunk(self, startup, num_blocks, block_size,
-                     max_blocks_per_seq):
-        """Chunked prefill: a ``[B, C]`` prompt slice that WRITES its
-        latents into the pool and READS attention through the block
-        table, absolute ``pos_ids`` doubling as the causal bound."""
-        main = self._program()
-        with program_guard(main, startup):
-            src = self._data("src_ids", [-1, -1], "int64")
-            pos = self._data("pos_ids", [-1, -1], "int64")
-            slots = self._data("slot_ids", [-1, -1], "int32")
-            table = self._data("block_table", [-1, max_blocks_per_seq],
-                               "int32")
-            ctx_len = self._data("ctx_len", [-1], "int32")
-            last_pos = self._data("last_pos", [-1, 1], "int64")
-            cache = _Cache(self._declare_pools(
-                main.global_block(), num_blocks, block_size), slots, table,
-                ctx_len, q_pos=pos)
-            x = self._body(src, pos, cache, None, "chunk")
-            _lm_head(_gather_last(x, last_pos, self.cfg), self.cfg)
-        return main, ["src_ids", "pos_ids", "slot_ids", "block_table",
-                      "ctx_len", "last_pos"]
+    def head(self, h2d):
+        return _lm_head(h2d, self.cfg)
 
     def build(self, num_blocks: int, block_size: int,
               max_blocks_per_seq: int, pack_max_segments: int = 1,
               chain_lengths: tuple = (), with_sampling: bool = False,
               chunk_tokens: Optional[int] = None) -> DecoderPrograms:
-        from ..framework import unique_name
-        startup = Program()
-        startup.random_seed = self.seed
-        with unique_name.guard(f"{self.name}@"):
-            prefill, prefill_feeds = self._build_prefill(
-                startup, num_blocks, block_size, pack_max_segments)
-            # the other builds re-declare the same parameters; their
-            # initializer ops go to throwaway startups (their counters
-            # are engine state, zeroed with the pools)
-            decode, decode_feeds = self._build_decode(
-                Program(), num_blocks, block_size, max_blocks_per_seq)
-            score, score_feeds = self._build_prefill(
-                Program(), num_blocks, block_size, 1, score_only=True)
-            chains, chain_feeds = {}, []
-            for length in chain_lengths:
-                chains[int(length)], chain_feeds = self._build_chain(
-                    Program(), num_blocks, block_size, max_blocks_per_seq,
-                    int(length), with_sampling)
-            chunk, chunk_feeds = None, []
-            kinds = ["prefill", "chain"]
-            if chunk_tokens:
-                chunk, chunk_feeds = self._build_chunk(
-                    Program(), num_blocks, block_size, max_blocks_per_seq)
-                kinds.append("chunk")
-        return DecoderPrograms(
-            prefill=prefill, decode=decode, score=score, startup=startup,
-            cache_vars=self.pool_var_names()
-            + self.counter_var_names(kinds),
-            prefill_feeds=prefill_feeds, decode_feeds=decode_feeds,
-            score_feeds=score_feeds, chains=chains,
-            chain_feeds=chain_feeds,
-            chain_fetch_names=["chain_tokens", "chain_logits"],
-            chunk=chunk, chunk_feeds=chunk_feeds)
+        return build_decoder_programs(
+            self, num_blocks, block_size, max_blocks_per_seq,
+            pack_max_segments, chain_lengths, with_sampling, chunk_tokens)
 
 
 __all__ = ["LatentDecoder", "LatentDecoderConfig"]

@@ -24,6 +24,7 @@ from . import tp_ops        # noqa: F401
 from . import moe_ops       # noqa: F401
 from . import decoder_lm_ops  # noqa: F401
 from . import mla_ops        # noqa: F401
+from . import linear_attn_ops  # noqa: F401
 from . import breadth_ops   # noqa: F401
 from . import breadth2_ops  # noqa: F401
 from . import crf_ops       # noqa: F401

@@ -1056,6 +1056,97 @@ def _lower_mla_paged_decode(ctx, ins, attrs):
     return lower_mla_paged_decode(ctx, ins, attrs)
 
 
+def _infer_gated_delta_rule(ins, attrs):
+    """Out [B, S, n_head * d_v] in Q's dtype; Q / K hold ``n_head`` key
+    heads, V ``n_head`` value heads; a StatePool is [slots, n_head, d_k,
+    d_v] float32 and aliases its output."""
+    q, v = _sig(ins, "Q"), _sig(ins, "V")
+    if q is None or v is None or q.shape is None or v.shape is None:
+        return None
+    h = int(attrs.get("n_head", 0))
+    for sig, nm in ((q, "Q"), (_sig(ins, "K"), "K"), (v, "V")):
+        if sig is not None and sig.shape is not None \
+                and sig.shape[-1] >= 0 and (h <= 0 or sig.shape[-1] % h):
+            raise SpecMismatch(
+                f"gated_delta_rule: {nm} width {sig.shape[-1]} does not "
+                f"divide over {h} heads", kind="shape")
+    out = {"Out": [VarSig(tuple(q.shape[:-1]) + (v.shape[-1],), q.dtype)]}
+    pool = _sig(ins, "StatePool")
+    if pool is not None:
+        want = (h, q.shape[-1] // h, v.shape[-1] // h)
+        if pool.shape is not None and len(pool.shape) == 4 and \
+                min(want) >= 0 and tuple(pool.shape[1:]) != want:
+            raise SpecMismatch(
+                f"gated_delta_rule: StatePool {list(pool.shape)} does not "
+                f"hold [slots, heads, d_k, d_v] = [*, {want[0]}, "
+                f"{want[1]}, {want[2]}]", kind="shape")
+        out["StatePoolOut"] = [VarSig(pool.shape, pool.dtype)]
+    return out
+
+
+def _infer_causal_conv1d(ins, attrs):
+    xv, w, pool = _sig(ins, "X"), _sig(ins, "W"), _sig(ins, "TailPool")
+    if xv is None or xv.shape is None:
+        return None
+    if w is not None and w.shape is not None and len(w.shape) == 2 and \
+            min(w.shape[1], xv.shape[-1]) >= 0 and \
+            w.shape[1] != xv.shape[-1]:
+        raise SpecMismatch(
+            f"causal_conv1d: W {list(w.shape)} is not [kernel, channels "
+            f"= {xv.shape[-1]}]", kind="shape")
+    out = {"Out": [VarSig(xv.shape, xv.dtype)]}
+    if pool is not None:
+        out["TailPoolOut"] = [VarSig(pool.shape, pool.dtype)]
+    return out
+
+
+def _gdn_dims(ins, attrs):
+    """(S, heads, d_k, d_v, pool dtype or None) of a gated_delta_rule, or
+    None where a shape is unknown."""
+    q, v = _shape_of(_sig(ins, "Q")), _shape_of(_sig(ins, "V"))
+    h = int(attrs.get("n_head", 0))
+    if q is None or v is None or len(q) != 3 or h <= 0 \
+            or min(q[1], q[2], v[2]) < 0:
+        return None
+    pool = _sig(ins, "StatePool")
+    return q[1], h, q[2] // h, v[2] // h, \
+        None if pool is None else pool.dtype
+
+
+def _pl_gdn_decode_supported(ins, attrs, axis_sizes=None):
+    """Recurrent route gate (ops/pallas/gated_delta.py): one token a row
+    over a float32 state pool."""
+    from .pallas.gated_delta import supported
+    dims = _gdn_dims(ins, attrs)
+    if dims is None:
+        return False, "shape-unknown"
+    s, h, dk, dv, pool_dtype = dims
+    if pool_dtype is None or s != 1 or _sig(ins, "Fresh") is not None:
+        return False, f"gdn:not-recurrent:sq:{s}"
+    return supported(h, dk, dv, pool_dtype)
+
+
+def _pl_gdn_chunk_supported(ins, attrs, axis_sizes=None):
+    """Chunked route gate: any length (padded to whole sub-chunks), with
+    or without a float32 state pool."""
+    from .pallas.gated_delta import supported
+    dims = _gdn_dims(ins, attrs)
+    if dims is None:
+        return False, "shape-unknown"
+    _, h, dk, dv, pool_dtype = dims
+    return supported(h, dk, dv, pool_dtype or "float32")
+
+
+def _lower_gdn_decode(ctx, ins, attrs):
+    from .linear_attn_ops import lower_gdn_decode
+    return lower_gdn_decode(ctx, ins, attrs)
+
+
+def _lower_gdn_chunk(ctx, ins, attrs):
+    from .linear_attn_ops import lower_gdn_chunk
+    return lower_gdn_chunk(ctx, ins, attrs)
+
+
 def _infer_moe_grouped_ffn(ins, attrs):
     xv, wg = _sig(ins, "X"), _sig(ins, "WGate")
     if xv is None or xv.shape is None:
@@ -1560,8 +1651,9 @@ def _lower_paged_decode_attention(ctx, ins, attrs):
 def _pl_paged_supported(ins, attrs, axis_sizes=None):
     """Paged decode route gate (ops/pallas/paged_attention.py): a
     cache read with a one-token query and no ``QPos``, float32 pools in
-    pages of a multiple of 8 tokens, the hidden width in 128-lane tiles
-    and a head size that divides 128."""
+    pages of a multiple of 8 tokens or bfloat16 pools in pages of a
+    multiple of 16, the hidden width in 128-lane tiles and a head size
+    that divides 128."""
     from .pallas.paged_attention import supported
     q = _shape_of(_sig(ins, "Q"))
     pool = _sig(ins, "KPool")
@@ -1652,6 +1744,17 @@ _PL_MLA_PAGED = PallasLowering(
     match=lambda attrs, ax: bool(attrs.get("_cached")),
     supported=_pl_mla_paged_supported, lower=_lower_mla_paged_decode,
     kernels=("mla_paged_decode",))
+# the gated delta rule's two serving forms (ops/linear_attn_ops.py picks
+# by the query's length): one token a row against the state pool, and the
+# chain over a chunk's sub-chunks
+_PL_GDN_DECODE = PallasLowering(
+    "gdn_decode", flag="use_pallas_fused",
+    supported=_pl_gdn_decode_supported, lower=_lower_gdn_decode,
+    kernels=("gdn_decode",))
+_PL_GDN_CHUNK = PallasLowering(
+    "gdn_chunk", flag="use_pallas_fused",
+    supported=_pl_gdn_chunk_supported, lower=_lower_gdn_chunk,
+    kernels=("gdn_chunk",))
 _PL_GMM = PallasLowering(
     "moe_grouped_matmul", flag="use_pallas_fused",
     supported=_pl_gmm_supported,
@@ -1862,6 +1965,12 @@ def register_default_specs():
     op_spec("lm_head_logits", infer=_infer_lm_head_logits)
     op_spec("mla_attention", infer=_infer_mla_attention,
             pallas=(_PL_MLA_PAGED,))
+    # linear-attention layers of a served hybrid decoder
+    # (ops/linear_attn_ops.py)
+    op_spec("gated_delta_rule", infer=_infer_gated_delta_rule,
+            pallas=(_PL_GDN_DECODE, _PL_GDN_CHUNK))
+    op_spec("causal_conv1d", infer=_infer_causal_conv1d)
+    op_spec("gated_rms_norm", infer=same_as_input())
     op_spec("moe_grouped_ffn", infer=_infer_moe_grouped_ffn,
             flops=_flops_moe_grouped_ffn, pallas=(_PL_GMM,))
     op_spec("moe_load_stats", infer=same_as_input("Acc", "AccOut"))

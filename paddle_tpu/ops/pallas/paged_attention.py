@@ -21,8 +21,10 @@ device time for context that is 72 % padding (PERF.md, PR 32).  Here:
   each head's lanes by a lane butterfly and leaves the head's score in
   EVERY lane of that head, so the softmax and ``p * v`` are elementwise
   on whole vregs;
-* float32 throughout, on the VPU and the XLU: no MXU pass rounds an
-  operand to bf16;
+* float32 arithmetic throughout, on the VPU and the XLU: no MXU pass
+  rounds an operand to bf16.  The pools are float32 or bfloat16: pages
+  are copied as they lie (a bfloat16 page is half the bytes) and widened
+  to float32 in VMEM, where they are scored;
 * the online softmax runs as eight independent streams, one a sublane
   (position ``t`` belongs to stream ``t % 8``), merged once per row —
   nothing reduces across sublanes inside the loop over a row's steps.
@@ -82,9 +84,11 @@ def supported(sq, hidden, n_head, block_size, dtype="float32",
         return False, f"paged-decode:sq:{sq}"
     if has_qpos:
         return False, "paged-decode:qpos"
-    if jnp.dtype(dtype) != jnp.float32:
+    if jnp.dtype(dtype) not in (jnp.float32, jnp.bfloat16):
         return False, f"paged-decode:dtype:{jnp.dtype(dtype).name}"
-    if block_size % SUBLANES:
+    # a page is whole sublane tiles of its dtype: 8 rows of float32, 16 of
+    # bfloat16
+    if block_size % (SUBLANES * 4 // jnp.dtype(dtype).itemsize):
         return False, f"paged-decode:block-size:{block_size}"
     if hidden % LANES:
         return False, f"paged-decode:hidden:{hidden}"
@@ -165,14 +169,16 @@ def _kernel(ctx_ref, tbl_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem,
             m, l, acc = state
             fetch(b, c, pages - c * pps)
             live = row_in_step < ctx - c * rows
-            s = jnp.where(live, _head_sum(kbuf[...] * q, head_dim), MASKED)
+            s = jnp.where(live, _head_sum(
+                kbuf[...].astype(jnp.float32) * q, head_dim), MASKED)
             m_new = jnp.maximum(m, _fold(s, jnp.maximum))
             alpha = jnp.exp(m - m_new)
             p = jnp.exp(s - jnp.concatenate([m_new] * (rows // SUBLANES),
                                             axis=0))
             l = alpha * l + _fold(p, jnp.add)
-            acc = alpha * acc + _fold(p * jnp.where(live, vbuf[...], 0.0),
-                                      jnp.add)
+            acc = alpha * acc + _fold(
+                p * jnp.where(live, vbuf[...].astype(jnp.float32), 0.0),
+                jnp.add)
             return m_new, l, acc
 
         zeros = jnp.zeros((SUBLANES, h), jnp.float32)
@@ -196,7 +202,7 @@ def paged_decode_attention(q, k_pool, v_pool, block_table, ctx_len, *,
                            n_head, pages_per_step=PAGES_PER_STEP,
                            interpret=False):
     """q: (B, 1, H) one query token a row; k_pool / v_pool:
-    (num_blocks, block_size, H) float32; block_table: (B,
+    (num_blocks, block_size, H) float32 or bfloat16; block_table: (B,
     max_blocks_per_seq) int32 pool blocks of each row's pages; ctx_len:
     (B,) int32 live positions of each row.  Returns the context, (B, 1,
     H).  Raises ValueError for what supported() rejects — call it
@@ -209,7 +215,8 @@ def paged_decode_attention(q, k_pool, v_pool, block_table, ctx_len, *,
         raise ValueError(f"paged_decode_attention: unsupported ({why})")
     d = h // n_head
     pps = min(int(pages_per_step), pages_per_seq)
-    table_bytes = 2 * b * pages_per_seq * bs * h * 4     # every page live
+    # every page live
+    table_bytes = 2 * b * pages_per_seq * bs * h * k_pool.dtype.itemsize
     out = pl.pallas_call(
         functools.partial(_kernel, head_dim=d, scale=1.0 / math.sqrt(d)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -218,10 +225,12 @@ def paged_decode_attention(q, k_pool, v_pool, block_table, ctx_len, *,
                       pl.BlockSpec(memory_space=pl.ANY),
                       pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=pl.BlockSpec((b, h), lambda i, *_: (0, 0)),
-            scratch_shapes=[pltpu.VMEM((pps * bs, h), jnp.float32),
-                            pltpu.VMEM((pps * bs, h), jnp.float32),
+            scratch_shapes=[pltpu.VMEM((pps * bs, h), k_pool.dtype),
+                            pltpu.VMEM((pps * bs, h), v_pool.dtype),
                             pltpu.SemaphoreType.DMA((2,))]),
-        out_shape=jax.ShapeDtypeStruct((b, h), q.dtype),
+        # float32 whatever the query's dtype: a row is written alone, and
+        # one row is not a whole tile of a packed dtype
+        out_shape=jax.ShapeDtypeStruct((b, h), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         cost_estimate=pl.CostEstimate(
@@ -231,4 +240,4 @@ def paged_decode_attention(q, k_pool, v_pool, block_table, ctx_len, *,
         name="paged_decode_attn",
     )(ctx_len.astype(jnp.int32), block_table.reshape(-1).astype(jnp.int32),
       q.reshape(b, h).astype(jnp.float32), k_pool, v_pool)
-    return out.reshape(b, 1, h)
+    return out.reshape(b, 1, h).astype(q.dtype)

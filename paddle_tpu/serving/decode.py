@@ -96,6 +96,27 @@ carries ``rid`` and its submit/admit/first-token/done stamps
 (``GenerationResult.timing``,
 ``stats()["queue_wait_ns"]``/``["first_token_ns"]``).
 
+**Recurrent state** is the second kind of per-sequence cache.  A model
+whose layers carry a state from token to token (a linear-attention
+layer's ``[heads, d_k, d_v]`` matrix and the tail of its short
+convolution, ops/linear_attn_ops.py) declares ``state_bytes_per_slot()``
+and gets, beside the block pools and owned by the same manager, a
+**state pool**: one slot a batch row (``max_batch_size``) and one
+scratch slot more.  A sequence takes ONE slot at admission together with
+its blocks, keeps it through its chunks and chains, and gives it back
+with its blocks at retire.  A slot is free whenever a batch row is, so
+admission waits on blocks alone (``admission_waits``) until snapshots or
+preemption let a slot outlive its row.  Every launch feeds a per-row
+``state_slot``; rows of a bucket that hold no sequence name the scratch
+slot, so they never write a live one.  A sequence's first launch carries
+``state_fresh`` (the packed prefill, whose rows then hold one segment
+each, starts every row fresh): the state starts from zero on the device,
+no host-side clear.  The chain's scan carries the state pools as it
+carries the KV pools.  The prefix index matches KV blocks by content; a
+hit without the recurrent state at that boundary would be wrong output,
+so ``prefix_cache=True`` is REFUSED for such a model until state
+snapshots exist.
+
 Static safety: ``analysis.verify_decode`` checks every program at
 engine start — no collectives, no persistable writes outside the
 declared cache pool, and the ``decode_chain`` marker (when present)
@@ -308,7 +329,7 @@ class _Seq:
                  "t_submit", "steps", "_gather_idx", "waited_rounds",
                  "temperature", "top_k", "top_p", "seed", "hit_blocks",
                  "_chunk_off", "rid", "t_submit_ns", "t_admit_ns",
-                 "t_first_token_ns", "logits")
+                 "t_first_token_ns", "logits", "state_slot")
 
     def __init__(self, prompt, max_new, eos, on_token,
                  temperature=0.0, top_k=0, top_p=0.0, seed=0,
@@ -319,6 +340,7 @@ class _Seq:
         self.future: Future = Future()
         self.on_token = on_token
         self.block_ids: List[int] = []
+        self.state_slot: Optional[int] = None   # recurrent-state slot held
         self.pos = 0                   # tokens currently in cache
         self.out_tokens: List[int] = []
         self.done = False
@@ -496,6 +518,20 @@ class DecodeEngine:
                 f"max_seq_len={cfg.max_seq_len} exceeds the model's "
                 f"max_position_embeddings={mcfg.max_position_embeddings}")
         self._mbps = cfg.max_blocks_per_seq
+        # recurrent state: one slot a batch row and a scratch slot (the
+        # last) for the rows of a bucket that hold no sequence
+        state_bytes = getattr(model, "state_bytes_per_slot", None)
+        self._state_bytes = int(state_bytes()) if state_bytes else 0
+        self._state_slots = cfg.max_batch_size if self._state_bytes else 0
+        if self._state_bytes and cfg.prefix_cache:
+            raise InvalidArgumentError(
+                "prefix_cache=True is refused for a model with recurrent "
+                "state: the prefix index matches KV blocks by content, "
+                "and a hit without the recurrent state at that block "
+                "boundary is wrong output (state snapshots do not exist "
+                "yet) — set DecodeConfig(prefix_cache=False)")
+        # a packed prefill row carries ONE recurrent state: one segment
+        self._pack = 1 if self._state_bytes else cfg.pack_max_segments
 
         # -- pool sizing (the memory analyzer IS the admission model) --
         budget = cfg.hbm_budget_gb
@@ -522,9 +558,10 @@ class DecodeEngine:
         need_chunk = cfg.prefix_cache or cfg.chunk_tokens
         self._programs = model.build(
             self.pool_blocks, cfg.block_size, self._mbps,
-            cfg.pack_max_segments, chain_lengths=cfg.chain_lengths,
+            self._pack, chain_lengths=cfg.chain_lengths,
             with_sampling=cfg.sampling,
-            chunk_tokens=cfg.chunk_width if need_chunk else None)
+            chunk_tokens=cfg.chunk_width if need_chunk else None,
+            **self._state_kw())
         if place is None:
             import jax
             place = CPUPlace() if jax.default_backend() == "cpu" \
@@ -623,6 +660,8 @@ class DecodeEngine:
 
         # -- scheduling state ------------------------------------------
         self._free: List[int] = list(range(self.pool_blocks - 1, -1, -1))
+        self._free_state: List[int] = list(
+            range(self._state_slots - 1, -1, -1))
         self._pending: List[_Seq] = []
         self._active: List[_Seq] = []
         self._chunking: List[_Seq] = []
@@ -652,11 +691,17 @@ class DecodeEngine:
         self._block_reuses = 0          # a freed block handed out again
         self._retired_blocks: set = set()
         self._admission_waits = 0
+        self._state_peak = 0
+        self._state_reuses = 0          # a freed slot handed out again
+        self._retired_state: set = set()
+        self._state_rows_launched = 0   # bucket rows x decode steps
+        self._state_rows_live = 0       # of them, rows a sequence owned
         self._host_syncs = 0            # one per device->host token fetch
         self._chains_run = 0
         self._chain_tokens = 0
         self._chain_hist: Dict[int, int] = {}
         self._chunk_steps = 0
+        self._chunk_tokens = 0          # prompt tokens the chunks computed
         self._interleaved_rounds = 0    # rounds mixing chunks + chains
         self._prefill_tokens = 0        # prompt tokens actually computed
         self._t_first = None
@@ -686,7 +731,7 @@ class DecodeEngine:
         from ..framework.memory_analysis import plan_cache_pool
         cfg = self.config
         probe = self.model.build(self._mbps, cfg.block_size, self._mbps,
-                                 cfg.pack_max_segments)
+                                 self._pack, **self._state_kw())
         bb = cfg.batch_buckets[-1]
         feed = self._decode_feed_arrays(
             bb, [], pad_only=True)
@@ -705,6 +750,12 @@ class DecodeEngine:
             "reserve_blocks": plan.get("reserve_blocks", 0),
         }
         return plan["blocks"]
+
+    def _state_kw(self) -> Dict[str, int]:
+        """``model.build``'s extra argument for a model with recurrent
+        state: the state pools' slots, the scratch slot included."""
+        return {"state_slots": self._state_slots + 1} \
+            if self._state_bytes else {}
 
     # -- lifecycle --------------------------------------------------------
     def start(self):
@@ -1006,6 +1057,9 @@ class DecodeEngine:
             else:
                 self._free.append(bid)
         seq.block_ids = []
+        if seq.state_slot is not None:
+            self._free_state.append(seq.state_slot)
+            seq.state_slot = None
 
     def _admit(self) -> List[_Seq]:
         """Pull pending prefills that fit THIS round: decode-slot
@@ -1055,7 +1109,7 @@ class DecodeEngine:
                         need_s = next(s for s in cfg.prefill_seq_buckets
                                       if s >= plen)
                     trial = row_lens + [plen]
-                    if _plan_bins(trial, need_s, cfg.pack_max_segments,
+                    if _plan_bins(trial, need_s, self._pack,
                                   cfg.prefill_batch_buckets[-1]) is None:
                         continue
                     row_lens = trial
@@ -1065,6 +1119,12 @@ class DecodeEngine:
                 # fresh; handing a previously-used block to a new
                 # sequence is the reuse case the parity contract covers
                 seq.block_ids = list(hits) + self._take_blocks(need)
+                if self._state_bytes:
+                    # one slot a batch row: a free row has a free slot
+                    seq.state_slot = self._free_state.pop()
+                    if seq.state_slot in self._retired_state:
+                        with self._stats_lock:
+                            self._state_reuses += 1
                 seq.hit_blocks = len(hits)
                 seq._chunk_off = len(hits) * cfg.block_size
                 taken += 1
@@ -1091,7 +1151,7 @@ class DecodeEngine:
     # -- prefill ----------------------------------------------------------
     def _prefill_feed(self, admitted: List[_Seq]):
         cfg = self.config
-        K = cfg.pack_max_segments
+        K = self._pack
         plens = [int(s.prompt.size) for s in admitted]
         bucket_s = next(s for s in cfg.prefill_seq_buckets
                         if s >= max(plens))
@@ -1117,9 +1177,22 @@ class DecodeEngine:
                                           for p in range(plen)]
             last_pos[row, ch] = off + plen - 1
             seq._gather_idx = row * K + ch
-        return ({"src_ids": src, "pos_ids": pos, "input_mask": mask,
-                 "slot_ids": slots, "last_pos": last_pos},
-                (bucket_b, bucket_s))
+        feed = {"src_ids": src, "pos_ids": pos, "input_mask": mask,
+                "slot_ids": slots, "last_pos": last_pos}
+        if self._state_bytes:       # one segment a row: the row's slot
+            feed["state_slot"] = self._state_rows(
+                bucket_b, [(row, seq) for seq, (row, _)
+                           in zip(admitted, placements)])
+            feed["state_fresh"] = np.ones((bucket_b,), np.int32)
+        return feed, (bucket_b, bucket_s)
+
+    def _state_rows(self, bucket_b: int, rows=()) -> np.ndarray:
+        """The per-row ``state_slot`` feed of a launch: each sequence's
+        slot at its row, the scratch slot everywhere else."""
+        out = np.full((bucket_b,), self._state_slots, np.int32)
+        for row, seq in rows:
+            out[row] = seq.state_slot
+        return out
 
     def _acquire(self, prepared):
         """Owner handoff between the prefill and decode prepared steps:
@@ -1232,8 +1305,13 @@ class DecodeEngine:
         table[0, :len(seq.block_ids)] = seq.block_ids
         ctx = np.array([end], np.int32)
         last = np.full((1, 1), n - 1 if final else 0, np.int64)
-        return {"src_ids": src, "pos_ids": pos, "slot_ids": slots,
+        feed = {"src_ids": src, "pos_ids": pos, "slot_ids": slots,
                 "block_table": table, "ctx_len": ctx, "last_pos": last}
+        if self._state_bytes:
+            feed["state_slot"] = self._state_rows(1, [(0, seq)])
+            # the sequence's first chunk starts its state from zero
+            feed["state_fresh"] = np.array([start == 0], np.int32)
+        return feed
 
     def _chunk_step(self, seq: _Seq):
         plen = int(seq.prompt.size)
@@ -1244,7 +1322,8 @@ class DecodeEngine:
         _flight.note_step(sid, "decode_chunk", (start, end))
         with step_scope(sid), \
                 RecordEvent("decode::chunk", tokens=end - start,
-                            final=final):
+                            final=final,
+                            state_rows=int(bool(self._state_bytes))):
             with self._phase("feed"):
                 feed = self._chunk_feed(seq, start, end, final)
             # only the FINAL chunk's first generated token crosses to
@@ -1259,6 +1338,7 @@ class DecodeEngine:
                     self._first_tokens_out([seq], [int(toks[0])], [0])
         with self._stats_lock:
             self._chunk_steps += 1
+            self._chunk_tokens += end - start
             if final:
                 self._host_syncs += 1
             self._launches["chunk"] += 1
@@ -1283,8 +1363,12 @@ class DecodeEngine:
                 slots[i, 0] = self._slot(seq, seq.pos)
                 table[i, :len(seq.block_ids)] = seq.block_ids
                 ctx[i] = seq.pos + 1
-        return {"token_ids": tok, "pos_ids": pos, "slot_ids": slots,
+        feed = {"token_ids": tok, "pos_ids": pos, "slot_ids": slots,
                 "block_table": table, "ctx_len": ctx}
+        if self._state_bytes:
+            feed["state_slot"] = self._state_rows(
+                bucket_b, () if pad_only else enumerate(live))
+        return feed
 
     def _chain_feed_arrays(self, bucket_b: int, live: List[_Seq],
                            pad_only: bool = False):
@@ -1365,6 +1449,8 @@ class DecodeEngine:
                 bucket_b = next(b for b in cfg.batch_buckets
                                 if b >= len(live))
                 parent.set(bucket=bucket_b, chain=length)
+                if self._state_bytes:
+                    parent.set(state_rows=bucket_b)
                 feed = self._chain_feed_arrays(bucket_b, live)
                 _flight.note_step(sid, "decode_chain",
                                   (length, bucket_b, len(live)))
@@ -1399,6 +1485,11 @@ class DecodeEngine:
         with self._stats_lock:
             self._kv_pages_read += pages_read
             self._kv_pages_spanned += length * bucket_b * self._mbps
+            if self._state_bytes:
+                # every row of the bucket moves a slot every step; a row
+                # that emitted a token moved a live sequence's
+                self._state_rows_launched += length * bucket_b
+                self._state_rows_live += emitted
             self._decode_steps += length
             self._chains_run += 1
             self._host_syncs += 1
@@ -1435,6 +1526,8 @@ class DecodeEngine:
             in_use = sum(len(s.block_ids)
                          for s in self._active + self._chunking)
             self._peak_blocks = max(self._peak_blocks, in_use)
+            self._state_peak = max(self._state_peak,
+                                   self._state_slots_in_use())
         finished = [s for s in self._active if s.done]
         if not finished:
             return
@@ -1442,6 +1535,8 @@ class DecodeEngine:
             self._active = [s for s in self._active if not s.done]
             for seq in finished:
                 self._retired_blocks.update(seq.block_ids)
+                if seq.state_slot is not None:
+                    self._retired_state.add(seq.state_slot)
                 self._release_blocks(seq)
             self._cond.notify_all()
         now = _now_ns()
@@ -1465,6 +1560,9 @@ class DecodeEngine:
             if self._prefix_index is not None else 0
         return self.pool_blocks - len(self._free) - evictable
 
+    def _state_slots_in_use(self) -> int:
+        return self._state_slots - len(self._free_state)
+
     # -- warmup -----------------------------------------------------------
     def warmup(self) -> int:
         """Compile (or AOT-cache-load) the WHOLE executable grid from
@@ -1474,8 +1572,17 @@ class DecodeEngine:
         the combo count — a warm restart under ``flag("aot_cache_dir")``
         resolves all of them with 0 fresh compiles."""
         cfg = self.config
-        K = cfg.pack_max_segments
+        K = self._pack
         n = 0
+
+        def stateful(feed, rows, fresh=False):
+            """Warm-up rows name the scratch slot."""
+            if self._state_bytes:
+                feed["state_slot"] = self._state_rows(rows)
+                if fresh:
+                    feed["state_fresh"] = np.ones((rows,), np.int32)
+            return feed
+
         with self._run_lock:
             for sb in cfg.prefill_seq_buckets:
                 for bb in cfg.prefill_batch_buckets:
@@ -1487,7 +1594,7 @@ class DecodeEngine:
                         "last_pos": np.zeros((bb, K), np.int64),
                     }
                     self._acquire(self._prefill)
-                    self._prefill.run(feed)
+                    self._prefill.run(stateful(feed, bb, fresh=True))
                     n += 1
             for length in self._chain_lengths:
                 for bb in cfg.batch_buckets:
@@ -1498,14 +1605,14 @@ class DecodeEngine:
             if self._chunk is not None:
                 width = cfg.chunk_width
                 self._acquire(self._chunk)
-                self._chunk.run({
+                self._chunk.run(stateful({
                     "src_ids": np.zeros((1, width), np.int64),
                     "pos_ids": np.zeros((1, width), np.int64),
                     "slot_ids": np.full((1, width), -1, np.int32),
                     "block_table": np.zeros((1, self._mbps), np.int32),
                     "ctx_len": np.zeros((1,), np.int32),
                     "last_pos": np.zeros((1, 1), np.int64),
-                })
+                }, 1, fresh=True))
                 n += 1
             if self._owner is not None:
                 self._owner.wait()
@@ -1696,6 +1803,12 @@ class DecodeEngine:
                 "prefill_batches": self._prefill_batches,
                 "decode_batch_hist": dict(self._decode_batch_hist),
                 "admission_waits": self._admission_waits,
+                "state_slots": self._state_slots,
+                "state_slots_peak": self._state_peak,
+                "state_slot_reuses": self._state_reuses,
+                "state_bytes_per_slot": self._state_bytes,
+                "state_rows_launched": self._state_rows_launched,
+                "state_rows_live": self._state_rows_live,
                 "block_reuses": self._block_reuses,
                 "pool_blocks": self.pool_blocks,
                 "peak_blocks_used": self._peak_blocks,
@@ -1706,6 +1819,7 @@ class DecodeEngine:
                 "chain_tokens": self._chain_tokens,
                 "chain_hist": dict(self._chain_hist),
                 "chunk_steps": self._chunk_steps,
+                "chunk_tokens": self._chunk_tokens,
                 "interleaved_rounds": self._interleaved_rounds,
                 "prefill_tokens": self._prefill_tokens,
                 "launches": dict(self._launches),
@@ -1721,6 +1835,7 @@ class DecodeEngine:
                 phase_ns[name] += _now_ns() - t0
             out["phase_ns"] = phase_ns
         out["cache_blocks_used"] = self._blocks_in_use()
+        out["state_slots_in_use"] = self._state_slots_in_use()
         out["compile_count"] = self.compiled_executables
         idx = self._prefix_index
         out["prefix_hits"] = idx.hits if idx is not None else 0

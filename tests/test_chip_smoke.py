@@ -30,7 +30,7 @@ def test_cpu_dry_run_walks_every_leg():
     summary = lines[-2]
     assert summary.startswith("chip_smoke summary:")
     assert "platform: cpu" in summary
-    for leg in "KABC":
+    for leg in "KABHC":
         assert f"leg {leg}: passed" in summary, summary
 
 

@@ -37,10 +37,14 @@ def _gathered(q, k_pool, v_pool, table, ctx, n_head):
                                0.0, None, True)
 
 
-def _problem(ctx, hidden=128, block=16, pages=32, seed=0, shared=0):
+def _problem(ctx, hidden=128, block=16, pages=32, seed=0, shared=0,
+             dtype="float32"):
     """Rows of ``ctx`` live positions over scattered pool blocks, every
     slot of the pools holding some sequence's values; with ``shared`` the
-    rows' first pages are the same blocks (a prefix hit)."""
+    rows' first pages are the same blocks (a prefix hit).  ``dtype`` is
+    the pools': the values are rounded to it and handed out as float32
+    NumPy arrays (what the composition reads); :func:`_run` stores them
+    in ``dtype`` again, exactly."""
     rng = np.random.RandomState(seed)
     ctx = np.asarray(ctx, np.int32)
     need = np.clip(-(-ctx // block), 1, pages)
@@ -53,8 +57,9 @@ def _problem(ctx, hidden=128, block=16, pages=32, seed=0, shared=0):
         at += n
     if shared:
         table[1:, :shared] = table[0, :shared]
-    pools = [rng.randn(num_blocks, block, hidden).astype(np.float32)
-             for _ in range(2)]
+    pools = [np.array(jnp.asarray(
+        rng.randn(num_blocks, block, hidden), dtype).astype(jnp.float32))
+        for _ in range(2)]
     q = rng.randn(len(ctx), 1, hidden).astype(np.float32)
     return q, pools[0], pools[1], table, ctx
 
@@ -72,11 +77,11 @@ def _poisoned(pool, table, ctx):
     return out
 
 
-def _run(q, k_pool, v_pool, table, ctx, n_head, **kw):
+def _run(q, k_pool, v_pool, table, ctx, n_head, dtype="float32", **kw):
     return np.asarray(pa.paged_decode_attention(
-        jnp.asarray(q), jnp.asarray(k_pool), jnp.asarray(v_pool),
-        jnp.asarray(table), jnp.asarray(ctx), n_head=n_head,
-        interpret=INTERPRET, **kw))
+        jnp.asarray(q), jnp.asarray(k_pool, dtype),
+        jnp.asarray(v_pool, dtype), jnp.asarray(table), jnp.asarray(ctx),
+        n_head=n_head, interpret=INTERPRET, **kw))
 
 
 #: live contexts a row (block 16, 32 pages): one token, a whole page, a
@@ -99,32 +104,40 @@ CASES = {
 }
 
 
-@pytest.mark.parametrize("case", sorted(CASES))
-def test_kernel_matches_gather_composition(case):
+#: a bfloat16 page is whole 16-row tiles: pages of 8 are float32's alone
+POOL_DTYPES = [(c, dt) for c in sorted(CASES)
+               for dt in ("float32", "bfloat16")
+               if dt == "float32" or CASES[c].get("block", 16) % 16 == 0]
+
+
+@pytest.mark.parametrize("case,dtype", POOL_DTYPES)
+def test_kernel_matches_gather_composition(case, dtype):
     kw = dict(CASES[case])
     n_head = kw.pop("n_head", 2)
     run_kw = {k: kw.pop(k) for k in ("pages_per_step",) if k in kw}
-    q, kp, vp, table, ctx = _problem(**kw)
-    got = _run(q, kp, vp, table, ctx, n_head, **run_kw)
+    q, kp, vp, table, ctx = _problem(dtype=dtype, **kw)
+    got = _run(q, kp, vp, table, ctx, n_head, dtype=dtype, **run_kw)
     want = np.asarray(_gathered(q, kp, vp, table, ctx, n_head))
     assert got.shape == q.shape
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-6)
 
 
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 @pytest.mark.parametrize("pages_per_step", [8, 2])
-def test_nan_past_ctx_len_and_in_unowned_blocks(pages_per_step):
+def test_nan_past_ctx_len_and_in_unowned_blocks(pages_per_step, dtype):
     """What the kernel must not read into the result is NaN here: the
     slots past ``ctx_len`` of every last page, every block no row owns
     (block 0, where dead table entries point, among them).  The gather
     composition cannot survive this (0 x NaN); the kernel must, and must
     agree with the composition on clean pools."""
-    q, kp, vp, table, ctx = _problem((1, 16, 17, 143, 512, 30, 250))
+    q, kp, vp, table, ctx = _problem((1, 16, 17, 143, 512, 30, 250),
+                                     dtype=dtype)
     want = np.asarray(_gathered(q, kp, vp, table, ctx, 2))
     bad_k, bad_v = _poisoned(kp, table, ctx), _poisoned(vp, table, ctx)
     assert np.isnan(bad_k[0]).all() and np.isnan(bad_v).any()
     assert not np.isfinite(np.asarray(
         _gathered(q, bad_k, bad_v, table, ctx, 2))).all()
-    got = _run(q, bad_k, bad_v, table, ctx, 2,
+    got = _run(q, bad_k, bad_v, table, ctx, 2, dtype=dtype,
                pages_per_step=pages_per_step)
     assert np.isfinite(got).all()
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-6)
@@ -195,8 +208,12 @@ def test_shape_rule(args, reason):
 def test_shape_rule_refuses_qpos_and_other_dtypes():
     assert pa.supported(1, 768, 12, 16, has_qpos=True) == (
         False, "paged-decode:qpos")
-    assert pa.supported(1, 768, 12, 16, "bfloat16") == (
-        False, "paged-decode:dtype:bfloat16")
+    assert pa.supported(1, 768, 12, 16, "float16") == (
+        False, "paged-decode:dtype:float16")
+    # a bfloat16 page is whole 16-row tiles
+    assert pa.supported(1, 3840, 30, 16, "bfloat16") == (True, "")
+    assert pa.supported(1, 768, 12, 8, "bfloat16") == (
+        False, "paged-decode:block-size:8")
     with pytest.raises(ValueError, match="sq:2"):
         pa.paged_decode_attention(
             jnp.zeros((1, 2, 128)), jnp.zeros((2, 8, 128)),

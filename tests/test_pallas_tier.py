@@ -614,6 +614,21 @@ def _mla_paged_decode():
                    grad=False, is_test=True)]
 
 
+def _gated_delta_rule(s):
+    """One token a row against the state pool (the recurrent route) or a
+    chunk of ``s`` tokens started fresh (the chunked route)."""
+    h, dk, dv = 2, 16, 128
+    ins = {"Q": _f32(2, s, h * dk), "K": _f32(2, s, h * dk),
+           "V": _f32(2, s, h * dv), "A": _f32(2, s, h), "B": _f32(2, s, h),
+           "ALog": _f32(h), "DtBias": _f32(h),
+           "StatePool": _f32(3, h, dk, dv),
+           "StateSlot": jnp.zeros((2,), jnp.int32)}
+    if s > 1:
+        ins["Fresh"] = jnp.ones((2,), jnp.int32)
+    return [_op_fn("gated_delta_rule", ins, {"n_head": h}, grad=False,
+                   is_test=True)]
+
+
 def _grouped_ffn():
     n, d, f, e, k = 256, 128, 128, 2, 2
     ins = {"X": _f32(n, d), "TopkWeight": _f32(n, k),
@@ -668,6 +683,8 @@ ROUTE_CASES = {
     ("fused_attention", "cached_flash_attention"): _cached_attention,
     ("fused_attention", "paged_decode_attention"): _paged_decode_attention,
     ("mla_attention", "mla_paged_decode"): _mla_paged_decode,
+    ("gated_delta_rule", "gdn_decode"): lambda: _gated_delta_rule(1),
+    ("gated_delta_rule", "gdn_chunk"): lambda: _gated_delta_rule(128),
     ("moe_grouped_ffn", "moe_grouped_matmul"): _grouped_ffn,
     ("layer_norm", "fused_layer_norm"): lambda: _norm("layer_norm"),
     ("fused_add_layernorm", "fused_add_layer_norm"): lambda: _norm(

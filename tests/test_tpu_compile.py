@@ -278,3 +278,94 @@ def test_wide_expert_grouped_matmul_compiles_for_v5e(
         sds((tokens, 8), jnp.int32), sds((16, 7168, 2048)),
         sds((16, 7168, 2048)), sds((16, 2048, 7168))).compile().as_text()
     assert "moe_gmm" in txt
+
+
+HYBRID_GDN = {"n_head": 30, "beta_scale": 2.0}
+
+
+def _gdn_inputs(sds, batch, s):
+    f32 = jnp.float32
+    return {"Q": [sds((batch, s, 2880))], "K": [sds((batch, s, 2880))],
+            "V": [sds((batch, s, 5760))], "A": [sds((batch, s, 30))],
+            "B": [sds((batch, s, 30))], "ALog": [sds((30,), f32)],
+            "DtBias": [sds((30,), f32)]}
+
+
+@pytest.mark.parametrize("batch", [64, 32])
+def test_hybrid_decode_step_compiles_for_v5e(one_chip, no_compile_cache,
+                                             batch):
+    """The hybrid serving cell's decode step at its own shapes, as it
+    sits in a chain (a scan that carries every pool): the full layers'
+    read of bfloat16 K/V pools (13 312 blocks of 16 x 3 840, 30 heads of
+    128, a 1 024-page table) by the paged kernel, and the linear layers'
+    recurrence on a float32 state pool of 65 slots x 30 x 96 x 192 by
+    ``gdn_decode``.  No ``[B, T, H]`` gather of the K/V cache and no copy
+    of a pool exists in the compiled module."""
+    import re
+    from paddle_tpu.ops.pallas import lowering_target
+    from paddle_tpu.ops.registry import get_op
+
+    def sds(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    attn = {"n_head": 30, "_cached": True, "is_test": True}
+
+    def chain(q, k_pool, v_pool, table, pos, state, slot, gdn):
+        def step(carry, _):
+            k_pool, v_pool, state, pos, q = carry
+            slots = (table[:, 0] * 16 + pos % 16)[:, None]
+            wrote = get_op("cache_write")(None, {
+                "KPool": [k_pool], "VPool": [v_pool], "K": [q], "V": [q],
+                "Slots": [slots]}, {})
+            k_pool, v_pool = wrote["KPoolOut"], wrote["VPoolOut"]
+            out = get_op("fused_attention")(None, {
+                "Q": [q], "KPool": [k_pool], "VPool": [v_pool],
+                "BlockTable": [table], "CtxLen": [pos + 1]}, attn)["Out"]
+            lin = get_op("gated_delta_rule")(None, dict(
+                gdn, StatePool=[state], StateSlot=[slot]), HYBRID_GDN)
+            q = out + jnp.pad(lin["Out"], ((0, 0), (0, 0), (0, 0)))[
+                ..., :3840]
+            return (k_pool, v_pool, lin["StatePoolOut"], pos + 1, q), None
+        return jax.lax.scan(step, (k_pool, v_pool, state, pos, q), None,
+                            length=8)[0]
+
+    with lowering_target("tpu"):
+        txt = jax.jit(chain, donate_argnums=(1, 2, 5)).lower(
+            sds((batch, 1, 3840)), sds((13312, 16, 3840)),
+            sds((13312, 16, 3840)), sds((batch, 1024), jnp.int32),
+            sds((batch,), jnp.int32), sds((65, 30, 96, 192), jnp.float32),
+            sds((batch,), jnp.int32),
+            _gdn_inputs(sds, batch, 1)).compile().as_text()
+    for route in ("paged_decode_attention", "gdn_decode"):
+        assert all(k in txt for k in _route_kernels(route)), route
+    assert f"bf16[{batch},16384,3840]" not in txt
+    moved = re.findall(
+        r"= (?:bf16\[13312,16,3840\]|f32\[65,30,96,192\])\S* copy\(", txt)
+    assert not moved, moved
+
+
+def test_hybrid_chunk_delta_rule_compiles_for_v5e(one_chip,
+                                                  no_compile_cache):
+    """A 1 024-token prefill chunk's linear-attention mixer at the
+    published widths: the WY transform in XLA and the chain over 16
+    sub-chunks on ``gdn_chunk``, the state read from and written to the
+    row's slot of the donated pool."""
+    from paddle_tpu.ops.pallas import lowering_target
+    from paddle_tpu.ops.registry import get_op
+
+    def sds(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def chunk(state, slot, fresh, valid, gdn):
+        out = get_op("gated_delta_rule")(None, dict(
+            gdn, StatePool=[state], StateSlot=[slot], Fresh=[fresh],
+            Valid=[valid]), HYBRID_GDN)
+        return out["Out"], out["StatePoolOut"]
+
+    with lowering_target("tpu"):
+        txt = jax.jit(chunk, donate_argnums=(0,)).lower(
+            sds((65, 30, 96, 192), jnp.float32), sds((1,), jnp.int32),
+            sds((1,), jnp.int32), sds((1, 1024), jnp.bool_),
+            _gdn_inputs(sds, 1, 1024)).compile().as_text()
+    assert all(k in txt for k in _route_kernels("gdn_chunk"))
+    assert "f32[65,30,96,192]{3,2,1,0} copy(" not in txt
