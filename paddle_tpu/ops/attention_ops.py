@@ -21,8 +21,8 @@ place through the block table as far as each row's live context.
 Routing goes through the registry's Pallas channel
 (``pallas_route("fused_attention", ...)`` — ops/op_specs.py registers the
 ``attention_tile``, ``flash_attention``, ``flash_gqa_attention``,
-``paged_decode_attention``, ``cached_flash_attention`` and
-``ring_flash_attention`` routes; which one is read from shapes and attrs,
+``paged_decode_attention_wide``, ``paged_decode_attention``,
+``cached_flash_attention`` and ``ring_flash_attention`` routes; which one is read from shapes and attrs,
 never from a flag or a model name), so the gate is
 statically enumerable, every hit/fallback lands in
 ``observability.metrics`` counters labeled by op + reason, and fallback
@@ -167,15 +167,18 @@ def lower_gqa_attention(ctx, ins, attrs, use_flash=True):
                       window=window)}
 
 
-def lower_paged_decode_attention(ctx, ins, attrs):
-    """The ``paged_decode_attention`` Pallas route: a decode step's
+def lower_paged_decode_attention(ctx, ins, attrs, wide=False):
+    """The ``paged_decode_attention`` Pallas routes: a decode step's
     cache read (one query token a row, no ``QPos``) straight from the
     pools, page by page through the block table as far as each row's
     ``CtxLen`` — no ``[B, T, H]`` copy of the cache exists
-    (pallas_route guarantees the shape rule before this is called)."""
-    from .pallas.paged_attention import paged_decode_attention
+    (pallas_route guarantees the shape rule before this is called).
+    ``wide``: the body for heads of whole lane tiles."""
+    from .pallas import paged_attention
+    kernel = paged_attention.paged_decode_attention_wide if wide \
+        else paged_attention.paged_decode_attention
     q = x(ins, "Q")
-    return {"Out": paged_decode_attention(
+    return {"Out": kernel(
         q, x(ins, "KPool"), x(ins, "VPool"), x(ins, "BlockTable"),
         x(ins, "CtxLen"), n_head=_resolve_heads(q, attrs))}
 
@@ -257,7 +260,8 @@ def _fused_attention(ctx, ins, attrs):
     # query by the paged kernel, a longer one by gather + flash
     if x(ins, "KPool") is not None:
         route, _ = pallas_route("fused_attention", ins, attrs,
-                                kernel=("paged_decode_attention",
+                                kernel=("paged_decode_attention_wide",
+                                        "paged_decode_attention",
                                         "cached_flash_attention"))
         if route is not None:
             return route.lower(ctx, ins, attrs)

@@ -24,6 +24,7 @@ shape knowledge — the warn-don't-fail path for exotic ops.
 
 from __future__ import annotations
 
+import functools
 from typing import List, Optional
 
 from .registry import (PallasLowering, SpecMismatch, VarSig, _shape_of,
@@ -1643,18 +1644,20 @@ def _lower_cached_flash_attention(ctx, ins, attrs):
     return lower_cached_attention(ctx, ins, attrs, use_flash=True)
 
 
-def _lower_paged_decode_attention(ctx, ins, attrs):
+def _lower_paged_decode_attention(ctx, ins, attrs, wide=False):
     from .attention_ops import lower_paged_decode_attention
-    return lower_paged_decode_attention(ctx, ins, attrs)
+    return lower_paged_decode_attention(ctx, ins, attrs, wide=wide)
 
 
-def _pl_paged_supported(ins, attrs, axis_sizes=None):
+def _pl_paged_supported(ins, attrs, axis_sizes=None, wide=False):
     """Paged decode route gate (ops/pallas/paged_attention.py): a
     cache read with a one-token query and no ``QPos``, float32 pools in
     pages of a multiple of 8 tokens or bfloat16 pools in pages of a
     multiple of 16, the hidden width in 128-lane tiles and a head size
-    that divides 128."""
-    from .pallas.paged_attention import supported
+    that divides 128 — or, ``wide``, that is a multiple of 128."""
+    from .pallas import paged_attention
+    supported = paged_attention.supported_wide if wide \
+        else paged_attention.supported
     q = _shape_of(_sig(ins, "Q"))
     pool = _sig(ins, "KPool")
     pshape = _shape_of(pool)
@@ -1737,6 +1740,15 @@ _PL_PAGED = PallasLowering(
     attr="use_flash", match=_PL_CACHED.match,
     supported=_pl_paged_supported, lower=_lower_paged_decode_attention,
     kernels=("paged_decode_attn",))
+# heads that are whole lane tiles (a head's slab of a page is a slice the
+# MXU takes as it lies) have a body of their own, tried first; narrower
+# heads share a tile and keep _PL_PAGED's
+_PL_PAGED_WIDE = PallasLowering(
+    "paged_decode_attention_wide", flag="use_flash_attention",
+    attr="use_flash", match=_PL_CACHED.match,
+    supported=functools.partial(_pl_paged_supported, wide=True),
+    lower=functools.partial(_lower_paged_decode_attention, wide=True),
+    kernels=("paged_decode_attn_wide",))
 # a decode step's read of the paged LATENT cache (mla_attention with a
 # Pool, one query token a row): the absorbed form on the MXU
 _PL_MLA_PAGED = PallasLowering(
@@ -1857,8 +1869,8 @@ def register_default_specs():
     op_spec("fused_attention", infer=_infer_fused_attention,
             mem_backward_extra=_attention_probs_bytes,
             flops=_flops_fused_attention,
-            pallas=(_PL_RING, _PL_PAGED, _PL_CACHED, _PL_GQA, _PL_TILE,
-                    _PL_FLASH))
+            pallas=(_PL_RING, _PL_PAGED_WIDE, _PL_PAGED, _PL_CACHED,
+                    _PL_GQA, _PL_TILE, _PL_FLASH))
     op_spec("cache_write", infer=_infer_cache_write)
     op_spec("decode_chain", infer=_infer_decode_chain)
 

@@ -14,8 +14,9 @@ far:
   sequences own i32 block tables, attention reads THROUGH the table
   (``fused_attention``'s cache variant: on TPU a decode step's
   one-token query reads the pools in place, page by page as far as each
-  row's live context — the ``paged_decode_attention`` Pallas route —
-  and a chunk's longer query gathers the table for the
+  row's live context — the ``paged_decode_attention`` Pallas routes, a
+  body for heads that share a lane tile and one for heads of whole
+  tiles — and a chunk's longer query gathers the table for the
   ``cached_flash_attention`` route; gather-based on CPU), and
   ``cache_write`` appends via host-computed flat slot ids.  The pool is
   sized ONCE at engine start by the PR 5 static analyzer

@@ -193,6 +193,31 @@ def _tile_route_cases():
         ("cached-decode-step", dict(cached, Q=[VarSig((2, 1, 128),
                                                       "float32")]),
          {"_cached": True}, None, "paged_decode_attention"),
+        # a head of whole lane tiles takes the wide body; heads that
+        # share a tile (64: the serving cell's, 32) keep the narrow one
+        ("cached-decode-step-heads-of-32",
+         dict(cached, Q=[VarSig((2, 1, 128), "float32")]),
+         {"_cached": True, "n_head": 4}, None, "paged_decode_attention"),
+        ("cached-decode-step-heads-of-128",
+         dict(cached, Q=[VarSig((2, 1, 128), "float32")]),
+         {"_cached": True, "n_head": 1}, None,
+         "paged_decode_attention_wide"),
+        ("cached-decode-step-heads-of-128-bf16-pools", {
+            "Q": [VarSig((64, 1, 3840), "bfloat16")],
+            "KPool": [VarSig((13312, 16, 3840), "bfloat16")],
+            "VPool": [VarSig((13312, 16, 3840), "bfloat16")],
+            "BlockTable": [VarSig((64, 1024), "int32")],
+            "CtxLen": [VarSig((64,), "int32")]},
+         {"_cached": True, "n_head": 30}, None,
+         "paged_decode_attention_wide"),
+        ("cached-decode-step-heads-of-256",
+         dict(cached, Q=[VarSig((2, 1, 256), "float32")],
+              KPool=[VarSig((16, 128, 256), "float32")],
+              VPool=[VarSig((16, 128, 256), "float32")]),
+         {"_cached": True, "n_head": 1}, None,
+         "paged_decode_attention_wide"),
+        ("cached-chunk-heads-of-128", cached,
+         {"_cached": True, "n_head": 1}, None, "cached_flash_attention"),
         ("cached-chunk-qpos", dict(cached, QPos=[VarSig((2, 128),
                                                         "int64")]),
          {"_cached": True}, None, "cached_flash_attention"),
@@ -211,7 +236,7 @@ def test_route_table_picks_tile_only_for_one_tile(ins, attrs, axis_sizes,
     ring-stamped and S = 256 keep the routes they had."""
     from paddle_tpu.ops.pallas import lowering_target
     from paddle_tpu.ops.registry import pallas_route
-    attrs = dict(attrs, n_head=2)
+    attrs = {"n_head": 2, **attrs}
     with lowering_target("tpu"):
         route, reason = pallas_route("fused_attention", ins, attrs,
                                      axis_sizes=axis_sizes, count=False)
@@ -599,6 +624,17 @@ def _paged_decode_attention():
                    grad=False, is_test=True)]
 
 
+def _paged_decode_attention_wide():
+    """The same with one head of 128: a head of whole lane tiles."""
+    pool = jnp.zeros((8, 16, 128), jnp.bfloat16)
+    ins = {"Q": _f32(2, 1, 128), "KPool": pool, "VPool": pool,
+           "BlockTable": jnp.zeros((2, 4), jnp.int32),
+           "CtxLen": jnp.full((2,), 17, jnp.int32)}
+    return [_op_fn("fused_attention", ins,
+                   {"n_head": 1, "_cached": True, "is_test": True},
+                   grad=False, is_test=True)]
+
+
 def _mla_paged_decode():
     """A decode step's read of the paged latent cache: one query token a
     row, 8 heads of 128+128 / v 128 over 256-wide bfloat16 rows."""
@@ -682,6 +718,8 @@ ROUTE_CASES = {
     ("fused_attention", "ring_flash_attention"): _ring_attention,
     ("fused_attention", "cached_flash_attention"): _cached_attention,
     ("fused_attention", "paged_decode_attention"): _paged_decode_attention,
+    ("fused_attention", "paged_decode_attention_wide"):
+        _paged_decode_attention_wide,
     ("mla_attention", "mla_paged_decode"): _mla_paged_decode,
     ("gated_delta_rule", "gdn_decode"): lambda: _gated_delta_rule(1),
     ("gated_delta_rule", "gdn_chunk"): lambda: _gated_delta_rule(128),

@@ -216,6 +216,9 @@ def test_paged_decode_attention_compiles_for_v5e(one_chip, no_compile_cache,
             sds((4096, 16, 768)), sds((batch, 32), jnp.int32),
             sds((batch,), jnp.int32)).compile().as_text()
     assert all(k in txt for k in _route_kernels("paged_decode_attention"))
+    # heads of 64 share a lane tile: the narrow body alone
+    assert not any(k in txt for k in
+                   _route_kernels("paged_decode_attention_wide"))
     assert f"f32[{batch},512,768]" not in txt
     moved = re.findall(r"= f32\[4096,16,768\]\S* copy\(", txt)
     assert not moved, moved
@@ -297,10 +300,13 @@ def test_hybrid_decode_step_compiles_for_v5e(one_chip, no_compile_cache,
     """The hybrid serving cell's decode step at its own shapes, as it
     sits in a chain (a scan that carries every pool): the full layers'
     read of bfloat16 K/V pools (13 312 blocks of 16 x 3 840, 30 heads of
-    128, a 1 024-page table) by the paged kernel, and the linear layers'
-    recurrence on a float32 state pool of 65 slots x 30 x 96 x 192 by
-    ``gdn_decode``.  No ``[B, T, H]`` gather of the K/V cache and no copy
-    of a pool exists in the compiled module."""
+    128, a 1 024-page table) by the paged kernel's wide body (heads of
+    whole lane tiles: the narrow body is not in the module), and the
+    linear layers' recurrence on a float32 state pool of 65 slots x 30 x
+    96 x 192 by ``gdn_decode``.  No ``[B, T, H]`` gather of the K/V cache
+    and no copy of a pool exists in the compiled module; inside the
+    kernel no float32 copy of a step's pages (``[P, 3840]``) exists
+    either: the bfloat16 pages go to the MXU as they lie."""
     import re
     from paddle_tpu.ops.pallas import lowering_target
     from paddle_tpu.ops.registry import get_op
@@ -336,12 +342,22 @@ def test_hybrid_decode_step_compiles_for_v5e(one_chip, no_compile_cache,
             sds((batch,), jnp.int32), sds((65, 30, 96, 192), jnp.float32),
             sds((batch,), jnp.int32),
             _gdn_inputs(sds, batch, 1)).compile().as_text()
-    for route in ("paged_decode_attention", "gdn_decode"):
+    for route in ("paged_decode_attention_wide", "gdn_decode"):
         assert all(k in txt for k in _route_kernels(route)), route
+    assert not re.search(r"paged_decode_attn(?!_wide)", txt)
     assert f"bf16[{batch},16384,3840]" not in txt
     moved = re.findall(
         r"= (?:bf16\[13312,16,3840\]|f32\[65,30,96,192\])\S* copy\(", txt)
     assert not moved, moved
+    from paddle_tpu.ops.pallas import paged_attention as pa
+    kernel = str(jax.make_jaxpr(
+        lambda *a: pa.paged_decode_attention_wide(*a, n_head=30))(
+            sds((batch, 1, 3840)), sds((13312, 16, 3840)),
+            sds((13312, 16, 3840)), sds((batch, 1024), jnp.int32),
+            sds((batch,), jnp.int32)))
+    pages = pa.PAGES_PER_STEP_WIDE * 16
+    assert f"bf16[2,{pages},3840]" in kernel
+    assert not re.search(rf"f32\[(?:2,)?{pages},3840\]", kernel)
 
 
 def test_hybrid_chunk_delta_rule_compiles_for_v5e(one_chip,
