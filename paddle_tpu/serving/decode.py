@@ -85,8 +85,44 @@ left between the others).  Each boundary is one clock read added into
 ``stats()["phase_ns"]`` (always on), and a ``decode::<phase>`` span
 (on whenever the profiler or any ``jax.profiler`` session is) whose
 parent is the launch's ``decode::prefill|chunk|chain`` span.
-``stats()["launch_ns"]``/``["launches"]`` split dispatch + sync time by
-executable kind; ``["kv_pages_read"]`` over ``["kv_pages_spanned"]`` is
+``stats()["launch_ns"]``/``["launches"]`` split the worker's dispatch +
+sync time by the kind of the launch that paid it.  That is NOT device
+time by kind: a non-final chunk is dispatched and never synced, so its
+device time is waited out in the next chain's ``sync`` and lands under
+``chain``.  Device time by kind is the **in-flight ledger**'s
+(``stats()["device_ns"]``): every launch
+dispatched and not yet known complete is on it; a ``sync`` waits on the
+launches before its own in order and stamps each completion, and launch
+*i* held the device from the later of launch *i-1*'s completion and its
+own dispatch to its own completion.  A host phase (``admit``, ``feed``,
+``dispatch``, ``emit``, ``retire``) that OPENS with the ledger empty has
+the device waiting on the host, and its length is also in
+``stats()["starved_ns"][phase]``, so that ``sum(device_ns) +
+sum(starved_ns) + phase_ns["idle"] == sum(phase_ns)`` whenever the
+ledger is empty: the ledger tiles the worker's wall time as the phases
+do (a launch whose dispatch found the device free starts at that
+dispatch's END, so an exposed dispatch counts once, as starved).  A
+launch is booked when some ``sync`` learns of its completion, never
+earlier: a ``stats()`` read of the device counters, or a round that
+leaves chunks in flight and runs no chain, may find the device finished
+without the worker knowing, and the next ``sync`` books those launches up
+to the instant it sees them complete.  Completions are stamps of the
+HOST, taken when a blocking call returns, so a launch's device time
+includes the worker's wake-up after it: an upper bound on what the
+device's own record gives.  The ledger also keeps a running mean of
+each executable's device time, and one compare at every boundary
+catches a **slow phase**: a non-idle interval of
+:data:`SLOW_PHASE_FACTOR` times what the device usually takes over the
+launches the phase waited on (``sync``) or the worker last dispatched
+(a host phase) — a stall measured against the launch itself, whatever
+the model's size (``stats()["slow_phase_ns"]`` by phase,
+``["slow_phases"]`` the last 32 with what the worker was running, and a
+``decode_slow_phase`` flight event).  A dispatch that bound a new
+executable (a compile or a cache load) is not judged, and its launch
+is left out of the mean, as is a launch that was complete before the
+worker waited on it.  Every ``decode::<phase>`` span carries
+``starved=0|1``.  ``["kv_pages_read"]`` over
+``["kv_pages_spanned"]`` is
 the share of the block tables the decode steps' cache reads touch (per
 step and row ``ceil(ctx / block_size)`` pages of ``max_blocks_per_seq``,
 counted on the host from the rows' positions); counters a model's
@@ -131,6 +167,8 @@ from __future__ import annotations
 
 import threading
 import time
+import weakref
+from collections import deque
 from concurrent.futures import Future
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
@@ -150,6 +188,13 @@ from .engine import _plan_bins
 PHASES = ("idle", "admit", "feed", "dispatch", "sync", "emit", "retire")
 #: the executables a launch (dispatch + sync) can be of
 LAUNCH_KINDS = ("prefill", "chunk", "chain")
+#: the phases in which the device waits on the HOST when nothing is in
+#: flight (``sync`` waits on the device, ``idle`` on the traffic)
+STARVABLE = ("admit", "feed", "dispatch", "emit", "retire")
+#: a non-idle phase interval is a stall at this many times the usual
+#: device time of the launches it waited on or was last dispatching (a
+#: healthy ``sync`` is about once that, a host phase a small part of it)
+SLOW_PHASE_FACTOR = 4
 
 #: the engine's one clock: request stamps, phase boundaries and the
 #: load generator's own ``time.monotonic()`` stamps are comparable
@@ -474,10 +519,14 @@ class _PhaseSpan:
     def __init__(self, engine: "DecodeEngine", name: str):
         self._engine = engine
         self._name = name
-        self._span = RecordEvent("decode::" + name)
+        self._span = None
 
     def __enter__(self):
-        self._engine._switch(self._name)
+        starved = self._engine._switch(self._name)
+        # the attribute goes in with the span's name: one encoding, where
+        # a set() on an open span costs a call into the profiler
+        self._span = RecordEvent("decode::" + self._name,
+                                 {"starved": int(starved)})
         return self._span.__enter__()
 
     def __exit__(self, *exc):
@@ -712,9 +761,31 @@ class DecodeEngine:
         # per phase, and the phase open now with its start (None while
         # no worker runs), so stats() is exact at any instant
         self._phase_ns = dict.fromkeys(PHASES, 0)
-        self._phase_open: Optional[Tuple[str, int]] = None
+        self._phase_open: Optional[Tuple[str, int, bool]] = None
         self._launch_ns = dict.fromkeys(LAUNCH_KINDS, 0)
         self._launches = dict.fromkeys(LAUNCH_KINDS, 0)
+        # the in-flight ledger, oldest first (the worker is its one
+        # writer): a launch dispatched and not yet known complete is
+        # [kind, a fetch handle of it, the stamp its device interval
+        # cannot start before (its dispatch's start, or the dispatch's
+        # end where the ledger was empty at that start), its executable
+        # (None where this dispatch bound it), that executable's usual
+        # device ns at the dispatch (0: none yet)]
+        self._inflight: deque = deque()
+        self._device_free_ns = 0        # the last completion a sync saw
+        self._device_ns = dict.fromkeys(LAUNCH_KINDS, 0)
+        self._starved_ns = dict.fromkeys(STARVABLE, 0)
+        # executable -> the running mean of its launches' device ns (0:
+        # bound, not yet launched again), and what the open phase is
+        # measured against (0: nothing to measure it against yet)
+        self._usual_ns = weakref.WeakKeyDictionary()
+        self._yardstick_ns = 0
+        self._slow_phase_ns = dict.fromkeys(PHASES[1:], 0)
+        self._slow_phases: deque = deque(maxlen=32)
+        # what the worker is launching, for a slow phase's row: (kind,
+        # rows, bucket or chain length, first rid), set inside the
+        # launch's feed phase; None between launches
+        self._launching: Optional[tuple] = None
         self._admitted = 0
         self._queue_wait_ns = 0         # sum of admit - submit
         self._first_token_ns = 0        # sum of first token - admit
@@ -925,16 +996,51 @@ class DecodeEngine:
         finally:
             self._switch(None)
 
-    def _switch(self, phase: Optional[str]) -> None:
+    def _switch(self, phase: Optional[str]) -> bool:
         """A phase boundary: close the open phase into ``phase_ns`` and
         open ``phase`` at the same clock read, so the phases tile the
-        worker's wall time with nothing between them."""
+        worker's wall time with nothing between them.  A host phase that
+        opens with nothing in flight is **starved** (returned; its length
+        also goes to ``starved_ns``); a non-idle interval of
+        :data:`SLOW_PHASE_FACTOR` times ``_yardstick_ns`` is put on
+        record."""
         now = _now_ns()
+        starved = phase in STARVABLE and not self._inflight
+        slow = None
         with self._stats_lock:
             if self._phase_open is not None:
-                name, t0 = self._phase_open
-                self._phase_ns[name] += now - t0
-            self._phase_open = None if phase is None else (phase, now)
+                name, t0, was_starved = self._phase_open
+                dur = now - t0
+                self._phase_ns[name] += dur
+                if was_starved:
+                    self._starved_ns[name] += dur
+                if 0 < self._yardstick_ns * SLOW_PHASE_FACTOR <= dur \
+                        and name != "idle":
+                    self._slow_phase_ns[name] += dur
+                    slow = (name, t0, dur)
+            self._phase_open = None if phase is None \
+                else (phase, now, starved)
+        if slow is not None:
+            self._note_slow_phase(*slow)
+        return starved
+
+    def _note_slow_phase(self, name: str, t0: int, dur: int) -> None:
+        """One row of ``stats()["slow_phases"]`` and a flight event:
+        ``[phase, launch kind or None, start ns, length ns, rows of the
+        launch (live sequences between launches), its size (a packed
+        prefill's sequence bucket, a chunk's tokens, a chain's length),
+        blocks in use, first rid]`` — whether the stall sat in ``sync``
+        (the device or its allocator), ``dispatch`` (the runtime) or
+        ``emit`` / ``retire`` (Python), and under what load."""
+        held = self._active + self._chunking
+        kind, rows, size, rid = self._launching or (
+            None, len(held), None, held[0].rid if held else None)
+        row = [name, kind, t0, dur, rows, size, self._blocks_in_use(), rid]
+        with self._stats_lock:
+            self._slow_phases.append(row)
+        _flight.note_event("decode_slow_phase", phase=name, launch=kind,
+                           start_ns=t0, dur_ns=dur, rows=rows, size=size,
+                           blocks=row[6], rid=rid)
 
     def _phase(self, name: str) -> "_PhaseSpan":
         """``with self._phase("feed"):`` — the worker is in ``name``
@@ -1206,12 +1312,27 @@ class DecodeEngine:
             self._owner.sync_scope()
         self._owner = prepared
 
-    def _launch(self, prepared, feed, fetch: Optional[int]):
+    def _launch(self, kind: str, prepared, feed, fetch: Optional[int]):
         """One executable's ``dispatch`` and, when ``fetch`` names the
         handle the host needs, its ``sync``, under the watchdog.
-        Returns (the fetched host array or None, the two phases' ns —
-        the worker is synchronous, so that is the executable's device
-        time plus launch latency).  The launch's fetch handles stay in
+        Returns (the fetched host array or None, the two phases' ns, the
+        stamp at which the later of them closed).  The two phases' ns is
+        what the WORKER spent on this launch (``launch_ns``), not the
+        executable's device time: a launch without ``fetch`` returns as
+        soon as it is dispatched, and whoever syncs next waits it out.
+        So the launch goes on the in-flight ledger, and a ``sync`` first
+        waits on every earlier launch there in order, stamping each
+        completion (all outputs of one execution become ready together),
+        then fetches its own: k + 1 blocking calls across the interval
+        one call would have blocked across, k the launches in flight
+        before it.  Launch *i* held the device from the later of launch
+        *i-1*'s completion and its own dispatch (the dispatch's END where
+        the ledger was empty at its start, which made that dispatch a
+        starved phase) to its own completion, the last one's being the
+        stamp that closes the ``sync``: that goes to ``device_ns[kind]``
+        and into the running mean of the launch's executable, against
+        which the phases of later launches of it are measured
+        (:data:`SLOW_PHASE_FACTOR`).  The launch's fetch handles stay in
         ``self._handles`` for a request that asked for its logits."""
         # the worker is the phase clock's one writer, so its reads
         # outside the lock see its own last write
@@ -1221,14 +1342,59 @@ class DecodeEngine:
         _watchdog.begin("decode")
         try:
             with self._phase("dispatch"):
+                _, since, exposed = self._phase_open
                 self._acquire(prepared)
                 handles = self._handles = prepared.run(feed)
+                step = prepared._cur    # the executable the feed bound
+                usual = self._usual_ns.get(step)
+                if usual is None:
+                    # bound by this dispatch (a compile or a cache load):
+                    # not judged, and not a launch to learn its usual
+                    # time from
+                    self._usual_ns[step] = 0
+                    step = None
+                self._yardstick_ns = usual = usual or 0
+                # on the ledger before the boundary, so that the phase
+                # that opens there is not starved
+                entry = [kind, handles[0], since, step, usual]
+                self._inflight.append(entry)
+            if exposed:     # a starved dispatch is no device time
+                entry[2] = self._phase_open[1]
             if fetch is not None:
                 with self._phase("sync"):
+                    waited = list(self._inflight)
+                    usual = [e[4] for e in waited]
+                    self._yardstick_ns = sum(usual) if all(usual) else 0
+                    # a launch found complete before the worker waits
+                    # on it (chunks pile up in a ramp and a dispatch
+                    # then blocks in the prepared step's own window)
+                    # was not seen to complete: booked, but not learnt
+                    # from
+                    done, seen = [], []
+                    for earlier in waited[:-1]:
+                        seen.append(not earlier[1].is_ready())
+                        earlier[1].block_until_ready()
+                        done.append(_now_ns())
+                    seen.append(not handles[fetch].is_ready())
                     out = handles[fetch].numpy()
+                    # off the ledger before the boundary: the device is
+                    # free again and the next phase is starved
+                    self._inflight.clear()
+                done.append(self._phase_open[1])
+                with self._stats_lock:
+                    for (k, _, since, step, _), t, saw in zip(
+                            waited, done, seen):
+                        held = t - max(self._device_free_ns, since)
+                        self._device_ns[k] += held
+                        self._device_free_ns = t
+                        if saw and step is not None:
+                            mean = self._usual_ns[step]
+                            self._usual_ns[step] = \
+                                mean + (held - mean) // 8 if mean else held
         finally:
             _watchdog.end("decode")
-        return out, ph["dispatch"] + ph["sync"] - launch0
+        return (out, ph["dispatch"] + ph["sync"] - launch0,
+                self._phase_open[1])
 
     def _run_prefill(self, admitted: List[_Seq]):
         sid = next_step_id()
@@ -1239,20 +1405,23 @@ class DecodeEngine:
                 feed, bucket = self._prefill_feed(admitted)
                 parent.set(bucket=f"{bucket[0]}x{bucket[1]}")
                 _flight.note_step(sid, "decode_prefill", bucket)
-            tokens, launch_ns = self._launch(self._prefill, feed, 1)
-            now = time.monotonic()
+                self._launching = ("prefill", len(admitted), bucket[1],
+                                   admitted[0].rid)
+            tokens, launch_ns, end_ns = self._launch(
+                "prefill", self._prefill, feed, 1)
             with self._phase("emit"):
                 self._first_tokens_out(
                     admitted, [int(tokens[seq._gather_idx])
                                for seq in admitted],
                     [seq._gather_idx for seq in admitted])
+            self._launching = None
         self._active.extend(admitted)
         with self._stats_lock:
             self._prefill_batches += 1
             self._host_syncs += 1
             self._launches["prefill"] += 1
             self._launch_ns["prefill"] += launch_ns
-            self._t_last = now
+            self._t_last = end_ns * 1e-9
 
     def _first_tokens_out(self, seqs: List[_Seq], toks: List[int],
                           rows: List[int]):
@@ -1325,18 +1494,19 @@ class DecodeEngine:
                 RecordEvent("decode::chunk", tokens=end - start,
                             final=final,
                             state_rows=int(bool(self._state_bytes))):
+            self._launching = ("chunk", 1, end - start, seq.rid)
             with self._phase("feed"):
                 feed = self._chunk_feed(seq, start, end, final)
             # only the FINAL chunk's first generated token crosses to
-            # the host — intermediate chunks stay async (their device
-            # time lands in a later launch's sync)
-            toks, launch_ns = self._launch(self._chunk, feed,
-                                           1 if final else None)
-            now = time.monotonic()
+            # the host — intermediate chunks stay async (a later
+            # launch's sync waits them out and books their device time)
+            toks, launch_ns, end_ns = self._launch(
+                "chunk", self._chunk, feed, 1 if final else None)
             seq._chunk_off = end
             if final:
                 with self._phase("emit"):
                     self._first_tokens_out([seq], [int(toks[0])], [0])
+            self._launching = None
         with self._stats_lock:
             self._chunk_steps += 1
             self._chunk_tokens += end - start
@@ -1344,7 +1514,7 @@ class DecodeEngine:
                 self._host_syncs += 1
             self._launches["chunk"] += 1
             self._launch_ns["chunk"] += launch_ns
-            self._t_last = now
+            self._t_last = end_ns * 1e-9
         if final:
             self._chunking.remove(seq)
             self._active.append(seq)
@@ -1447,6 +1617,7 @@ class DecodeEngine:
                 RecordEvent("decode::chain", live=len(live)) as parent:
             with self._phase("feed"):
                 length = self._pick_chain()
+                self._launching = ("chain", len(live), length, live[0].rid)
                 bucket_b = next(b for b in cfg.batch_buckets
                                 if b >= len(live))
                 parent.set(bucket=bucket_b, chain=length)
@@ -1456,8 +1627,8 @@ class DecodeEngine:
                 _flight.note_step(sid, "decode_chain",
                                   (length, bucket_b, len(live)))
             # tokens: [length, bucket_b]
-            tokens, launch_ns = self._launch(self._chains[length], feed, 0)
-            now = time.monotonic()
+            tokens, launch_ns, end_ns = self._launch(
+                "chain", self._chains[length], feed, 0)
             emitted = 0
             with self._phase("emit"):
                 for i, seq in enumerate(live):
@@ -1483,6 +1654,7 @@ class DecodeEngine:
                 ctx = feed["pos_ids"][None, :] + advanced + 1
                 pages_read = int(np.clip(-(-ctx // cfg.block_size), 1,
                                          self._mbps).sum())
+            self._launching = None
         with self._stats_lock:
             self._kv_pages_read += pages_read
             self._kv_pages_spanned += length * bucket_b * self._mbps
@@ -1501,7 +1673,7 @@ class DecodeEngine:
                 self._chain_hist.get(length, 0) + 1
             self._decode_batch_hist[len(live)] = \
                 self._decode_batch_hist.get(len(live), 0) + 1
-            self._t_last = now
+            self._t_last = end_ns * 1e-9
 
     def _emit(self, seq: _Seq, tok: int):
         seq.out_tokens.append(tok)
@@ -1519,7 +1691,9 @@ class DecodeEngine:
             seq.done = True
 
     def _retire(self):
-        with RecordEvent("decode::retire"):     # the phase it is in already
+        # the phase it is in already
+        with RecordEvent("decode::retire",
+                         {"starved": int(self._phase_open[2])}):
             self._retire_finished()
 
     def _retire_finished(self):
@@ -1825,16 +1999,24 @@ class DecodeEngine:
                 "prefill_tokens": self._prefill_tokens,
                 "launches": dict(self._launches),
                 "launch_ns": dict(self._launch_ns),
+                "device_ns": dict(self._device_ns),
+                "slow_phase_ns": dict(self._slow_phase_ns),
+                "slow_phases": [list(r) for r in self._slow_phases],
                 "admitted": self._admitted,
                 "queue_wait_ns": self._queue_wait_ns,
                 "first_tokens": self._first_tokens,
                 "first_token_ns": self._first_token_ns,
             }
             phase_ns = dict(self._phase_ns)
+            starved_ns = dict(self._starved_ns)
             if self._phase_open is not None:
-                name, t0 = self._phase_open
-                phase_ns[name] += _now_ns() - t0
+                name, t0, starved = self._phase_open
+                so_far = _now_ns() - t0
+                phase_ns[name] += so_far
+                if starved:
+                    starved_ns[name] += so_far
             out["phase_ns"] = phase_ns
+            out["starved_ns"] = starved_ns
         out["cache_blocks_used"] = self._blocks_in_use()
         out["state_slots_in_use"] = self._state_slots_in_use()
         out["compile_count"] = self.compiled_executables
