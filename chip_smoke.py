@@ -1311,8 +1311,113 @@ def _hybrid_paged_read(S: Sizes, cfg, rng):
              f"(smoke timing)")
 
 
+def _hybrid_chunk_read(S: Sizes, cfg, rng):
+    """Leg H's chunk read of bfloat16 K/V pools at the cell's shapes:
+    four rows of a 1 024-query chunk over a 1 024-page table — the
+    prompt's first chunk, one mid-table, a final chunk of 1 000 tokens
+    (24 padded query rows) and one ending at ``max_seq_len`` — NaN in
+    every slot no context owns.  ``paged_chunk_attn`` against the gather
+    + flash composition it replaces (on the pools with the NaN zeroed)
+    and against the float32 einsum of 64 sampled queries a row; then one
+    row's chunk at a typical start, each form timed over 12 chained
+    calls."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from paddle_tpu.ops.attention_ops import lower_cached_attention
+    from paddle_tpu.ops.pallas import paged_chunk as pc
+    interpret = pc.pltpu.InterpretParams() if S.dry else False
+    if S.dry:
+        sq, pages, bs, n_head, hidden = 32, 8, 16, 2, 256
+        chunks = ((0, 32), (40, 32), (70, 26), (96, 32))
+    else:
+        sq, pages, bs, n_head, hidden = 1024, 1024, 16, \
+            cfg.num_attention_heads, cfg.hidden_size
+        chunks = ((0, 1024), (3000, 1024), (11264, 1000), (15360, 1024))
+    rows = len(chunks)
+    ctx = np.array([s + n for s, n in chunks], np.int32)
+    q_pos = np.zeros((rows, sq), np.int32)
+    for i, (s, n) in enumerate(chunks):
+        q_pos[i, :n] = np.arange(s, s + n)
+    need = -(-ctx // bs)
+    nb = int(need.sum()) + 1
+    table = np.zeros((rows, pages), np.int32)
+    order = rng.permutation(nb - 1) + 1
+    at = 0
+    for i, n in enumerate(need):
+        table[i, :n] = order[at:at + n]
+        at += n
+
+    def slots(i):
+        return (table[i, :need[i]][:, None] * bs
+                + np.arange(bs)[None]).reshape(-1)[:ctx[i]]
+    owned = np.zeros((nb * bs,), bool)
+    for i in range(rows):
+        owned[slots(i)] = True
+    hole = jnp.asarray(owned.reshape(nb, bs, 1))
+    clean = [jax.random.normal(jax.random.PRNGKey(rng.randint(1 << 30)),
+                               (nb, bs, hidden), jnp.bfloat16)
+             for _ in range(2)]
+    pools = [jnp.where(hole, p, jnp.nan) for p in clean]
+    clean = [jnp.where(hole, p, 0) for p in clean]
+    q = jnp.asarray(rng.randn(rows, sq, hidden), jnp.bfloat16)
+    args = (q, *pools, jnp.asarray(table), jnp.asarray(ctx),
+            jnp.asarray(q_pos))
+
+    def kernel(q, kp, vp, tbl, ctx_, pos):
+        return pc.paged_chunk_attention(q, kp, vp, tbl, ctx_, pos,
+                                        n_head=n_head, interpret=interpret)
+
+    def gather(q, kp, vp, tbl, ctx_, pos):
+        ins = {"Q": [q], "KPool": [kp], "VPool": [vp], "BlockTable": [tbl],
+               "CtxLen": [ctx_], "QPos": [pos]}
+        return lower_cached_attention(None, ins, {"n_head": n_head},
+                                      use_flash=not S.dry)["Out"]
+    got = np.asarray(jax.jit(kernel)(*args).astype(jnp.float32))
+    assert np.isfinite(got).all()
+    off_gather = _rel(got, np.asarray(jax.jit(gather)(
+        q, *clean, *args[3:]).astype(jnp.float32)))
+    worst = 0.0
+    d = hidden // n_head
+    pick = rng.choice(sq, 64 if not S.dry else 8, replace=False)
+    with jax.default_matmul_precision("highest"):
+        for i in range(rows):
+            k, v = (p.reshape(-1, hidden)[slots(i)].astype(jnp.float32)
+                    .reshape(-1, n_head, d) for p in pools)
+            qi = q[i, pick].astype(jnp.float32).reshape(-1, n_head, d)
+            sc = jnp.einsum("shd,thd->hst", qi, k) * d ** -0.5
+            vis = np.arange(ctx[i])[None, :] <= q_pos[i, pick][:, None]
+            sc = jnp.where(jnp.asarray(vis)[None], sc, -jnp.inf)
+            want = jnp.einsum("hst,thd->shd", jax.nn.softmax(sc, -1), v)
+            worst = max(worst, _rel(got[i, pick],
+                                    np.asarray(want).reshape(len(pick), -1)))
+    _say(f"  paged_chunk_attn {rows} rows x {sq} queries x {n_head} heads "
+         f"of {d} over {nb} bf16 blocks of {bs} x {hidden} (chunks "
+         f"{chunks}), NaN outside the contexts: rel err {worst:.2e} against "
+         f"the float32 einsum of {len(pick)} queries a row, "
+         f"{off_gather:.2e} against the gather + flash composition")
+    # the output is bfloat16, as the query
+    assert worst < 2e-2 and off_gather < 2e-2, (worst, off_gather)
+    calls = 1 if S.dry else 12
+    one = (q[1:2], *pools, jnp.asarray(table[1:2]), jnp.asarray(ctx[1:2]),
+           jnp.asarray(q_pos[1:2]))
+    for name, fn, ops in (("paged_chunk_attn", kernel, one),
+                          ("gather + flash", gather,
+                           (one[0], *clean, *one[3:]))):
+        chained = jax.jit(lambda q, *rest, fn=fn: jax.lax.fori_loop(
+            0, calls, lambda _, q: fn(q, *rest), q))
+        chained(*ops).block_until_ready()
+        best = float("inf")
+        for _ in range(1 if S.dry else 3):
+            t0 = time.perf_counter()
+            chained(*ops).block_until_ready()
+            best = min(best, time.perf_counter() - t0)
+        _say(f"    {name}, one row's chunk at {chunks[1][0]}: "
+             f"{best * 1e3:.2f} ms per {calls} calls (smoke timing)")
+
+
 def _hybrid_kernels(S: Sizes, cfg, rng):
-    """Leg H's three kernels alone, at the cell's shapes."""
+    """Leg H's kernels alone, at the cell's shapes."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -1321,6 +1426,7 @@ def _hybrid_kernels(S: Sizes, cfg, rng):
     from paddle_tpu.ops.pallas import gated_delta as gd
     interpret = gd.pltpu.InterpretParams() if S.dry else False
     _hybrid_paged_read(S, cfg, rng)
+    _hybrid_chunk_read(S, cfg, rng)
 
     # -- the two delta-rule kernels alone ----------------------------------
     h, dk, dv = cfg.linear_num_key_heads, cfg.linear_key_head_dim, \
@@ -1512,7 +1618,8 @@ def leg_hybrid(S: Sizes, platform: str):
         engine.close(timeout=5.0)
     _check_routes(
         _routes_since(routes0), S,
-        want_hits=("paged_decode_attention_wide", "gdn_decode", "gdn_chunk"),
+        want_hits=("paged_decode_attention_wide", "paged_chunk_attention",
+                   "gdn_decode", "gdn_chunk"),
         allowed_fallbacks=())
 
 

@@ -360,6 +360,54 @@ def test_hybrid_decode_step_compiles_for_v5e(one_chip, no_compile_cache,
     assert not re.search(rf"f32\[(?:2,)?{pages},3840\]", kernel)
 
 
+@pytest.mark.parametrize("dtype,q_dtype", [
+    (jnp.bfloat16, jnp.bfloat16), (jnp.float32, jnp.float32),
+    (jnp.bfloat16, jnp.float32)],
+    ids=["bf16-pools", "f32-pools", "f32-query-bf16-pools"])
+def test_hybrid_chunk_attention_compiles_for_v5e(one_chip, no_compile_cache,
+                                                 dtype, q_dtype):
+    """The hybrid serving cell's chunk read at its own shapes, as it sits
+    in the chunk program: 1 024 queries written into the donated pools
+    (13 312 blocks of 16 x 3 840, 30 heads of 128) just before the
+    ``fused_attention`` with ``QPos`` reads them through a 1 024-page
+    table.  The paged chunk kernel takes it: no ``[1, T, H]`` gather of
+    the cache, no ``[Sq, T]`` bias, no flash kernel and no copy of a pool
+    exist in the compiled module."""
+    import re
+    from paddle_tpu.ops.pallas import lowering_target
+    from paddle_tpu.ops.registry import get_op
+
+    def sds(shape, dt=dtype):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    attn = {"n_head": 30, "_cached": True, "is_test": True}
+
+    def chunk(q, k_pool, v_pool, table, slots, ctx, pos):
+        kv = q.astype(dtype)
+        wrote = get_op("cache_write")(None, {
+            "KPool": [k_pool], "VPool": [v_pool], "K": [kv], "V": [kv],
+            "Slots": [slots]}, {})
+        k_pool, v_pool = wrote["KPoolOut"], wrote["VPoolOut"]
+        out = get_op("fused_attention")(None, {
+            "Q": [q], "KPool": [k_pool], "VPool": [v_pool],
+            "BlockTable": [table], "CtxLen": [ctx], "QPos": [pos]},
+            attn)["Out"]
+        return k_pool, v_pool, out
+
+    with lowering_target("tpu"):
+        txt = jax.jit(chunk, donate_argnums=(1, 2)).lower(
+            sds((1, 1024, 3840), q_dtype), sds((13312, 16, 3840)),
+            sds((13312, 16, 3840)), sds((1, 1024), jnp.int32),
+            sds((1, 1024), jnp.int32), sds((1,), jnp.int32),
+            sds((1, 1024), jnp.int32)).compile().as_text()
+    assert all(k in txt for k in _route_kernels("paged_chunk_attention"))
+    assert "flash_fwd" not in txt
+    assert not re.search(r"\[1,16384,3840\]", txt)
+    assert not re.search(r"\[1,1,1024,16384\]", txt)
+    moved = re.findall(r"= \w+\[13312,16,3840\]\S* copy\(", txt)
+    assert not moved, moved
+
+
 def test_hybrid_chunk_delta_rule_compiles_for_v5e(one_chip,
                                                   no_compile_cache):
     """A 1 024-token prefill chunk's linear-attention mixer at the
