@@ -59,6 +59,16 @@ checks what comes out by the repo's own means:
   its 64-row chains with both kernels inside, under a watchdog — serving
   four prompts of 1 024-12 288 tokens whose logits are held to the
   reference's full forward pass on the committed limits;
+* **leg W** — the grouped and windowed paged reads at Laguna-S-2.1's
+  widths (the ``laguna_s_serve.code_closed`` cell's): the wide decode
+  body's grouped scoring (``paged_gqa_decode``, 64 rows over a 2 304-page
+  table, contexts 1 / 16 / 17 / 511 / 512 / 513 / 528 / 30 000 beside
+  drawn ones) and ``paged_chunk_attn`` with K/V groups (four 1 024-query
+  chunks, the last ending at 31 024), each for a full layer (48 heads on 8
+  K/V heads) and a window layer (72 on 8, window 512), NaN in every slot
+  no context owns, against the gather route's arithmetic (the float32
+  einsum over the row's gathered positions with the causal and window
+  masks), under a watchdog; then each timed over 12 chained calls;
 * **leg C** — four chips (run when >= 4 devices are visible): Fleet dp4
   with the bucketed grad all-reduce at per-chip batch 96, dp4-vs-one-chip
   loss parity, one dp2 x tp2 step, one ZeRO-1 flat-shard-Adam step.
@@ -86,7 +96,7 @@ import re
 import sys
 import time
 
-LEGS = ("K", "A", "B", "M", "L", "H", "C")
+LEGS = ("K", "A", "B", "M", "L", "H", "W", "C")
 
 
 def _say(msg):
@@ -1624,6 +1634,177 @@ def leg_hybrid(S: Sizes, platform: str):
 
 
 # ---------------------------------------------------------------------------
+# leg W — grouped and windowed paged reads
+# ---------------------------------------------------------------------------
+
+def _paged_table(rng, ctx, pages, bs):
+    """Each row's live pages drawn from a shuffled pool (block 0 left
+    free): (table, blocks in the pool, each row's flat live slots)."""
+    import numpy as np
+    need = np.maximum(-(-ctx // bs), 1)
+    nb = int(need.sum()) + 1
+    table = np.zeros((len(ctx), pages), np.int32)
+    order = rng.permutation(nb - 1) + 1
+    at = 0
+    for i, n in enumerate(need):
+        table[i, :n] = order[at:at + n]
+        at += n
+    slots = [(table[i, :need[i]][:, None] * bs + np.arange(bs)[None])
+             .reshape(-1)[:ctx[i]] for i in range(len(ctx))]
+    return table, nb, slots
+
+
+def _gathered(rng, nb, bs, width, slots):
+    """bfloat16 K and V pools with NaN in every slot no row owns."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    owned = np.zeros((nb * bs,), bool)
+    for s in slots:
+        owned[s] = True
+    hole = jnp.asarray(owned.reshape(nb, bs, 1))
+    return [jnp.where(hole, jax.random.normal(
+        jax.random.PRNGKey(rng.randint(1 << 30)), (nb, bs, width),
+        jnp.bfloat16), jnp.nan) for _ in range(2)]
+
+
+def _grouped_want(q, k, v, q_pos, n_head, n_kv, window):
+    """The gather route's arithmetic on one row's gathered positions, in
+    float32: q [S, H] at positions ``q_pos`` against k, v [T, H_kv]."""
+    import jax
+    import jax.numpy as jnp
+    d = q.shape[-1] // n_head
+    group = n_head // n_kv
+    kh = jnp.repeat(k.astype(jnp.float32).reshape(-1, n_kv, d), group, 1)
+    vh = jnp.repeat(v.astype(jnp.float32).reshape(-1, n_kv, d), group, 1)
+    sc = jnp.einsum("shd,thd->hst", q.astype(jnp.float32).reshape(
+        -1, n_head, d), kh) * d ** -0.5
+    t = jnp.arange(k.shape[0])[None, :]
+    seen = t <= q_pos[:, None]
+    if window:
+        seen &= t > q_pos[:, None] - window
+    sc = jnp.where(seen[None], sc, -jnp.inf)
+    return jnp.einsum("hst,thd->shd", jax.nn.softmax(sc, -1), vh) \
+        .reshape(q.shape[0], -1)
+
+
+def _timed(name, fn, ops, S):
+    import jax
+    calls = 1 if S.dry else 12
+    chained = jax.jit(lambda q, *rest: jax.lax.fori_loop(
+        0, calls, lambda _, q: fn(q, *rest).astype(q.dtype), q))
+    chained(*ops).block_until_ready()
+    best = float("inf")
+    for _ in range(1 if S.dry else 3):
+        t0 = time.perf_counter()
+        chained(*ops).block_until_ready()
+        best = min(best, time.perf_counter() - t0)
+    _say(f"    {name}: {best * 1e3:.2f} ms per {calls} calls "
+         f"(smoke timing)")
+
+
+def leg_window(S: Sizes):
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from paddle_tpu.ops.pallas import paged_attention as pa
+    from paddle_tpu.ops.pallas import paged_chunk as pc
+    rng = np.random.RandomState(0)
+    interpret = pa.pltpu.InterpretParams() if S.dry else False
+    if S.dry:
+        rows, pages, bs, d, n_kv, window = 20, 16, 16, 128, 2, 48
+        edges = (1, 16, 17, 47, 48, 49, 64, 250)
+        chunks, sq = ((0, 32), (40, 32), (130, 26), (224, 32)), 32
+        kinds = ((4, 0), (6, window))
+    else:
+        rows, pages, bs, d, n_kv, window = 64, 2304, 16, 128, 8, 512
+        edges = (1, 16, 17, 511, 512, 513, 528, 30000)
+        chunks, sq = ((0, 1024), (3000, 1024), (11264, 1000),
+                      (30000, 1024)), 1024
+        kinds = ((48, 0), (72, window))
+    faulthandler.dump_traceback_later(900, exit=True)
+    try:
+        # -- decode: the wide body's grouped scoring -----------------------
+        ctx = np.zeros((rows,), np.int32)
+        drawn = rows - 8 - len(edges)           # 8 pad rows at the end
+        ctx[:drawn] = np.clip(np.round(np.exp(rng.randn(drawn) * 0.8)
+                                       * (pages * bs // 6)), 1,
+                              pages * bs - 1)
+        ctx[drawn:drawn + len(edges)] = edges
+        table, nb, slots = _paged_table(rng, ctx, pages, bs)
+        pools = _gathered(rng, nb, bs, n_kv * d, slots)
+        for n_head, win in kinds:
+            q = jnp.asarray(rng.randn(rows, 1, n_head * d), jnp.float32)
+            args = (q, *pools, jnp.asarray(table), jnp.asarray(ctx))
+            fn = functools.partial(pa.paged_gqa_decode,
+                                   n_head=n_head, num_kv_heads=n_kv,
+                                   window=win, interpret=interpret)
+            got = np.asarray(jax.jit(fn)(*args))
+            assert np.isfinite(got).all()
+            assert not got[ctx == 0].any(), "a row with nothing live wrote"
+            worst = 0.0
+            with jax.default_matmul_precision("highest"):
+                for i in list(range(3)) + list(range(drawn,
+                                                     drawn + len(edges))):
+                    k, v = (p.reshape(-1, n_kv * d)[slots[i]]
+                            for p in pools)
+                    want = _grouped_want(q[i], k, v,
+                                         jnp.asarray([ctx[i] - 1]), n_head,
+                                         n_kv, win)
+                    worst = max(worst, _rel(got[i], np.asarray(want)))
+            _say(f"  paged_gqa_decode {rows} rows x {n_head} heads on {n_kv} "
+                 f"of {d}, window {win}, over {nb} bf16 blocks, "
+                 f"{int(ctx.sum())} live positions (edges {edges}), NaN "
+                 f"outside them: rel err {worst:.2e} against the gathered "
+                 f"rows")
+            # float32 arithmetic over bfloat16 pages
+            assert worst < 1e-4, worst
+            _timed(f"paged_gqa_decode, {n_head} heads, window {win}", fn,
+                   args, S)
+        del pools
+        # -- chunks: paged_chunk_attn with K/V groups and a window ---------
+        ctx = np.array([s + n for s, n in chunks], np.int32)
+        q_pos = np.zeros((len(chunks), sq), np.int32)
+        for i, (s, n) in enumerate(chunks):
+            q_pos[i, :n] = np.arange(s, s + n)
+        table, nb, slots = _paged_table(rng, ctx, pages, bs)
+        pools = _gathered(rng, nb, bs, n_kv * d, slots)
+        pick = rng.choice(sq, 8 if S.dry else 64, replace=False)
+        for n_head, win in kinds:
+            q = jnp.asarray(rng.randn(len(chunks), sq, n_head * d),
+                            jnp.bfloat16)
+            args = (q, *pools, jnp.asarray(table), jnp.asarray(ctx),
+                    jnp.asarray(q_pos))
+            fn = functools.partial(pc.paged_chunk_attention, n_head=n_head,
+                                   num_kv_heads=n_kv, window=win,
+                                   interpret=interpret)
+            got = np.asarray(jax.jit(fn)(*args).astype(jnp.float32))
+            assert np.isfinite(got).all()
+            worst = 0.0
+            with jax.default_matmul_precision("highest"):
+                for i in range(len(chunks)):
+                    k, v = (p.reshape(-1, n_kv * d)[slots[i]]
+                            for p in pools)
+                    want = _grouped_want(q[i, pick], k, v,
+                                         jnp.asarray(q_pos[i, pick]),
+                                         n_head, n_kv, win)
+                    worst = max(worst, _rel(got[i, pick], np.asarray(want)))
+            _say(f"  paged_chunk_attn {len(chunks)} rows x {sq} queries x "
+                 f"{n_head} heads on {n_kv} of {d}, window {win} (chunks "
+                 f"{chunks}), NaN outside the contexts: rel err "
+                 f"{worst:.2e} against the float32 einsum of {len(pick)} "
+                 f"queries a row")
+            # the output is bfloat16, as the query
+            assert worst < 2e-2, worst
+            _timed(f"paged_chunk_attn, {n_head} heads, window {win}, one "
+                   f"row's chunk at {chunks[1][0]}", fn,
+                   (q[1:2], *pools, jnp.asarray(table[1:2]),
+                    jnp.asarray(ctx[1:2]), jnp.asarray(q_pos[1:2])), S)
+    finally:
+        faulthandler.cancel_dump_traceback_later()
+
+
+# ---------------------------------------------------------------------------
 # leg C — four chips
 # ---------------------------------------------------------------------------
 
@@ -1799,7 +1980,7 @@ def main(argv=None):
                     help="tiny width on the CPU backend, to debug this "
                          "script; proves nothing about the chip")
     ap.add_argument("--legs", default=",".join(LEGS),
-                    help="comma-separated subset of K,A,B,M,L,H,C")
+                    help="comma-separated subset of K,A,B,M,L,H,W,C")
     args = ap.parse_args(argv)
     legs = [x.strip().upper() for x in args.legs.split(",") if x.strip()]
     if not legs or set(legs) - set(LEGS):
@@ -1850,6 +2031,7 @@ def main(argv=None):
            "M": lambda: leg_decoder_lm(S, device["platform"]),
            "L": lambda: leg_latent(S, device["platform"]),
            "H": lambda: leg_hybrid(S, device["platform"]),
+           "W": lambda: leg_window(S),
            "C": lambda: leg_four_chips(S, device["platform"])}
     summary = []
     t_all = time.perf_counter()

@@ -241,12 +241,14 @@ def rms_norm(input, epsilon=1e-6, param_attr=None, name=None):
 
 def rotary_embedding(input, head_dim, rope_theta=10000.0,
                      rope_type="default", name=None, pos=None,
-                     interleaved=False, rotary_dim=None, **yarn):
+                     interleaved=False, rotary_dim=None, leading=False,
+                     **yarn):
     """Rotary position embedding (rotate-half form) of every head of a
     ``[B, S, heads * head_dim]`` projection at positions ``0..S-1``, or
     at ``pos`` ``[B, S]`` when given (a served decoder's positions come
     from its feeds).  ``interleaved`` rotates ADJACENT pairs together;
-    ``rotary_dim`` rotates only the last so many columns of every head.
+    ``rotary_dim`` rotates only the last so many columns of every head,
+    or under ``leading`` the first so many (a ``partial_rotary_factor``).
     ``rope_type="yarn"`` takes ``factor``,
     ``original_max_position_embeddings``, ``beta_fast``, ``beta_slow``
     and ``attention_factor`` as the published configs name them."""
@@ -261,6 +263,8 @@ def rotary_embedding(input, head_dim, rope_theta=10000.0,
         attrs["interleaved"] = True
     if rotary_dim and int(rotary_dim) != int(head_dim):
         attrs["rotary_dim"] = int(rotary_dim)
+        if leading:
+            attrs["rotary_leading"] = True
     helper.append_op(type="rotary_embedding", inputs=inputs,
                      outputs={"Out": [out]}, attrs=attrs)
     return out

@@ -82,8 +82,10 @@ class HybridDecoderConfig:
         if self.num_key_value_heads != self.num_attention_heads or \
                 self.linear_num_key_heads != self.linear_num_value_heads:
             raise ValueError(
-                "grouped K/V heads are not served by the cached attention "
-                "routes, nor grouped key heads by gated_delta_rule")
+                "grouped K/V heads are not built by this decoder's full "
+                "layers (their pools are hidden_size wide; "
+                "models/window_decoder.py serves grouped heads), nor "
+                "grouped key heads by gated_delta_rule")
 
     @staticmethod
     def tiny(**kw):

@@ -85,6 +85,35 @@ def _cache_write(ctx, ins, attrs):
             "VPoolOut": new_v.reshape(vpool.shape)}
 
 
+@register("window_ring")
+def _window_ring(ctx, ins, attrs):
+    """Where a sliding window's K/V live: a RING of ``ring_pages`` pages
+    a sequence, in a pool of ``[slots * ring_pages, block_size, H]``
+    (slot ``s``'s ring is pages ``s * ring_pages ..``).  Position ``p`` of
+    the sequence in slot ``StateSlot`` lives in ring page ``p //
+    block_size % ring_pages``, so a page is overwritten ``ring_pages``
+    pages later; a ring of ``ceil((window + launch - 1) / block_size) +
+    1`` pages holds every position a launch of ``launch`` tokens a row
+    still reads (serving/decode.py sizes it).
+
+    Outputs ``RingSlots`` ``[B, S]``: the flat slot each written token
+    goes to (``-1`` where ``Slots`` is -1: padding stays dropped), and,
+    with ``table_pages``, ``Table`` ``[B, table_pages]``: logical page
+    ``j`` of each row -> its ring page, the block table a cached read of
+    the window layers walks like any other."""
+    slot = x(ins, "StateSlot").astype(jnp.int32)
+    pos = x(ins, "Pos").astype(jnp.int32)
+    valid = x(ins, "Slots").astype(jnp.int32).reshape(pos.shape) >= 0
+    ring, bs = int(attrs["ring_pages"]), int(attrs["block_size"])
+    base = slot[:, None] * ring
+    out = {"RingSlots": jnp.where(
+        valid, (base + pos // bs % ring) * bs + pos % bs, -1)}
+    if attrs.get("table_pages"):
+        pages = jnp.arange(int(attrs["table_pages"]), dtype=jnp.int32)
+        out["Table"] = base + pages[None, :] % ring
+    return out
+
+
 def gather_cache(pool, block_table, block_size=None):
     """Gather a per-sequence context ``[B, T, H]`` out of the pool
     through the block table (``T = max_blocks_per_seq * block_size``).

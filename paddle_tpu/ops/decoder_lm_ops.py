@@ -105,7 +105,8 @@ def _rotary_embedding(ctx, ins, attrs):
     Rotate-half pairing, or under ``interleaved`` ADJACENT pairs
     ``(x_2i, x_2i+1)`` together (the DeepSeek family's published form);
     under ``rotary_dim`` only the LAST ``rotary_dim`` columns of every
-    head rotate (a head of ``[nope | rope]`` parts)."""
+    head rotate (a head of ``[nope | rope]`` parts), or under
+    ``rotary_leading`` the FIRST (a ``partial_rotary_factor``)."""
     xv = x(ins, "X")
     b, s, width = xv.shape
     d = int(attrs["head_dim"])
@@ -117,7 +118,9 @@ def _rotary_embedding(ctx, ins, attrs):
     inv_freq, factor = rope_inv_freq(r, attrs)
     freqs = pos.astype(jnp.float32)[:, :, None, None] * inv_freq
     heads = xv.reshape(b, s, width // d, d)
-    xh = heads[..., d - r:].astype(jnp.float32)
+    leading = bool(attrs.get("rotary_leading"))
+    xh = (heads[..., :r] if leading else heads[..., d - r:]) \
+        .astype(jnp.float32)
     if attrs.get("interleaved"):
         cos, sin = jnp.cos(freqs) * factor, jnp.sin(freqs) * factor
         x1, x2 = xh[..., 0::2], xh[..., 1::2]
@@ -130,7 +133,8 @@ def _rotary_embedding(ctx, ins, attrs):
         out = xh * (jnp.cos(emb) * factor) + rot * (jnp.sin(emb) * factor)
     out = out.astype(xv.dtype)
     if r < d:
-        out = jnp.concatenate([heads[..., :d - r], out], axis=-1)
+        out = jnp.concatenate([out, heads[..., r:]] if leading
+                              else [heads[..., :d - r], out], axis=-1)
     return {"Out": out.reshape(b, s, width)}
 
 
@@ -153,6 +157,8 @@ def _moe_topk_router(ctx, ins, attrs):
                           int(attrs["top_k"]))
     if attrs.get("norm_topk_prob", True):
         vals = vals / jnp.sum(vals, axis=-1, keepdims=True)
+    if "routed_scale" in attrs:
+        vals = vals * float(attrs["routed_scale"])
     return {"TopkWeight": vals, "TopkIndex": idx.astype(jnp.int32)}
 
 

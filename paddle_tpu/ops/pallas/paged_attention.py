@@ -79,6 +79,14 @@ with one buffer 86.6; with two buffers 49.7 — 90 % of what the pages'
 bytes take at the HBM peak (45 ms), so a step's size no longer matters
 (49.7 at 4 and at 8 pages, 50.1 at 16).
 
+*Grouped* (:func:`paged_gqa_decode`, kernel ``paged_gqa_decode``; grouped
+K/V heads or a sliding window — ``laguna_s_serve.code_closed``: 48 or 72
+heads of 128 on 8 K/V heads, window 512).  The wide body's page walk and
+two buffers; the query rows of one K/V head (its group, padded to whole
+sublane tiles) are one MXU operand against that head's lanes of the
+pages, and under a window a row's walk starts at the page of ``ctx -
+window``.
+
 What a paged read must get right and the gather never met (each has its
 case in tests/test_paged_decode_attention.py): positions ``>= ctx_len``
 in the last live page and the dead pages of a row's last step (a reused
@@ -315,6 +323,17 @@ def supported_wide(sq, hidden, n_head, block_size, dtype="float32",
     return True, ""
 
 
+def supported_gqa(sq, hidden, n_head, n_kv, block_size, dtype="float32",
+                  has_qpos=False):
+    """Static shape rule -> (ok, reason) of the wide body's grouped form:
+    ``n_head`` query heads of ``hidden / n_head`` on ``n_kv`` K/V heads
+    (pools ``n_kv`` heads wide), whole lane tiles each."""
+    if n_head <= 0 or n_kv <= 0 or hidden % n_head or n_head % n_kv:
+        return False, f"paged-gqa:heads:{n_head}/{n_kv}"
+    d = hidden // n_head
+    return supported_wide(sq, n_kv * d, n_kv, block_size, dtype, has_qpos)
+
+
 def _mxu_lhs(a, dtype):
     """The (8, K) float32 operand ``a`` as the MXU takes it against pages
     of ``dtype``.  Float32 pages: as it is (the product runs at
@@ -331,16 +350,16 @@ def _mxu_lhs(a, dtype):
                            axis=0).astype(jnp.bfloat16)
 
 
-def _mxu_dot(lhs, pages, dims):
-    """``lhs`` (from :func:`_mxu_lhs`) times a slab of pages, float32
-    accumulation, (8, N): the pages go to the MXU in their own dtype."""
+def _mxu_dot(lhs, pages, dims, g=HEAD_GROUP):
+    """``lhs`` (from :func:`_mxu_lhs` of ``g`` rows) times a slab of
+    pages, float32 accumulation, (g, N): the pages go to the MXU in their
+    own dtype."""
     if pages.dtype == jnp.float32:
         return lax.dot_general(lhs, pages, dims,
                                precision=lax.Precision.HIGHEST,
                                preferred_element_type=jnp.float32)
     o = lax.dot_general(lhs, pages, dims,
                         preferred_element_type=jnp.float32)
-    g = HEAD_GROUP
     return o[:g] + o[g:2 * g] + o[2 * g:3 * g]
 
 
@@ -348,33 +367,40 @@ _NT = (((1,), (1,)), ((), ()))      # (M, K) x (N, K) -> (M, N)
 _NN = (((1,), (0,)), ((), ()))      # (M, K) x (K, N) -> (M, N)
 
 
-def _kernel_wide(ctx_ref, tbl_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf,
-                 sem, *, head_dim, scale, prefetch):
-    n_rows, h = q_ref.shape
+def _page_walk(ctx_ref, tbl_ref, k_hbm, v_hbm, kbuf, vbuf, sem, n_rows,
+               window=0):
+    """The wide body's page copies, shared by its two scorings: (first
+    page of a row, live pages of a row, start step ``c`` of row ``b`` into
+    buffer ``slot``, wait for it).  A row's live pages run from page 0,
+    or under a ``window`` from the page of its first visible position
+    (``ctx - window``), to the page of its last; a dead page is neither
+    started nor waited for."""
     num_blocks, bs, _ = k_hbm.shape
     pages_per_seq = tbl_ref.shape[0] // n_rows
-    rows = kbuf.shape[1]            # positions one step copies and scores
-    pps = rows // bs                # pages a step
-    g = HEAD_GROUP
-    # a group: g heads side by side, the last one what is left
-    groups = [slice(at, min(at + g * head_dim, h))
-              for at in range(0, h, g * head_dim)]
+    pps = kbuf.shape[1] // bs       # pages a step
 
     def live_pages(b):
         # at least page 0, at most the table
         return jnp.clip((ctx_ref[b] + bs - 1) // bs, 1, pages_per_seq)
 
+    def first_page(b):
+        if not window:
+            return 0
+        ctx = jnp.minimum(ctx_ref[b], pages_per_seq * bs)
+        return jnp.maximum(ctx - window, 0) // bs
+
     def copies(b, c, slot):
         """The page copies of step ``c`` of row ``b`` into buffer
-        ``slot``, each with whether its page is live: a dead page is
-        neither started nor waited for."""
+        ``slot``, each with whether its page is live."""
         out, live = [], live_pages(b)
+        first = first_page(b) if window else None
         for j in range(pps):
-            entry = tbl_ref[b * pages_per_seq
-                            + jnp.minimum(c * pps + j, pages_per_seq - 1)]
+            page = None if first is None else first + c * pps + j
+            entry = tbl_ref[b * pages_per_seq + jnp.minimum(
+                c * pps + j if page is None else page, pages_per_seq - 1)]
             blk = jnp.clip(entry, 0, num_blocks - 1)
             dst = pl.ds(j * bs, bs)
-            out.append((c * pps + j < live,
+            out.append((c * pps + j < live if page is None else page < live,
                         pltpu.make_async_copy(k_hbm.at[blk],
                                               kbuf.at[slot, dst],
                                               sem.at[slot]),
@@ -397,6 +423,35 @@ def _kernel_wide(ctx_ref, tbl_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf,
                 ck.wait()
                 cv.wait()
 
+    return first_page, live_pages, start, wait
+
+
+def _prefetch(start, b, c, steps, slot, n_rows):
+    """Start the copies of this row's next step, or of the next row's
+    first, into the other buffer."""
+    more = c + 1 < steps
+    nb = jnp.where(more, b, b + 1)
+
+    @pl.when(nb < n_rows)
+    def _():
+        start(jnp.minimum(nb, n_rows - 1), jnp.where(more, c + 1, 0),
+              1 - slot)
+
+
+def _kernel_wide(ctx_ref, tbl_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf,
+                 sem, *, head_dim, scale, prefetch):
+    n_rows, h = q_ref.shape
+    _, bs, _ = k_hbm.shape
+    pages_per_seq = tbl_ref.shape[0] // n_rows
+    rows = kbuf.shape[1]            # positions one step copies and scores
+    pps = rows // bs                # pages a step
+    g = HEAD_GROUP
+    # a group: g heads side by side, the last one what is left
+    groups = [slice(at, min(at + g * head_dim, h))
+              for at in range(0, h, g * head_dim)]
+    _, live_pages, start, wait = _page_walk(
+        ctx_ref, tbl_ref, k_hbm, v_hbm, kbuf, vbuf, sem, n_rows)
+
     # row r of a group's operand is head r of the group: it keeps that
     # head's lanes and is zero in the other heads'
     own = (lax.broadcasted_iota(jnp.int32, (g, h), 1) // head_dim) % g \
@@ -413,14 +468,7 @@ def _kernel_wide(ctx_ref, tbl_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf,
             m, l, acc = state
             slot = (first_slot + c) % 2
             if prefetch:
-                # this row's next step, or the next row's first
-                more = c + 1 < steps
-                nb = jnp.where(more, b, b + 1)
-
-                @pl.when(nb < n_rows)
-                def _():
-                    start(jnp.minimum(nb, n_rows - 1),
-                          jnp.where(more, c + 1, 0), 1 - slot)
+                _prefetch(start, b, c, steps, slot, n_rows)
             else:
                 start(b, c, slot)
             wait(b, c, slot)
@@ -470,6 +518,84 @@ def _kernel_wide(ctx_ref, tbl_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf,
     lax.fori_loop(0, n_rows, row_body, 0)
 
 
+def _kernel_gqa(ctx_ref, tbl_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf,
+                sem, *, head_dim, rows_per_kv, window):
+    """The wide body's walk with grouped K/V heads and a window: the
+    ``rows_per_kv`` query rows of K/V head ``k`` (its ``group`` heads,
+    padded to whole sublane tiles, scaled on the way in) are ONE MXU
+    operand against that head's lanes of the pages, ``[rows_per_kv,
+    positions]`` a head; ``p . V`` the same way.  Keys outside ``[ctx -
+    window, ctx)`` get an exactly-zero weight, and their V rows are zero
+    before the MXU meets them."""
+    n_rows = q_ref.shape[0]
+    n_kv = k_hbm.shape[2] // head_dim
+    _, bs, _ = k_hbm.shape
+    pages_per_seq = tbl_ref.shape[0] // n_rows
+    rows = kbuf.shape[1]            # positions one step copies and scores
+    pps = rows // bs                # pages a step
+    gp, d = rows_per_kv, head_dim
+    first_page, live_pages, start, wait = _page_walk(
+        ctx_ref, tbl_ref, k_hbm, v_hbm, kbuf, vbuf, sem, n_rows, window)
+    at_s = lax.broadcasted_iota(jnp.int32, (n_kv * gp, rows), 1)
+    at_v = lax.broadcasted_iota(jnp.int32, (rows, n_kv * d), 0)
+
+    def row_body(b, first_slot):
+        ctx = jnp.minimum(ctx_ref[b], pages_per_seq * bs)
+        lo = jnp.maximum(ctx - window, 0) if window else None
+        first = first_page(b)
+        steps = (live_pages(b) - first + pps - 1) // pps
+        q = q_ref[b]
+        lhs = [_mxu_lhs(q[k * gp:(k + 1) * gp], kbuf.dtype)
+               for k in range(n_kv)]
+
+        def step_body(c, state):
+            m, l, acc = state
+            slot = (first_slot + c) % 2
+            _prefetch(start, b, c, steps, slot, n_rows)
+            wait(b, c, slot)
+            at = (first + c * pps) * bs     # position of the step's row 0
+            edge = at + rows > ctx
+            if lo is not None:
+                edge = edge | (at < lo)
+
+            def visible(pos):
+                ok = pos < ctx
+                return ok if lo is None else ok & (pos >= lo)
+
+            @pl.when(edge)
+            def _():
+                # 0 * NaN is NaN: a V row outside the visible keys is
+                # zero before the MXU meets it
+                v = vbuf[slot]
+                vbuf[slot] = jnp.where(visible(at + at_v), v,
+                                       jnp.zeros_like(v))
+
+            s = jnp.concatenate(
+                [_mxu_dot(lhs[k], kbuf[slot, :, k * d:(k + 1) * d], _NT, gp)
+                 for k in range(n_kv)], axis=0)
+            s = jnp.where(visible(at + at_s), s, MASKED)
+            m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.exp(s - m_new)
+            l = alpha * l + jnp.sum(p, axis=1, keepdims=True)
+            pv = jnp.concatenate(
+                [_mxu_dot(_mxu_lhs(p[k * gp:(k + 1) * gp], vbuf.dtype),
+                          vbuf[slot, :, k * d:(k + 1) * d], _NN, gp)
+                 for k in range(n_kv)], axis=0)
+            return m_new, l, alpha * acc + pv
+
+        m, l, acc = lax.fori_loop(
+            0, steps, step_body,
+            (jnp.full((n_kv * gp, 1), EMPTY, jnp.float32),
+             jnp.zeros((n_kv * gp, 1), jnp.float32),
+             jnp.zeros((n_kv * gp, d), jnp.float32)))
+        o_ref[b] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+        return (first_slot + steps) % 2
+
+    start(0, 0, 0)
+    lax.fori_loop(0, n_rows, row_body, 0)
+
+
 @functools.partial(jax.jit, static_argnames=("n_head", "pages_per_step",
                                              "prefetch", "interpret"))
 def paged_decode_attention_wide(q, k_pool, v_pool, block_table, ctx_len, *,
@@ -514,4 +640,67 @@ def paged_decode_attention_wide(q, k_pool, v_pool, block_table, ctx_len, *,
         name="paged_decode_attn_wide",
     )(ctx_len.astype(jnp.int32), block_table.reshape(-1).astype(jnp.int32),
       q.reshape(b, h).astype(jnp.float32), k_pool, v_pool)
+    return out.reshape(b, 1, h).astype(q.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "n_head", "num_kv_heads", "window", "pages_per_step", "interpret"))
+def paged_gqa_decode(q, k_pool, v_pool, block_table, ctx_len, *, n_head,
+                     num_kv_heads, window=0,
+                     pages_per_step=PAGES_PER_STEP_WIDE, interpret=False):
+    """:func:`paged_decode_attention_wide` over grouped K/V heads and a
+    window (:func:`_kernel_gqa`): the pools hold ``num_kv_heads`` heads
+    and query head ``h`` reads K/V head ``h // (n_head / num_kv_heads)``;
+    under ``window`` a row sees its last ``window`` positions only (``ctx
+    - window <= t < ctx``).  Raises ValueError for what supported_gqa()
+    rejects — call it first."""
+    n_kv, window = int(num_kv_heads), int(window or 0)
+    b, sq, h = q.shape
+    _, bs, hkv = k_pool.shape
+    pages_per_seq = block_table.shape[1]
+    ok, why = supported_gqa(sq, h, n_head, n_kv, bs, k_pool.dtype)
+    if not ok or hkv * n_head != h * n_kv:
+        raise ValueError(f"paged_gqa_decode: unsupported "
+                         f"({why or f'pool width {hkv}'})")
+    d, group = h // n_head, n_head // n_kv
+    # a K/V head's query rows, in whole sublane tiles
+    gp = -(-group // SUBLANES) * SUBLANES
+    qg = q.reshape(b, n_kv, group, d).astype(jnp.float32) \
+        * (1.0 / math.sqrt(d))
+    qg = jnp.pad(qg, ((0, 0), (0, 0), (0, gp - group), (0, 0)))
+    page_bytes = bs * hkv * k_pool.dtype.itemsize
+    pps = max(1, min(int(pages_per_step), pages_per_seq,
+                     WIDE_BUFFER_BYTES // (4 * page_bytes)))
+    # the pages a row reads at most
+    pages = min(pages_per_seq, -(-window // bs) + 1) if window \
+        else pages_per_seq
+    read_bytes = 2 * b * pages * page_bytes
+    # query and output blocks (two buffers each) beside the page buffers
+    vmem = 4 * b * n_kv * gp * d * 4 + 4 * pps * page_bytes
+    out = pl.pallas_call(
+        functools.partial(_kernel_gqa, head_dim=d, rows_per_kv=gp,
+                          window=window),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(1,),
+            in_specs=[pl.BlockSpec((b, n_kv * gp, d),
+                                   lambda i, *_: (0, 0, 0)),
+                      pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((b, n_kv * gp, d),
+                                   lambda i, *_: (0, 0, 0)),
+            scratch_shapes=[pltpu.VMEM((2, pps * bs, hkv), k_pool.dtype),
+                            pltpu.VMEM((2, pps * bs, hkv), v_pool.dtype),
+                            pltpu.SemaphoreType.DMA((2,))]),
+        out_shape=jax.ShapeDtypeStruct((b, n_kv * gp, d), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=max(32 << 20, vmem + (8 << 20))),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * group * read_bytes, bytes_accessed=read_bytes,
+            transcendentals=group * read_bytes // (2 * d)),
+        interpret=interpret,
+        name="paged_gqa_decode",
+    )(ctx_len.astype(jnp.int32), block_table.reshape(-1).astype(jnp.int32),
+      qg.reshape(b, n_kv * gp, d), k_pool, v_pool)
+    out = out.reshape(b, n_kv, gp, d)[:, :, :group]
     return out.reshape(b, 1, h).astype(q.dtype)

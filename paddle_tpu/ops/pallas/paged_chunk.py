@@ -84,12 +84,15 @@ def q_block(sq: int) -> int:
 
 
 def supported(sq, hidden, n_head, block_size, dtype="float32",
-              has_qpos=True):
+              has_qpos=True, n_kv=None):
     """Static shape rule -> (ok, reason): is this cache-read attention a
     chunk the kernel takes?  A query of more than one token with
     ``QPos``, float32 or bfloat16 pools in pages of whole sublane tiles,
-    heads of whole lane tiles, and a chunk that divides into query blocks
-    of whole bfloat16 tiles."""
+    heads of whole lane tiles (``n_kv`` K/V heads, a divisor of
+    ``n_head``), and a chunk that divides into query blocks of whole
+    bfloat16 tiles."""
+    if n_kv is not None and (n_kv <= 0 or n_head % n_kv):
+        return False, f"paged-chunk:heads:{n_head}/{n_kv}"
     if not has_qpos:
         return False, "paged-chunk:no-qpos"
     if sq <= 1:
@@ -106,12 +109,14 @@ def supported(sq, hidden, n_head, block_size, dtype="float32",
     return True, ""
 
 
-def group_heads(n_head: int, head_dim: int, itemsize: int) -> int:
-    """Heads of one grid step: the most that divide ``n_head`` and fit
-    ``GROUP_BYTES`` at ``itemsize`` bytes a lane (at least one)."""
-    return max([g for g in range(1, n_head + 1)
+def group_heads(n_head: int, head_dim: int, itemsize: int,
+                kv_group: int = 1) -> int:
+    """Heads of one grid step: the most that divide ``n_head``, are whole
+    groups of the ``kv_group`` query heads one K/V head serves, and fit
+    ``GROUP_BYTES`` at ``itemsize`` bytes a lane (at least one group)."""
+    return max([g for g in range(kv_group, n_head + 1, kv_group)
                 if n_head % g == 0
-                and g * head_dim * itemsize <= GROUP_BYTES] or [1])
+                and g * head_dim * itemsize <= GROUP_BYTES] or [kv_group])
 
 
 def frontiers(q_pos, ctx_len, sq, positions):
@@ -131,6 +136,19 @@ def frontiers(q_pos, ctx_len, sq, positions):
     return hi, lo, bound[:, :, None]
 
 
+def window_bounds(q_pos, ctx_len, sq, window):
+    """Under a ``window`` (key ``t`` visible to query ``p`` iff ``p -
+    window < t``), per query block of each row ``(first, edge)``: ``first``
+    the block's first visible key (its earliest query's), ``edge`` the
+    first key every query of the block sees from below (its latest
+    query's); and ``lower`` ``[B, Sq, 1]``, each query's own."""
+    xp = jnp if isinstance(q_pos, jax.Array) else np
+    b = q_pos.shape[0]
+    lower = xp.maximum(q_pos.astype(xp.int32) - (window - 1), 0)
+    blocks = lower.reshape(b, sq // q_block(sq), q_block(sq))
+    return blocks.min(axis=2), blocks.max(axis=2), lower[:, :, None]
+
+
 def pages_read(hi, block_size: int, pages_per_seq: int):
     """The pages each query block fetches: page 0 at least, the table at
     most."""
@@ -138,21 +156,49 @@ def pages_read(hi, block_size: int, pages_per_seq: int):
 
 
 def _kernel(tbl_ref, hi_ref, lo_ref, q_ref, bound_ref, k_hbm, v_hbm, o_ref,
-            kbuf, vbuf, sem, m_ref, l_ref, acc_ref, *, heads, head_dim,
-            pages_per_seq, scale, exact):
+            kbuf, vbuf, sem, m_ref, l_ref, acc_ref, **kw):
+    _chunk_body(tbl_ref, hi_ref, lo_ref, q_ref, bound_ref, k_hbm, v_hbm,
+                o_ref, kbuf, vbuf, sem, m_ref, l_ref, acc_ref, **kw)
+
+
+def _kernel_window(tbl_ref, hi_ref, lo_ref, first_ref, edge_ref, q_ref,
+                   bound_ref, lower_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf,
+                   sem, m_ref, l_ref, acc_ref, **kw):
+    _chunk_body(tbl_ref, hi_ref, lo_ref, q_ref, bound_ref, k_hbm, v_hbm,
+                o_ref, kbuf, vbuf, sem, m_ref, l_ref, acc_ref,
+                window=(first_ref, edge_ref, lower_ref), **kw)
+
+
+def _chunk_body(tbl_ref, hi_ref, lo_ref, q_ref, bound_ref, k_hbm, v_hbm,
+                o_ref, kbuf, vbuf, sem, m_ref, l_ref, acc_ref, *, heads,
+                head_dim, pages_per_seq, scale, exact, kv_group=1,
+                window=None):
+    """One grid step: ``heads`` query heads of one query block, against
+    their ``heads / kv_group`` K/V heads' lanes of the pages.  ``window``:
+    the refs of the block's first visible key, the first key all its
+    queries see from below, and each query's lower bound; the walk then
+    starts at the key block of the first."""
     b, j, g = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     n_q = pl.num_programs(1)
     num_blocks, bs, _ = k_hbm.shape
     rows = kbuf.shape[1]            # positions one step copies and scores
     pps = rows // bs                # pages a step
     width = heads * head_dim
-    # the group's lanes of every page
-    cols = pl.ds(pl.multiple_of(g * width, LANES), width)
+    kv_width = width // kv_group
+    # the group's K/V lanes of every page
+    cols = pl.ds(pl.multiple_of(g * kv_width, LANES), kv_width)
     hi = hi_ref[b * n_q + j]
     pages = jnp.clip((hi + bs - 1) // bs, 1, pages_per_seq)
     steps = (pages + pps - 1) // pps
     # steps wholly below every query's bound need no mask
     unmasked = lo_ref[b * n_q + j] // rows
+    first_key = first = above = None
+    if window is not None:
+        first_ref, edge_ref, lower_ref = window
+        first_key = first_ref[b * n_q + j]
+        first = first_key // rows
+        # and, under a window, wholly above every query's lower bound
+        above = (edge_ref[b * n_q + j] + rows - 1) // rows
 
     def copies(c, slot):
         """The page copies of step ``c`` into buffer ``slot``, each with
@@ -188,7 +234,10 @@ def _kernel(tbl_ref, hi_ref, lo_ref, q_ref, bound_ref, k_hbm, v_hbm, o_ref,
                 ck.wait()
                 cv.wait()
 
-    start(0, 0)
+    if first is None:
+        start(0, 0)
+    else:
+        start(first, first % 2)
     m_ref[...] = jnp.full_like(m_ref, EMPTY)
     l_ref[...] = jnp.zeros_like(l_ref)
     acc_ref[...] = jnp.zeros_like(acc_ref)
@@ -199,8 +248,10 @@ def _kernel(tbl_ref, hi_ref, lo_ref, q_ref, bound_ref, k_hbm, v_hbm, o_ref,
         bound = bound_ref[0] - c * rows             # (qb, 1)
         for i in range(heads):
             lanes = slice(i * head_dim, (i + 1) * head_dim)
-            qh, kh = q_ref[0, :, lanes], kbuf[slot, :, lanes]
-            vh = vbuf[slot, :, lanes]
+            kv = i // kv_group
+            kv_lanes = slice(kv * head_dim, (kv + 1) * head_dim)
+            qh, kh = q_ref[0, :, lanes], kbuf[slot, :, kv_lanes]
+            vh = vbuf[slot, :, kv_lanes]
             if exact:
                 s = lax.dot_general(qh.astype(jnp.float32),
                                     kh.astype(jnp.float32), _NT,
@@ -211,7 +262,10 @@ def _kernel(tbl_ref, hi_ref, lo_ref, q_ref, bound_ref, k_hbm, v_hbm, o_ref,
                                     preferred_element_type=jnp.float32)
             s = s * scale
             if masked:
-                s = jnp.where(pos < bound, s, MASKED)
+                seen = pos < bound
+                if window is not None:
+                    seen = seen & (pos >= lower_ref[0] - c * rows)
+                s = jnp.where(seen, s, MASKED)
             m_prev = m_ref[i]
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
             alpha = jnp.exp(m_prev - m_new)
@@ -235,25 +289,37 @@ def _kernel(tbl_ref, hi_ref, lo_ref, q_ref, bound_ref, k_hbm, v_hbm, o_ref,
             start(c + 1, 1 - slot)
         wait(c, slot)
         left = hi - c * rows
+        edge = left < rows
+        if first_key is not None:
+            edge = edge | (c * rows < first_key)
 
-        @pl.when(left < rows)
+        @pl.when(edge)
         def _():
-            # a V row past the frontier is zero before the MXU meets it:
-            # past the context, or a page not fetched (0 * NaN is NaN)
+            # a V row outside the block's keys is zero before the MXU
+            # meets it: past the context, before the window, or a page
+            # not fetched (0 * NaN is NaN)
             v = vbuf[slot]
             at = lax.broadcasted_iota(jnp.int32, v.shape, 0)
-            vbuf[slot] = jnp.where(at < left, v, jnp.zeros_like(v))
+            keep = at < left
+            if first_key is not None:
+                keep = keep & (at >= first_key - c * rows)
+            vbuf[slot] = jnp.where(keep, v, jnp.zeros_like(v))
 
-        @pl.when(c < unmasked)
+        plain = c < unmasked
+        if above is not None:
+            plain = plain & (c >= above)
+
+        @pl.when(plain)
         def _():
             score(c, slot, False)
 
-        @pl.when(c >= unmasked)
+        @pl.when(jnp.logical_not(plain) if above is not None
+                 else c >= unmasked)
         def _():
             score(c, slot, True)
         return 0
 
-    lax.fori_loop(0, steps, step, 0)
+    lax.fori_loop(0 if first is None else first, steps, step, 0)
     for i in range(heads):
         lanes = slice(i * head_dim, (i + 1) * head_dim)
         o_ref[0, :, lanes] = (acc_ref[:, lanes]
@@ -261,49 +327,70 @@ def _kernel(tbl_ref, hi_ref, lo_ref, q_ref, bound_ref, k_hbm, v_hbm, o_ref,
                               ).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("n_head", "interpret"))
+@functools.partial(jax.jit, static_argnames=("n_head", "num_kv_heads",
+                                             "window", "interpret"))
 def paged_chunk_attention(q, k_pool, v_pool, block_table, ctx_len, q_pos, *,
-                          n_head, interpret=False):
+                          n_head, num_kv_heads=None, window=0,
+                          interpret=False):
     """q: (B, Sq, H) a chunk of queries a row; k_pool / v_pool:
-    (num_blocks, block_size, H) float32 or bfloat16; block_table: (B,
-    max_blocks_per_seq) int32; ctx_len: (B,) live positions of each row
-    (the chunk's own included); q_pos: (B, Sq) each query's absolute
-    position.  Returns the context, (B, Sq, H) in q's dtype.  Raises
-    ValueError for what supported() rejects — call it first."""
+    (num_blocks, block_size, H_kv) float32 or bfloat16 (``num_kv_heads``
+    heads, ``n_head`` when None: query head ``h`` reads K/V head ``h //
+    (n_head / num_kv_heads)``); block_table: (B, max_blocks_per_seq)
+    int32; ctx_len: (B,) live positions of each row (the chunk's own
+    included); q_pos: (B, Sq) each query's absolute position; ``window``:
+    key ``t`` is visible to query ``p`` iff ``p - window < t`` too.
+    Returns the context, (B, Sq, H) in q's dtype.  Raises ValueError for
+    what supported() rejects — call it first."""
     b, sq, h = q.shape
-    _, bs, _ = k_pool.shape
+    _, bs, hkv = k_pool.shape
     pages_per_seq = block_table.shape[1]
-    ok, why = supported(sq, h, n_head, bs, k_pool.dtype)
-    if not ok:
-        raise ValueError(f"paged_chunk_attention: unsupported ({why})")
+    n_kv, window = int(num_kv_heads or n_head), int(window or 0)
+    ok, why = supported(sq, h, n_head, bs, k_pool.dtype, n_kv=n_kv)
+    if not ok or hkv * n_head != h * n_kv:
+        raise ValueError(f"paged_chunk_attention: unsupported "
+                         f"({why or f'pool width {hkv}'})")
     d = h // n_head
+    kv_group = n_head // n_kv
     heads = group_heads(n_head, d, max(q.dtype.itemsize,
-                                       k_pool.dtype.itemsize))
+                                       k_pool.dtype.itemsize), kv_group)
     width = heads * d
+    kv_width = width // kv_group
     qb = q_block(sq)
     rows = max(1, KEY_BLOCK // bs) * bs      # whole pages a step
     hi, lo, bound = frontiers(q_pos, ctx_len, sq, pages_per_seq * bs)
     exact = not (q.dtype == k_pool.dtype == v_pool.dtype == jnp.bfloat16)
-    # every query block against half the table, an estimate for the
-    # scheduler
+    # every query block against half the table (or its window), an
+    # estimate for the scheduler
     half = pages_per_seq * bs // 2
+    if window:
+        half = min(half, window)
     pairs = b * sq * half
+    kw = dict(heads=heads, head_dim=d, pages_per_seq=pages_per_seq,
+              scale=1.0 / math.sqrt(d), exact=exact)
+    prefetched = [block_table.reshape(-1).astype(jnp.int32), hi.reshape(-1),
+                  lo.reshape(-1)]
+    q_spec = pl.BlockSpec((1, qb, width), lambda i, j, g, *_: (i, j, g))
+    row_spec = pl.BlockSpec((1, qb, 1), lambda i, j, g, *_: (i, j, 0))
+    blocks, specs, kernel = [q, bound], [q_spec, row_spec], _kernel
+    if kv_group > 1:
+        kw["kv_group"] = kv_group
+    if window:
+        first, edge, lower = window_bounds(q_pos, ctx_len, sq, window)
+        prefetched += [first.reshape(-1), edge.reshape(-1)]
+        blocks.append(lower)
+        specs.append(row_spec)
+        kernel = _kernel_window
     out = pl.pallas_call(
-        functools.partial(_kernel, heads=heads, head_dim=d,
-                          pages_per_seq=pages_per_seq,
-                          scale=1.0 / math.sqrt(d), exact=exact),
+        functools.partial(kernel, **kw),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3, grid=(b, sq // qb, h // width),
-            in_specs=[pl.BlockSpec((1, qb, width),
-                                   lambda i, j, g, *_: (i, j, g)),
-                      pl.BlockSpec((1, qb, 1),
-                                   lambda i, j, g, *_: (i, j, 0)),
-                      pl.BlockSpec(memory_space=pl.ANY),
-                      pl.BlockSpec(memory_space=pl.ANY)],
+            num_scalar_prefetch=len(prefetched),
+            grid=(b, sq // qb, h // width),
+            in_specs=specs + [pl.BlockSpec(memory_space=pl.ANY),
+                              pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=pl.BlockSpec((1, qb, width),
                                    lambda i, j, g, *_: (i, j, g)),
-            scratch_shapes=[pltpu.VMEM((2, rows, width), k_pool.dtype),
-                            pltpu.VMEM((2, rows, width), v_pool.dtype),
+            scratch_shapes=[pltpu.VMEM((2, rows, kv_width), k_pool.dtype),
+                            pltpu.VMEM((2, rows, kv_width), v_pool.dtype),
                             pltpu.SemaphoreType.DMA((2,)),
                             pltpu.VMEM((heads, qb, 1), jnp.float32),
                             pltpu.VMEM((heads, qb, 1), jnp.float32),
@@ -313,11 +400,10 @@ def paged_chunk_attention(q, k_pool, v_pool, block_table, ctx_len, q_pos, *,
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
         cost_estimate=pl.CostEstimate(
             flops=4 * pairs * h,
-            bytes_accessed=2 * b * (sq // qb) * half * h
+            bytes_accessed=2 * b * (sq // qb) * half * hkv
             * k_pool.dtype.itemsize,
             transcendentals=pairs * n_head),
         interpret=interpret,
         name="paged_chunk_attn",
-    )(block_table.reshape(-1).astype(jnp.int32), hi.reshape(-1),
-      lo.reshape(-1), q, bound, k_pool, v_pool)
+    )(*prefetched, *blocks, k_pool, v_pool)
     return out

@@ -183,7 +183,9 @@ def moe_dropless_ffn(x: Variable, num_experts: int, ffn_hidden: int,
     ``scoring="sigmoid"`` routes as the DeepSeek-V3 family does: sigmoid
     scores, a selection-only bias ``b`` (``bias_attr``; it chooses, it
     never weighs), ``n_group`` groups of which the ``topk_group`` best
-    stay, weights times ``routed_scale`` (ops/decoder_lm_ops.py).
+    stay (ops/decoder_lm_ops.py).  Under either scoring the weights are
+    times ``routed_scale`` (a softmax router at 1.0 carries no such
+    attr, so its program is the one it was before the scale existed).
     ``shared_hidden`` adds a SHARED expert of that width which every
     token visits: computed once on the whole input, not through the
     sort, and held by every chip alike (so over the shares of an
@@ -216,6 +218,8 @@ def moe_dropless_ffn(x: Variable, num_experts: int, ffn_hidden: int,
         "int32", (-1, top_k), stop_gradient=True)
     router_in = {"X": [x], "W": [router_w]}
     router_attrs = {"top_k": top_k, "norm_topk_prob": norm_topk_prob}
+    if scoring == "softmax" and routed_scale != 1.0:
+        router_attrs["routed_scale"] = float(routed_scale)
     if scoring != "softmax":
         router_in["Bias"] = [helper.create_parameter(
             _suffixed(bias_attr if bias_attr is not None else param_attr,

@@ -9,10 +9,11 @@ are padding (position 0); query blocks of different frontiers; heads in
 more than one group a grid step; float32 and bfloat16 pools; every pool
 slot no context owns holding NaN.
 
-Then the route (which programs pick it: the hybrid decoder's chunks
-alone, not ``bert_base_decoder``'s or ``deepseek_v3_ep16_serve``'s), the
-engine's tokens through it, and the engine's count of the pages the
-chunks read.
+Then the route (which programs pick it: the hybrid and the window
+decoders' chunks, not ``bert_base_decoder``'s or
+``deepseek_v3_ep16_serve``'s), a census that every serving
+configuration's programs keep their routes, the engine's tokens through
+it, and the engine's count of the pages the chunks read.
 
 The kernel runs in Pallas' TPU interpret mode (scratch VMEM starts as
 NaN, a read outside a buffer raises); ``chip_smoke.py`` leg H repeats the
@@ -297,6 +298,10 @@ def _model(name, config):
         from benchmark.builders.serve_lm import decoder_config
         from paddle_tpu.models.latent_decoder import LatentDecoder
         return LatentDecoder(decoder_config(config))
+    if name == "laguna_s21_ep4_serve":
+        from benchmark.builders.serve_window import decoder_config
+        from paddle_tpu.models.window_decoder import WindowDecoder
+        return WindowDecoder(decoder_config(config))
     from benchmark.builders.serve_hybrid import decoder_config
     from paddle_tpu.models.hybrid_decoder import HybridDecoder
     return HybridDecoder(decoder_config(config))
@@ -315,6 +320,10 @@ def _census(name, rehearse):
     model = _model(name, config)
     mbps = cfg.max_blocks_per_seq
     kw = {"state_slots": 2} if "hybrid" in name else {}
+    if "laguna" in name:
+        kw = {"state_slots": 2, "ring_pages": model.window_ring_pages(
+            cfg.block_size, max(cfg.prefill_seq_buckets[-1],
+                                cfg.chunk_width))}
     progs = model.build(mbps, cfg.block_size, mbps, 1,
                         chain_lengths=cfg.chain_lengths,
                         chunk_tokens=cfg.chunk_width, **kw)
@@ -365,6 +374,18 @@ CENSUS = {
         **{k: [(_MLA, "mla_paged_decode"),
                ("moe_grouped_ffn", "moe_grouped_matmul")]
            for k in ("decode", "chain1", "chain8")}),
+    ("olmo_hybrid_7b_pp4_serve", True): dict(
+        {k: [(_FA, "fallback"), ("gated_delta_rule", "gdn_chunk")]
+         for k in ("prefill", "chunk")},
+        **{k: [(_FA, "fallback"), ("gated_delta_rule", "gdn_decode")]
+           for k in ("decode", "chain1", "chain4")}),
+    ("olmo_hybrid_7b_pp4_serve", False): dict(
+        prefill=[(_FA, "flash_attention"), ("gated_delta_rule", "gdn_chunk")],
+        chunk=[(_FA, "paged_chunk_attention"),
+               ("gated_delta_rule", "gdn_chunk")],
+        **{k: [(_FA, "paged_decode_attention_wide"),
+               ("gated_delta_rule", "gdn_decode")]
+           for k in ("decode", "chain1", "chain8")}),
 }
 
 
@@ -380,6 +401,31 @@ def test_no_other_serving_configuration_picks_the_chunk_route(name,
     had before the kernel existed (heads of 64 share a lane tile; the
     latent decoder's chunks are another op)."""
     assert _census(name, rehearse) == CENSUS[(name, rehearse)]
+
+
+@pytest.mark.parametrize("rehearse", [True, False],
+                         ids=["rehearsal", "published"])
+@pytest.mark.parametrize("name", sorted({n for n, _ in CENSUS}))
+def test_every_serving_configuration_keeps_its_routes(name, rehearse):
+    """Grouped K/V heads and a window in the paged reads are routed by the
+    op's attrs alone: every program of every serving configuration that
+    has neither picks the routes it picked before they existed (recorded
+    from the commit before; group 1 / window 0 is the same kernel)."""
+    assert _census(name, rehearse) == CENSUS[(name, rehearse)]
+
+
+def test_the_window_decoders_programs_take_the_grouped_routes():
+    """``laguna_s_serve.code_closed``'s programs at the published widths
+    (48 / 72 heads of 128 on 8 K/V heads, windows of 512, bfloat16 pools):
+    decode steps by the wide body's grouped scoring, chunks by the chunk
+    kernel with K/V groups, the packed prefill by the windowed flash
+    kernels; experts by the grouped matmul."""
+    got = _census("laguna_s21_ep4_serve", False)
+    moe = ("moe_grouped_ffn", "moe_grouped_matmul")
+    assert got["prefill"] == [(_FA, "flash_gqa_attention"), moe]
+    assert got["chunk"] == [(_FA, "paged_chunk_attention"), moe]
+    for kind in ("decode", "chain1", "chain8"):
+        assert got[kind] == [(_FA, "paged_gqa_decode"), moe]
 
 
 def test_the_hybrid_decoders_chunks_take_the_chunk_route():

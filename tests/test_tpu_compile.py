@@ -433,3 +433,64 @@ def test_hybrid_chunk_delta_rule_compiles_for_v5e(one_chip,
             _gdn_inputs(sds, 1, 1024)).compile().as_text()
     assert all(k in txt for k in _route_kernels("gdn_chunk"))
     assert "f32[65,30,96,192]{3,2,1,0} copy(" not in txt
+
+
+@pytest.mark.parametrize("n_head,window,blocks", [
+    (48, 0, 44032), (72, 512, 65 * 97)], ids=["full-layer", "window-layer"])
+def test_window_decoder_reads_compile_for_v5e(one_chip, no_compile_cache,
+                                              n_head, window, blocks):
+    """The window decoder cell's cached reads at its own shapes (8 K/V
+    heads of 128, bfloat16 pools of 16-position pages, a 2 304-page
+    table): a decode step of 64 rows as it sits in a chain and a
+    1 024-query chunk, each writing its keys into the donated pools just
+    before it reads them.  A full layer (48 heads) reads its block pool
+    through the engine's table, a window layer (72 heads, window 512) its
+    ring pool through the table ``window_ring`` derives.  The grouped
+    scoring and the chunk kernel take them: no ``[B, T, H]`` gather, no
+    head-repeated copy of the pages and no copy of a pool exists in the
+    compiled modules."""
+    import re
+    from paddle_tpu.ops.pallas import lowering_target
+    from paddle_tpu.ops.registry import get_op
+
+    def sds(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    attn = {"n_head": n_head, "num_kv_heads": 8, "_cached": True,
+            "is_test": True}
+    if window:
+        attn["window"] = window
+
+    def read(q, k_pool, v_pool, table, slots, ctx, pos, slot):
+        if window:
+            ring = get_op("window_ring")(None, {
+                "StateSlot": [slot], "Pos": [pos], "Slots": [slots]},
+                {"ring_pages": 97, "block_size": 16,
+                 "table_pages": table.shape[1]})
+            slots, table = ring["RingSlots"], ring["Table"]
+        kv = q[..., :1024]
+        wrote = get_op("cache_write")(None, {
+            "KPool": [k_pool], "VPool": [v_pool], "K": [kv], "V": [kv],
+            "Slots": [slots]}, {})
+        k_pool, v_pool = wrote["KPoolOut"], wrote["VPoolOut"]
+        ins = {"Q": [q], "KPool": [k_pool], "VPool": [v_pool],
+               "BlockTable": [table], "CtxLen": [ctx]}
+        if q.shape[1] > 1:
+            ins["QPos"] = [pos]
+        return k_pool, v_pool, get_op("fused_attention")(
+            None, ins, attn)["Out"]
+
+    for rows, sq, route in ((64, 1, "paged_gqa_decode"),
+                            (1, 1024, "paged_chunk_attention")):
+        with lowering_target("tpu"):
+            txt = jax.jit(read, donate_argnums=(1, 2)).lower(
+                sds((rows, sq, n_head * 128)), sds((blocks, 16, 1024)),
+                sds((blocks, 16, 1024)), sds((rows, 2304), jnp.int32),
+                sds((rows, sq), jnp.int32), sds((rows,), jnp.int32),
+                sds((rows, sq), jnp.int32),
+                sds((rows,), jnp.int32)).compile().as_text()
+        assert all(k in txt for k in _route_kernels(route)), route
+        assert not re.search(rf"\[{rows},36864,", txt)
+        moved = re.findall(rf"= \w+\[{blocks},16,1024\]\S* copy\(", txt)
+        assert not moved, moved
+
