@@ -45,6 +45,14 @@ checks what comes out by the repo's own means:
   chunk program and its 128-row chains under a watchdog — serving four
   prompts of 1 024-4 096 tokens whose logits are held to the reference's
   full forward pass;
+* **leg P** — the latent cache's chunk read at the
+  ``deepseek_v3_decode.reason_closed`` cell's shapes: ``mla_chunk_attn``
+  (1 024 queries of 128 heads over a 640-wide bfloat16 pool through a
+  512-page table, chunks ending at ``CtxLen`` 1 024 / 2 048 / 3 072 /
+  4 096, NaN in every slot no context owns) against the XLA loop it
+  replaces (``mla_ops.chunk_attention``), under a watchdog; then each
+  timed over 12 chained calls, beside the XLA up-projection of the live
+  context alone (what sharing the up-projection through HBM would add);
 * **leg H** — the served hybrid decoder (models/hybrid_decoder.py) at
   Olmo-Hybrid-7B's widths: the paged decode attention over bfloat16 K/V
   pools at 30 heads of 128 (64 rows, contexts 1 / 16 / 17 / 4 095 /
@@ -96,7 +104,7 @@ import re
 import sys
 import time
 
-LEGS = ("K", "A", "B", "M", "L", "H", "W", "C")
+LEGS = ("K", "A", "B", "M", "L", "P", "H", "W", "C")
 
 
 def _say(msg):
@@ -1203,8 +1211,114 @@ def leg_latent(S: Sizes, platform: str):
         engine.close(timeout=5.0)
     _check_routes(
         _routes_since(routes0), S,
-        want_hits=("mla_paged_decode", "moe_grouped_matmul"),
+        want_hits=("mla_paged_decode", "mla_chunk_attn",
+                   "moe_grouped_matmul"),
         allowed_fallbacks=())
+
+
+# ---------------------------------------------------------------------------
+# leg P — the latent cache's chunk read
+# ---------------------------------------------------------------------------
+
+
+def _chained(fn, ops, calls):
+    """``fn(*ops)`` ``calls`` times in one dispatch, each call waiting on
+    the last through its first operand: (seconds a call, last output)."""
+    import jax
+
+    def body(_, carry):
+        first, _ = carry
+        out = fn(first, *ops[1:])
+        return first + (out.ravel()[0] * 0).astype(first.dtype), out
+    chained = jax.jit(lambda *a: jax.lax.fori_loop(
+        0, calls, body, (a[0], fn(*a)))[1])
+    out = chained(*ops).block_until_ready()
+    best = float("inf")
+    for _ in range(3):
+        t0 = time.perf_counter()
+        chained(*ops).block_until_ready()
+        best = min(best, time.perf_counter() - t0)
+    return best / calls, out
+
+
+def leg_latent_chunk(S: Sizes):
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from paddle_tpu.ops import mla_ops
+    from paddle_tpu.ops.pallas import mla_chunk as mc
+    interpret = mc.pltpu.InterpretParams() if S.dry else False
+    if S.dry:
+        h, dn, dr, dv, dc, width = 2, 128, 64, 128, 128, 256
+        sq, pages, bs, ends = 48, 8, 16, (48, 96, 112, 128)
+    else:
+        h, dn, dr, dv, dc, width = 128, 128, 64, 128, 512, 640
+        sq, pages, bs, ends = 1024, 512, 16, (1024, 2048, 3072, 4096)
+    attrs = {"n_head": h, "nope_dim": dn, "rope_dim": dr, "v_dim": dv,
+             "scale": (dn + dr) ** -0.5}
+    rng = np.random.RandomState(43)
+    ctx = np.array(ends, np.int32)
+    table, nb, slots = _paged_table(rng, ctx, pages, bs)
+    owned = np.zeros((nb * bs,), bool)
+    for sl in slots:
+        owned[sl] = True
+    key = jax.random.PRNGKey(rng.randint(1 << 30))
+    live = jax.random.normal(key, (nb, bs, width), jnp.bfloat16)
+    live = jnp.where(jnp.arange(width) < dc + dr, live, 0)
+    pool = jnp.where(jnp.asarray(owned.reshape(nb, bs, 1)), live, jnp.nan)
+    q_pos = np.stack([np.arange(e - sq, e) for e in ends]).astype(np.int32)
+    q = jnp.asarray(rng.randn(len(ends), sq, h * (dn + dr)), jnp.bfloat16)
+    wkvb = jnp.asarray(rng.randn(dc, h * (dn + dv)) * dc ** -0.5,
+                       jnp.bfloat16)
+
+    def kernel(q, pool, tbl, ctx_, pos, w):
+        return mc.mla_chunk_attention(q, pool, tbl, ctx_, pos, w, **attrs,
+                                      interpret=interpret)
+
+    def loop(q, pool, tbl, ctx_, pos, w):
+        return mla_ops.chunk_attention(q, pool, tbl, ctx_, pos, w, attrs)
+
+    faulthandler.dump_traceback_later(600, exit=True)
+    try:
+        args = (q, pool, jnp.asarray(table), jnp.asarray(ctx),
+                jnp.asarray(q_pos), wkvb)
+        got = np.asarray(jax.jit(kernel)(*args).astype(jnp.float32))
+        assert np.isfinite(got).all()
+        want = np.asarray(jax.jit(loop)(*args).astype(jnp.float32))
+        off = _rel(got, want)
+        _say(f"  mla_chunk_attn {len(ends)} rows x {sq} queries x {h} heads "
+             f"over {nb} bf16 blocks of {bs} x {width}, chunks ending at "
+             f"{ends}, NaN outside the contexts: rel err {off:.2e} "
+             f"against the XLA loop (mla_ops.chunk_attention)")
+        assert off < 2e-2, off
+        calls = 1 if S.dry else 12
+        for i, end in enumerate(ends):
+            one = (q[i:i + 1], pool, jnp.asarray(table[i:i + 1]),
+                   jnp.asarray(ctx[i:i + 1]), jnp.asarray(q_pos[i:i + 1]),
+                   wkvb)
+            pairs = sum(range(end - sq + 1, end + 1))
+            need = 2 * h * (pairs * (dn + dr + dv) + end * dc * (dn + dv))
+            line = []
+            for name, fn in (("mla_chunk_attn", kernel), ("XLA loop", loop)):
+                sec, _ = _chained(fn, one, calls)
+                line.append(f"{name} {sec * 1e3:.3f} ms "
+                            f"({need / sec / 1e12:.1f} TFLOP/s of the "
+                            f"needed {need / 1e9:.1f} GFLOP)")
+            # sharing the up-projection through HBM: the live context's
+            # keys and values written once, before any kernel reads them
+            n = -(-end // bs)
+
+            def up(w, pool=pool, tbl=jnp.asarray(table[i, :n])):
+                lat = pool[tbl].reshape(-1, width)[:end, :dc]
+                return jnp.einsum("tc,cn->tn", lat, w,
+                                  preferred_element_type=jnp.float32
+                                  ).astype(lat.dtype)
+            sec, _ = _chained(up, (wkvb,), calls)
+            line.append(f"XLA up-projection alone {sec * 1e3:.3f} ms")
+            _say(f"    CtxLen {end}: " + "; ".join(line)
+                 + " (smoke timings)")
+    finally:
+        faulthandler.cancel_dump_traceback_later()
 
 
 # ---------------------------------------------------------------------------
@@ -1980,7 +2094,7 @@ def main(argv=None):
                     help="tiny width on the CPU backend, to debug this "
                          "script; proves nothing about the chip")
     ap.add_argument("--legs", default=",".join(LEGS),
-                    help="comma-separated subset of K,A,B,M,L,H,W,C")
+                    help="comma-separated subset of K,A,B,M,L,P,H,W,C")
     args = ap.parse_args(argv)
     legs = [x.strip().upper() for x in args.legs.split(",") if x.strip()]
     if not legs or set(legs) - set(LEGS):
@@ -2030,6 +2144,7 @@ def main(argv=None):
            "B": lambda: leg_server(S, device["platform"]),
            "M": lambda: leg_decoder_lm(S, device["platform"]),
            "L": lambda: leg_latent(S, device["platform"]),
+           "P": lambda: leg_latent_chunk(S),
            "H": lambda: leg_hybrid(S, device["platform"]),
            "W": lambda: leg_window(S),
            "C": lambda: leg_four_chips(S, device["platform"])}

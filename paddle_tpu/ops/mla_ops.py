@@ -20,11 +20,14 @@ from shapes and inputs, never from a flag or a model name:
   k_rope_j) * scale`` for ``j <= i``, softmax in f32;
 * **cached with ``QPos``** (``Pool`` ``[NB, BS, W]``,
   ``BlockTable``, ``CtxLen``, ``QPos`` ``[B, Sq]``): chunked prefill
-  over the paged latent cache.  EXPANDED too, by blocks of keys with an
-  online softmax: a block's latents are gathered through the table and
-  up-projected where they are used, and the loop stops at the longest
-  live context, so neither the whole table nor an ``[H, Sq, T]`` score
-  tensor is ever formed;
+  over the paged latent cache.  EXPANDED too: on a TPU the Pallas kernel
+  of ops/pallas/mla_chunk.py (route ``mla_chunk_attn``), which reads the
+  pool in place, up-projects each live key once a layer in VMEM and
+  scores each query block to its causal frontier; else ``chunk_attention``
+  below, by blocks of keys with an online softmax (a block's latents
+  gathered through the table and up-projected where they are used, the
+  loop stopping at the longest live context), which is also the spec the
+  kernel is held to;
 * **cached, one query token a row, no ``QPos``**: a decode step.
   ABSORBED form: ``q~_h = q_nope_h W_uk_h^T`` (``dc`` wide), every head
   scores the same latent row, ``o_h = (sum_j p_j c_kv_j) W_uv_h`` — on
@@ -208,6 +211,17 @@ def lower_mla_paged_decode(ctx, ins, attrs):
     return {"Out": project_value(o_lat, wkvb, attrs, q.dtype)}
 
 
+def lower_mla_chunk_attn(ctx, ins, attrs):
+    """The ``mla_chunk_attn`` Pallas route (pallas_route guarantees the
+    shape rule before this is called)."""
+    from .pallas.mla_chunk import mla_chunk_attention
+    return {"Out": mla_chunk_attention(
+        x(ins, "Q"), x(ins, "Pool"), x(ins, "BlockTable"), x(ins, "CtxLen"),
+        x(ins, "QPos"), x(ins, "WKVB"), n_head=int(attrs["n_head"]),
+        nope_dim=int(attrs["nope_dim"]), rope_dim=int(attrs["rope_dim"]),
+        v_dim=int(attrs["v_dim"]), scale=float(attrs["scale"]))}
+
+
 @register("mla_attention")
 def _mla_attention(ctx, ins, attrs):
     q, wkvb, pool = x(ins, "Q"), x(ins, "WKVB"), x(ins, "Pool")
@@ -220,9 +234,14 @@ def _mla_attention(ctx, ins, attrs):
         if q_pos is None:
             raise ValueError("mla_attention: a cached read of more than "
                              "one query token a row needs QPos")
+        route, _ = pallas_route("mla_attention", ins, attrs,
+                                kernel="mla_chunk_attn")
+        if route is not None:
+            return route.lower(ctx, ins, attrs)
         return {"Out": chunk_attention(q, pool, table, ctx_len, q_pos,
                                        wkvb, attrs)}
-    route, _ = pallas_route("mla_attention", ins, attrs)
+    route, _ = pallas_route("mla_attention", ins, attrs,
+                            kernel="mla_paged_decode")
     if route is not None:
         return route.lower(ctx, ins, attrs)
     o_lat = gathered_decode(absorb_query(q, wkvb, attrs, pool.shape[-1]),

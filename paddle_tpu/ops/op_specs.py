@@ -1075,6 +1075,37 @@ def _lower_mla_paged_decode(ctx, ins, attrs):
     return lower_mla_paged_decode(ctx, ins, attrs)
 
 
+def _pl_mla_chunk_supported(ins, attrs, axis_sizes=None):
+    """MLA chunk route gate (ops/pallas/mla_chunk.py): a cached read of
+    more than one query token with ``QPos``, float32 or bfloat16 pools in
+    pages of whole sublane tiles, the latent, the row and each head's key
+    and value widths in whole 128-lane tiles, and one head's up-projected
+    keys and values for the whole table within the kernel's VMEM budget."""
+    from .pallas.mla_chunk import supported
+    q = _shape_of(_sig(ins, "Q"))
+    pool = _sig(ins, "Pool")
+    pshape = _shape_of(pool)
+    w = _shape_of(_sig(ins, "WKVB"))
+    table = _shape_of(_sig(ins, "BlockTable"))
+    if pool is None:
+        return False, "not-cached"
+    if q is None or len(q) != 3 or pshape is None or len(pshape) != 3 \
+            or w is None or table is None or len(table) != 2 \
+            or min(q[1], pshape[1], pshape[2], w[0], table[1]) < 0:
+        return False, "shape-unknown"
+    return supported(q[1], int(attrs.get("n_head", 0)),
+                     int(attrs.get("nope_dim", 0)),
+                     int(attrs.get("rope_dim", 0)),
+                     int(attrs.get("v_dim", 0)), w[0], pshape[1], pshape[2],
+                     table[1], pool.dtype,
+                     has_qpos=_sig(ins, "QPos") is not None)
+
+
+def _lower_mla_chunk_attn(ctx, ins, attrs):
+    from .mla_ops import lower_mla_chunk_attn
+    return lower_mla_chunk_attn(ctx, ins, attrs)
+
+
 def _infer_gated_delta_rule(ins, attrs):
     """Out [B, S, n_head * d_v] in Q's dtype; Q / K hold ``n_head`` key
     heads, V ``n_head`` value heads; a StatePool is [slots, n_head, d_k,
@@ -1829,6 +1860,14 @@ _PL_MLA_PAGED = PallasLowering(
     match=lambda attrs, ax: bool(attrs.get("_cached")),
     supported=_pl_mla_paged_supported, lower=_lower_mla_paged_decode,
     kernels=("mla_paged_decode",))
+# a prefill chunk's read of the paged latent cache (mla_attention with a
+# Pool and QPos, more than one query token a row): the expanded form, the
+# pool read in place to each query block's causal frontier
+_PL_MLA_CHUNK = PallasLowering(
+    "mla_chunk_attn", flag="use_flash_attention", attr="use_flash",
+    match=lambda attrs, ax: bool(attrs.get("_cached")),
+    supported=_pl_mla_chunk_supported, lower=_lower_mla_chunk_attn,
+    kernels=("mla_chunk_attn",))
 # the gated delta rule's two serving forms (ops/linear_attn_ops.py picks
 # by the query's length): one token a row against the state pool, and the
 # chain over a chunk's sub-chunks
@@ -2051,7 +2090,7 @@ def register_default_specs():
     op_spec("moe_topk_router", infer=_infer_moe_topk_router)
     op_spec("lm_head_logits", infer=_infer_lm_head_logits)
     op_spec("mla_attention", infer=_infer_mla_attention,
-            pallas=(_PL_MLA_PAGED,))
+            pallas=(_PL_MLA_PAGED, _PL_MLA_CHUNK))
     # linear-attention layers of a served hybrid decoder
     # (ops/linear_attn_ops.py)
     op_spec("gated_delta_rule", infer=_infer_gated_delta_rule,

@@ -368,9 +368,13 @@ CENSUS = {
     ("deepseek_v3_ep16_serve", True): {
         k: [(_MLA, "fallback"), ("moe_grouped_ffn", "fallback")]
         for k in ("prefill", "chunk", "decode", "chain1", "chain4")},
+    # at the published widths the latent decoder's chunks take a kernel
+    # of their own (ops/pallas/mla_chunk.py)
     ("deepseek_v3_ep16_serve", False): dict(
-        {k: [(_MLA, "fallback"), ("moe_grouped_ffn", "moe_grouped_matmul")]
-         for k in ("prefill", "chunk")},
+        prefill=[(_MLA, "fallback"),
+                 ("moe_grouped_ffn", "moe_grouped_matmul")],
+        chunk=[(_MLA, "mla_chunk_attn"),
+               ("moe_grouped_ffn", "moe_grouped_matmul")],
         **{k: [(_MLA, "mla_paged_decode"),
                ("moe_grouped_ffn", "moe_grouped_matmul")]
            for k in ("decode", "chain1", "chain8")}),
@@ -399,7 +403,7 @@ def test_no_other_serving_configuration_picks_the_chunk_route(name,
     run none of this kernel: every program of their configurations, at
     the rehearsal's sizes and at the published ones, keeps the routes it
     had before the kernel existed (heads of 64 share a lane tile; the
-    latent decoder's chunks are another op)."""
+    latent decoder's chunks are another op, with a kernel of their own)."""
     assert _census(name, rehearse) == CENSUS[(name, rehearse)]
 
 

@@ -720,6 +720,22 @@ def _mla_paged_decode():
                    grad=False, is_test=True)]
 
 
+def _mla_chunk_attn():
+    """A prefill chunk's read of the paged latent cache: 32 queries with
+    QPos, 2 heads of 128+64 / v 128 over 256-wide bfloat16 rows."""
+    bf = jnp.bfloat16
+    ins = {"Q": jnp.zeros((1, 32, 2 * 192), bf),
+           "WKVB": jnp.zeros((128, 2 * 256), bf),
+           "Pool": jnp.zeros((8, 16, 256), bf),
+           "BlockTable": jnp.zeros((1, 4), jnp.int32),
+           "CtxLen": jnp.full((1,), 40, jnp.int32),
+           "QPos": jnp.arange(8, 40, dtype=jnp.int32)[None]}
+    return [_op_fn("mla_attention", ins,
+                   {"n_head": 2, "nope_dim": 128, "rope_dim": 64,
+                    "v_dim": 128, "scale": 0.1, "_cached": True},
+                   grad=False, is_test=True)]
+
+
 def _gated_delta_rule(s):
     """One token a row against the state pool (the recurrent route) or a
     chunk of ``s`` tokens started fresh (the chunked route)."""
@@ -793,6 +809,7 @@ ROUTE_CASES = {
     ("fused_attention", "paged_gqa_decode"): _paged_gqa_decode,
     ("fused_attention", "paged_chunk_attention"): _paged_chunk_attention,
     ("mla_attention", "mla_paged_decode"): _mla_paged_decode,
+    ("mla_attention", "mla_chunk_attn"): _mla_chunk_attn,
     ("gated_delta_rule", "gdn_decode"): lambda: _gated_delta_rule(1),
     ("gated_delta_rule", "gdn_chunk"): lambda: _gated_delta_rule(128),
     ("moe_grouped_ffn", "moe_grouped_matmul"): _grouped_ffn,

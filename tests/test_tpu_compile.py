@@ -257,6 +257,41 @@ def test_mla_paged_decode_compiles_for_v5e(one_chip, no_compile_cache,
     assert f"bf16[{batch},8192,640]" not in txt
 
 
+def test_mla_chunk_attn_compiles_for_v5e(one_chip, no_compile_cache):
+    """The latent serving cell's chunk read at its own shapes: a
+    1 024-token chunk of 128 heads of 128+64 / v 128 over the pool of
+    34 688 blocks of 16 x 640 bfloat16 through a 512-page table, as
+    ``mla_attention`` lowers it for a TPU with ``QPos``.  The kernel's
+    VMEM (a head group's keys and values for the whole table) passes the
+    compiler, and neither the table's gather nor a ``[H, Sq, keys]``
+    float32 score tensor exists in the compiled module."""
+    import re
+
+    from paddle_tpu.ops.pallas import lowering_target
+    from paddle_tpu.ops.registry import get_op
+
+    def sds(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    attrs = {"n_head": 128, "nope_dim": 128, "rope_dim": 64, "v_dim": 128,
+             "scale": 0.07, "_cached": True}
+
+    def chunk(q, wkvb, pool, table, ctx, pos):
+        return get_op("mla_attention")(None, {
+            "Q": [q], "WKVB": [wkvb], "Pool": [pool], "BlockTable": [table],
+            "CtxLen": [ctx], "QPos": [pos]}, attrs)["Out"]
+
+    with lowering_target("tpu"):
+        txt = jax.jit(chunk).lower(
+            sds((1, 1024, 128 * 192)), sds((512, 128 * 256)),
+            sds((34688, 16, 640)), sds((1, 512), jnp.int32),
+            sds((1,), jnp.int32), sds((1, 1024), jnp.int32)
+        ).compile().as_text()
+    assert all(k in txt for k in _route_kernels("mla_chunk_attn"))
+    assert "bf16[1,8192,640]" not in txt and "bf16[1,512,640]" not in txt
+    assert not re.findall(r"f32\[1,128,1024,\d+\]", txt)
+
+
 @pytest.mark.parametrize("tokens", [128, 1024],
                          ids=["decode-128-rows", "chunk-1024-tokens"])
 def test_wide_expert_grouped_matmul_compiles_for_v5e(
